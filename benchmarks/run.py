@@ -28,6 +28,9 @@ SECTIONS = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     wanted = sys.argv[1:] or SECTIONS
     failures = 0
     for name in wanted:

@@ -566,17 +566,19 @@ class PallasProgram(Program):
         return out
 
     def bind(self, params) -> "Program":
-        """Pack weights into the heap and upload it — exactly once."""
-        self._params = _np_tree(params)
-        self._params_dev = None
+        """Pack weights into the heap and upload it — exactly once.  The
+        prefill path uses ``params`` as given (f32 device arrays are kept,
+        not copied), so the device holds the heap and one weight copy."""
+        self._params_dev = jax.tree.map(
+            lambda a: jnp.asarray(a, jnp.float32), params)
         zero_cache = _np_tree(init_cache(self.cfg, self.batch,
                                          self.max_seq, dtype=jnp.float32))
         if self.cfg.embed_input:
             tok0 = np.zeros((self.batch, self.cfg.d_model), np.float32)
         else:
             tok0 = np.zeros((self.batch,), np.int32)
-        binds = decode_bindings(self.cfg, self._params, zero_cache, tok0,
-                                np.zeros((self.batch,), np.int32))
+        binds = decode_bindings(self.cfg, _np_tree(params), zero_cache,
+                                tok0, np.zeros((self.batch,), np.int32))
         self.executor.upload(self.plan.build_heap(binds))
         return self
 

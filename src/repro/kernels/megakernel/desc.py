@@ -118,8 +118,9 @@ from ...core.compile import CompiledTGraph
 from ...core.graph import OpKind
 
 __all__ = ["KIND_CODES", "DESC_WORDS", "STATS_WORDS", "TRACE_WORDS",
-           "TRACE_HEADER", "PER_STEP_INPUTS", "MegakernelPlan",
-           "MegakernelProgram", "lower_tgraph", "stamp_multichip"]
+           "TRACE_HEADER", "PER_STEP_INPUTS", "LANE", "heap_words",
+           "MegakernelPlan", "MegakernelProgram", "lower_tgraph",
+           "stamp_multichip"]
 
 #: graph inputs that change every decode step — everything else in the heap
 #: (weights, caches, SSM/conv state) is uploaded once and lives on device
@@ -189,6 +190,18 @@ REMOTE_COPY_CODE = 14
 AR_CHUNK_CODE = 15
 
 _ACT_IDS = {None: 0, "identity": 0, "silu": 1, "gelu": 2}
+
+#: lane width of one heap tile: on a TPU the heap is a ``(1, N)`` f32 row
+#: tiled ``(1, 128)``, so the compiled kernel moves whole 128-word tiles
+#: from 128-aligned offsets
+LANE = 128
+
+
+def heap_words(heap_size: int) -> int:
+    """Length of the device heap for a plan of ``heap_size`` words: whole
+    128-word tiles plus one, so a 256-word block staged from the tile
+    holding any word stays inside the array."""
+    return -(-heap_size // LANE) * LANE + LANE
 
 
 def _align(n: int, a: int = 128) -> int:
@@ -314,9 +327,10 @@ class MegakernelPlan:
                 "weights": weights}
 
     def build_heap(self, bindings: Dict[str, np.ndarray]) -> np.ndarray:
-        """Pack bindings into the heap — replicated into every chip's
-        region under a multichip plan (the TP model's SPMD inputs)."""
-        heap = np.zeros((self.heap_size,), np.float32)
+        """Pack bindings into the ``heap_words(heap_size)``-word heap —
+        replicated into every chip's region under a multichip plan (the
+        TP model's SPMD inputs)."""
+        heap = np.zeros((heap_words(self.heap_size),), np.float32)
         g = self.compiled.graph
         for name in g.inputs:
             slot = self.layout[name]
@@ -328,6 +342,36 @@ class MegakernelPlan:
                 view = view.reshape(slot.rows, slot.ld)
                 view[:, : a2.shape[1]] = a2
         return heap
+
+    def compiled_layout_errors(self) -> List[str]:
+        """What keeps this plan off the compiled TPU kernel's DMA path:
+        every heap offset and row stride a descriptor hands to a tile
+        DMA, and every static tile width, must be a whole number of
+        ``LANE``-word tiles.  Empty when the plan can compile."""
+        errs = [f"{k}={self.statics[k]} is not a multiple of {LANE}"
+                for k in ("TN", "STORE_CH")
+                if self.statics.get(k, LANE) % LANE]
+        tk = min(LANE, max(8, self.statics["TK"]))
+        if tk % LANE:
+            errs.append(f"K chunk {tk} is not a multiple of {LANE}")
+        if ATTN_CODE in self.descs[:, 0] and self.statics["HD"] % LANE:
+            errs.append(f"head_dim {self.statics['HD']} is not a multiple "
+                        f"of {LANE}")
+        for row in self.descs:
+            code = int(row[0])
+            words = _ALIGNED_WORDS.get(code, ())
+            # a one-row primary tile never adds its row stride
+            if int(row[26]) > 0:
+                words += (24, 25) if int(row[26]) > 1 else (24,)
+            if int(row[30]) > 0:
+                words += (28, 29) if int(row[30]) > 1 else (28,)
+            bad = [w for w in words if row[w] >= 0 and row[w] % LANE]
+            if bad:
+                errs.append(f"kind {code} descriptor words {bad} = "
+                            f"{[int(row[w]) for w in bad]}")
+                if len(errs) > 8:
+                    break
+        return errs
 
     def read_output(self, heap: np.ndarray, name: str,
                     chip: int = 0) -> np.ndarray:
@@ -367,7 +411,9 @@ def _primary_record(d: np.ndarray):
     """(off, ld, rows) of a descriptor's primary operand tile, or None."""
     code = int(d[0])
     if code == KIND_CODES[OpKind.EMBED_LOOKUP]:
-        return int(d[6]), 1, 1
+        # the token ids are read as scalar words at word 6; the tile only
+        # paces the pipeline, so it starts at the ids' aligned lane tile
+        return int(d[6]) // LANE * LANE, 1, 1
     if code in _PRIMARY_ROWS_M:
         return int(d[6]), max(1, int(d[7])), int(d[1])
     return None
@@ -563,9 +609,11 @@ def lower_tgraph(compiled: CompiledTGraph, cfg,
     # spilling into a neighbouring column tile (tiles commute across
     # workers, so the overhang would clobber finished output), stores are
     # masked to STORE_CH-wide chunks.  STORE_CH must divide every column
-    # start and width of every column-tiled tensor — the gcd below —
-    # so masked stores are exactly tile-wide (row-only tensors only ever
-    # overhang into their row slot's zero padding).
+    # start and every width but the last tile's of every column-tiled
+    # tensor — the gcd below — so masked stores are exactly tile-wide;
+    # a tensor's last tile (like a row-only tensor) only ever overhangs
+    # into its row slot's padding, and what it writes there is computed
+    # from zero pad columns, so the padding stays zero.
     store_ch = 128
     col_starts: Dict[str, set] = {}
     col_geom: Dict[str, list] = {}
@@ -580,8 +628,11 @@ def lower_tgraph(compiled: CompiledTGraph, cfg,
         col_geom.setdefault(op.outputs[0], []).append((c0, nw))
     for name, starts in col_starts.items():
         if len(starts) > 1:
+            cols = g.spec(name).shape[-1]
             for c0, nw in col_geom[name]:
-                store_ch = math.gcd(store_ch, math.gcd(c0 or store_ch, nw))
+                last = c0 + nw == cols
+                store_ch = math.gcd(store_ch, math.gcd(
+                    c0 or store_ch, store_ch if last else nw))
     store_ch = max(1, store_ch)
 
     descs = np.zeros((len(compiled.order), DESC_WORDS), np.int32)
@@ -855,6 +906,25 @@ _OFFSET_WORDS = {
     KIND_CODES[OpKind.CONV1D_UPDATE]: (4, 6, 8, 10, 12),
     REMOTE_COPY_CODE: (4, 6),
     AR_CHUNK_CODE: (4, 6),
+}
+
+
+ATTN_CODE = KIND_CODES[OpKind.ATTENTION_DECODE]
+
+#: descriptor words, per kind code, that the compiled kernel adds to heap
+#: tile DMA addresses (offsets and row strides); lengths, positions and
+#: token ids are read as scalar words and may sit anywhere
+_ALIGNED_WORDS = {
+    KIND_CODES[OpKind.MATMUL]: (4, 5, 6, 7, 8, 9, 10),
+    KIND_CODES[OpKind.RMSNORM]: (4, 5, 6, 7, 10),
+    KIND_CODES[OpKind.ROPE]: (4, 5, 6, 7),
+    KIND_CODES[OpKind.GLU_MUL]: (4, 5, 6, 7, 8, 9),
+    KIND_CODES[OpKind.RESIDUAL_ADD]: (4, 5, 6, 7, 8, 9),
+    ATTN_CODE: (4, 5, 6, 7, 8, 9, 10, 11, 15),
+    KIND_CODES[OpKind.CACHE_UPDATE]: (4, 5, 6, 7, 15),
+    KIND_CODES[OpKind.EMBED_LOOKUP]: (4, 5, 8, 9),
+    KIND_CODES[OpKind.SSM_UPDATE]: (4, 5, 6, 7, 8, 9, 15, 16, 19, 20, 21, 22),
+    KIND_CODES[OpKind.CONV1D_UPDATE]: (4, 5, 6, 7, 8, 9, 10, 11, 12, 15),
 }
 
 

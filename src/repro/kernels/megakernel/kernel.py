@@ -79,15 +79,31 @@ interpreter.  No cross-slot prefetch is planned (the next task is a
 runtime decision): every task demand-loads its primary tile through its
 own record, words 28-30.
 
+Compiled for a TPU (``interpret=False``, Mosaic): the heap is one
+``(1, N)`` f32 row, tiled ``(1, 128)`` in HBM.  Every tile moves as
+single-row DMAs between it and ``(rows, 1, width)`` VMEM buffers —
+Mosaic slices neither a 1-D HBM array nor an ``(8, 128)``-tiled VMEM
+buffer by one row — so every DMA offset, row stride and width is a whole
+number of 128-word tiles (``MegakernelPlan.compiled_layout_errors``).
+Scalar words (lengths, token ids, positions, counters, event and trace
+words) never pass through vector registers: they move through a 256-word
+SMEM block staged from the word's tile.  The grid runs in order on the
+chip's one TensorCore, exactly as in interpret mode.  The compiled kernel
+covers the static scheduler and the dense decoder's and the SSM's kinds
+(``COMPILED_KINDS``); MoE and COMM kinds and the dynamic scheduler run
+in interpret mode only.
+
 Validated in interpret mode against the numpy tGraph interpreter and the
 JAX model oracle (tests/test_megakernel.py, tests/test_program_api.py,
-tests/test_workers.py for W > 1).
+tests/test_workers.py for W > 1); compiled, by ``chip_smoke.py`` against
+the f32 reference on a TPU v5e and by tests/test_chip_compile.py against
+a described one.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Iterable
 
 import jax
 import jax.numpy as jnp
@@ -95,9 +111,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...runtime.dyn_sched import QUEUE_EMPTY
-from .desc import DESC_WORDS, STATS_WORDS, TRACE_HEADER, TRACE_WORDS
+from .desc import (LANE, STATS_WORDS, TRACE_HEADER, TRACE_WORDS,
+                   heap_words)
 
-__all__ = ["make_megakernel", "make_count", "COMM_BLOCK"]
+__all__ = ["make_megakernel", "make_count", "COMM_BLOCK", "COMPILED_KINDS"]
 
 #: occupied/empty discriminator for ready-pool slots (row ids sit far
 #: below, the QUEUE_EMPTY sentinel far above)
@@ -108,6 +125,17 @@ _QTH = QUEUE_EMPTY / 2
 #: the ``sR`` scratch — the stamper reserves a trailing heap pad so the
 #: last block of a span may read (never write) past its end
 COMM_BLOCK = 256
+
+#: scalar words (counters, event and tick words, token ids, lengths,
+#: positions, trace records) move through a 256-word SMEM block staged
+#: from the 128-aligned tile holding the word — any span of up to 128
+#: words starting in that tile fits
+_BLK = 2 * LANE
+
+#: task kinds the compiled (Mosaic) kernel implements: the dense decoder
+#: path plus the SSM and conv updates.  A plan holding any other kind
+#: runs only in interpret mode.
+COMPILED_KINDS = frozenset(range(9)) | {12, 13}
 
 #: incremented on every ``make_megakernel`` call — the compile-count hook
 #: used by tests to assert the Program API builds the kernel exactly once
@@ -120,7 +148,10 @@ def make_count() -> int:
 
 
 def _f32(bits):
-    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+    """Reinterpret an int32 descriptor word as an f32 (1, 1) vector (the
+    scalar core has no bitcast)."""
+    return jax.lax.bitcast_convert_type(
+        jnp.full((1, 1), bits, jnp.int32), jnp.float32)
 
 
 def _act(y, act_id):
@@ -131,10 +162,38 @@ def _act(y, act_id):
     )
 
 
+def _dot(a, b, dims):
+    # the heap is f32 and the kernel is held to a plain f32 reference:
+    # ask the MXU for full f32 contraction, not a bf16 pass
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
+
+
 def make_megakernel(statics: Dict[str, Any], num_steps: int,
-                    heap_size: int):
+                    heap_size: int, *, interpret: bool,
+                    kinds: Iterable[int] = ()):
+    """Build the persistent kernel over a ``(1, heap_words(heap_size))``
+    f32 heap.
+
+    ``interpret=True`` runs it through the Pallas interpreter (CPU);
+    ``False`` compiles it with Mosaic for a TPU, which takes a static
+    scheduler plan whose task kinds are all in ``COMPILED_KINDS``.
+    ``kinds`` lists the task-kind codes the descriptor table holds;
+    dispatch branches for other kinds are left out of the kernel (empty
+    means every kind).
+    """
     global _MAKE_COUNT
     _MAKE_COUNT += 1
+    kinds = frozenset(int(k) for k in kinds) or frozenset(range(16))
+    if not interpret:
+        if statics.get("DYN", 0):
+            raise NotImplementedError(
+                "the compiled TPU megakernel runs the static scheduler only")
+        if not kinds <= COMPILED_KINDS:
+            raise NotImplementedError(
+                "task kinds %s have no compiled TPU implementation yet"
+                % sorted(kinds - COMPILED_KINDS))
     W = max(1, statics.get("W", 1))
     EVENT_OFF = statics.get("EVENT_OFF", 0)
     TN = statics["TN"]
@@ -176,16 +235,17 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
     RDMA = bool(statics.get("REMOTE_DMA", 0))
     TRACE = bool(statics.get("TRACE", 0))
     TR_OFF = statics.get("TR_OFF", 0)
+    HEAP = heap_words(heap_size)
 
     def kernel(desc, *rest):
         rest = list(rest)
         sT = rest.pop() if TRACE else None   # trace-record staging
         if DYN:
-            (sched, heap_in, heap, sA, sB, sC, sD, acc, acc2, sP, sE,
-             cnt, sR, sem, psem, rsend, rrecv, sQ, sS) = rest
+            (sched, heap_in, heap, sA, sB, sC, sD, acc, acc2, sO, sY, sP,
+             sBlk, cnt, sR, sem, psem, rsend, rrecv, sQ, sS) = rest
         else:
-            (heap_in, heap, sA, sB, sC, sD, acc, acc2, sP, sE,
-             cnt, sR, sem, psem, rsend, rrecv) = rest
+            (heap_in, heap, sA, sB, sC, sD, acc, acc2, sO, sY, sP,
+             sBlk, cnt, sR, sem, psem, rsend, rrecv) = rest
         s = pl.program_id(0)                # grid step (shared time axis)
         w_id = pl.program_id(1)             # worker lane
         slot = jax.lax.rem(s, 2)            # A side: this step's operands
@@ -193,12 +253,12 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
 
         @pl.when(s == 0)
         def _():                            # each worker zeroes its row
-            cnt[pl.ds(w_id, 1), :] = jnp.zeros((1, STATS_WORDS),
-                                               jnp.float32)
+            for j in range(STATS_WORDS):
+                cnt[w_id, j] = jnp.float32(0.0)
 
         def cadd(j, v):
             """Accumulate into this worker's counter row."""
-            cnt[pl.ds(w_id, 1), j] = cnt[pl.ds(w_id, 1), j] + v
+            cnt[w_id, j] = cnt[w_id, j] + v
 
         def _count(nrows):
             """One bulk tile DMA moving ``nrows`` strided rows.  The row
@@ -207,31 +267,86 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             cadd(0, 1.0)
             cadd(1, jnp.asarray(nrows).astype(jnp.float32))
 
-            @pl.when(cnt[pl.ds(w_id, 1), 1][0] >= 1048576.0)
+            @pl.when(cnt[w_id, 1] >= 1048576.0)
             def _():
                 cadd(4, 1.0)
                 cadd(1, -1048576.0)
+
+        def _count_tile(nrows):
+            """``_count`` for a bulk tile DMA that may move no rows."""
+            @pl.when(nrows > 0)
+            def _():
+                _count(nrows)
+
+        # ---------------- heap views ----------------------------------
+        # The heap is one (1, N) f32 row.  Row-tile operands move between
+        # it and (rows, 1, width) VMEM buffers one (1, width) row at a
+        # time; the compiled kernel needs every such offset and width to
+        # be a whole number of 128-word tiles (checked at lowering).
+        def hrow(off, width):
+            return heap.at[:, pl.ds(pl.multiple_of(off, LANE), width)]
+
+        def vrow(buf, i, width, c0=0):
+            return buf.at[i, :, pl.ds(c0, width)]
+
+        def mat(buf, nrows, width):
+            """Rows ``[0, nrows)`` of a (rows, 1, W) buffer as a value."""
+            return buf[:nrows, :, :width].reshape(nrows, width)
+
+        def store_out(y, m, valid):
+            """Stage a (TM, TN) result in ``sO`` and store its first ``m``
+            rows to the task's output tile (words 4-5)."""
+            sO[...] = y.reshape(TM, 1, TN)
+            store_tile(sO, d(4), d(5), m, TM, TN, valid=valid)
+
+        def _copy(src, dst):
+            cp = pltpu.make_async_copy(src, dst, sem)
+            cp.start()
+            cp.wait()
+
+        # ---------------- scalar words through the SMEM block --------
+        def blk_load(addr):
+            """Stage the heap words from ``addr``'s tile into ``sBlk``;
+            returns ``addr``'s lane in the block."""
+            base = addr - jax.lax.rem(addr, LANE)
+            _copy(hrow(base, _BLK), sBlk)
+            return addr - base
+
+        def blk_store(addr):
+            base = addr - jax.lax.rem(addr, LANE)
+            _copy(sBlk, hrow(base, _BLK))
+
+        def peek(addr):
+            return sBlk[0, blk_load(addr)]
+
+        def poke(addr, value):
+            sBlk[0, blk_load(addr)] = value
+            blk_store(addr)
+
+        def poke_add(addr, delta):
+            """heap[addr] += delta; returns the new value.  The grid runs
+            in order on one core, so the read-modify-write is exact."""
+            o = blk_load(addr)
+            new = sBlk[0, o] + delta
+            sBlk[0, o] = new
+            blk_store(addr)
+            return new
 
         # ---------------- DMA helpers (all through the aliased out ref) ---
         def load_tile(dst, base, ld, nrows, max_rows, width):
             """dst[i, :width] = heap[base + i*ld : +width], zero if i>=nrows.
 
             ONE bulk strided DMA: every row copy is issued back-to-back
-            (start), then completion is awaited once — a real TPU DMA
-            engine expresses this as a single strided descriptor; only
-            interpret mode's 1-D heap ref forces the per-row expansion."""
+            (start), then completion is awaited once."""
             nrows = jnp.asarray(nrows, jnp.int32)
-
-            @pl.when(nrows > 0)
-            def _():
-                _count(nrows)
+            _count_tile(nrows)
 
             def start_body(i, _):
                 @pl.when(i < nrows)
                 def _():
                     pltpu.make_async_copy(
-                        heap.at[pl.ds(base + i * ld, width)],
-                        dst.at[i, pl.ds(0, width)], sem).start()
+                        hrow(base + i * ld, width),
+                        vrow(dst, i, width), sem).start()
                 return 0
             jax.lax.fori_loop(0, max_rows, start_body, 0)
 
@@ -239,139 +354,112 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 @pl.when(i < nrows)
                 def _():
                     pltpu.make_async_copy(
-                        heap.at[pl.ds(base + i * ld, width)],
-                        dst.at[i, pl.ds(0, width)], sem).wait()
+                        hrow(base + i * ld, width),
+                        vrow(dst, i, width), sem).wait()
                 @pl.when(jnp.logical_not(i < nrows))
                 def _():
-                    dst[i, pl.ds(0, width)] = jnp.zeros((width,),
-                                                        jnp.float32)
+                    dst[i, :, pl.ds(0, width)] = jnp.zeros((1, width),
+                                                           jnp.float32)
                 return 0
             jax.lax.fori_loop(0, max_rows, fin_body, 0)
 
-        def store_tile(src, base, ld, nrows, max_rows, width, valid=None):
-            """Bulk strided write-back: issue all row copies, wait once.
-
-            ``valid`` (dynamic) masks the store to 128-wide chunks whose
-            start lies inside the task's true output width: a tile's
-            write-back must never spill into a neighbouring column tile
-            of the same tensor — on one worker the rightful tile always
-            re-wrote the overhang later, but across workers the tiles
-            commute, so an overhanging store would clobber a neighbour's
-            finished output.  Chunk starts are 128-aligned exactly like
-            the decomposer's column tiles, so masked stores are disjoint
+        def _chunked(fn, width, valid):
+            """Call ``fn(c0, cw)`` for each column chunk a store writes:
+            the whole width, or — masked by ``valid`` — the STORE_CH-wide
+            chunks whose start lies inside the task's true output width.
+            A tile's write-back must never spill into a neighbouring
+            column tile of the same tensor: tiles commute across workers,
+            so an overhanging store would clobber a neighbour's finished
+            output.  Chunk starts are aligned exactly like the
+            decomposer's column tiles, so masked stores are disjoint
             between tiles (the tail chunk only ever overhangs into the
             row slot's zero padding)."""
-            nrows = jnp.asarray(nrows, jnp.int32)
-
-            @pl.when(nrows > 0)
-            def _():
-                _count(nrows)
-
+            if valid is None:
+                fn(0, width)
+                return
             chw = min(SCH_W, width)
-            nch = -(-width // chw) if valid is not None else 1
+            nch = -(-width // chw)
+            live = jnp.minimum(nch, (jnp.asarray(valid) + chw - 1) // chw)
 
-            def chunks(i, fn):
-                if valid is None:
-                    fn(i, 0, width)
-                else:
-                    for j in range(nch):
-                        @pl.when(j * chw < jnp.asarray(valid))
-                        def _(j=j):
-                            fn(i, j * chw, chw)
+            def body(j, _):
+                fn(pl.multiple_of(j * chw, chw), chw)
+                return 0
+            jax.lax.fori_loop(0, live, body, 0)
+
+        def store_tile(src, base, ld, nrows, max_rows, width, valid=None):
+            """Bulk strided write-back: issue all row copies, wait once;
+            chunk-masked by ``valid`` (see ``_chunked``)."""
+            nrows = jnp.asarray(nrows, jnp.int32)
+            _count_tile(nrows)
+
+            def row_copies(i, op):
+                _chunked(lambda c0, cw: getattr(pltpu.make_async_copy(
+                    vrow(src, i, cw, c0), hrow(base + i * ld + c0, cw),
+                    sem), op)(), width, valid)
 
             def start_body(i, _):
                 @pl.when(i < nrows)
                 def _():
-                    chunks(i, lambda i, c0, cw: pltpu.make_async_copy(
-                        src.at[i, pl.ds(c0, cw)],
-                        heap.at[pl.ds(base + i * ld + c0, cw)],
-                        sem).start())
+                    row_copies(i, "start")
                 return 0
             jax.lax.fori_loop(0, max_rows, start_body, 0)
 
             def fin_body(i, _):
                 @pl.when(i < nrows)
                 def _():
-                    chunks(i, lambda i, c0, cw: pltpu.make_async_copy(
-                        src.at[i, pl.ds(c0, cw)],
-                        heap.at[pl.ds(base + i * ld + c0, cw)],
-                        sem).wait())
+                    row_copies(i, "wait")
                 return 0
             jax.lax.fori_loop(0, max_rows, fin_body, 0)
 
         def load_row(dst, row, base, width):
             """Single contiguous row: heap[base:+width] -> dst[row]."""
             _count(1)
-            cp = pltpu.make_async_copy(
-                heap.at[pl.ds(base, width)],
-                dst.at[row, pl.ds(0, width)], sem)
-            cp.start()
-            cp.wait()
+            _copy(hrow(base, width), vrow(dst, row, width))
 
         def load_col(row, base, stride, nelems, max_elems):
             """Strided element gather (one bulk DMA of ``nelems`` width-1
-            rows): sC[row, i] = heap[base + i*stride], zero if i>=nelems."""
+            rows): sC[row, i] = heap[base + i*stride], zero if i>=nelems.
+            Single-word copies: interpret mode only."""
             nelems = jnp.asarray(nelems, jnp.int32)
+            _count_tile(nelems)
 
-            @pl.when(nelems > 0)
-            def _():
-                _count(nelems)
+            def one(i):
+                return pltpu.make_async_copy(
+                    heap.at[:, pl.ds(base + i * stride, 1)],
+                    sC.at[row, :, pl.ds(i, 1)], sem)
 
             def body(i, _):
                 @pl.when(i < nelems)
                 def _():
-                    pltpu.make_async_copy(
-                        heap.at[pl.ds(base + i * stride, 1)],
-                        sC.at[row, pl.ds(i, 1)], sem).start()
+                    one(i).start()
                 return 0
             jax.lax.fori_loop(0, max_elems, body, 0)
 
             def fin(i, _):
                 @pl.when(i < nelems)
                 def _():
-                    pltpu.make_async_copy(
-                        heap.at[pl.ds(base + i * stride, 1)],
-                        sC.at[row, pl.ds(i, 1)], sem).wait()
+                    one(i).wait()
                 @pl.when(jnp.logical_not(i < nelems))
                 def _():
-                    sC[row, pl.ds(i, 1)] = jnp.zeros((1,), jnp.float32)
+                    sC[row, :, pl.ds(i, 1)] = jnp.zeros((1, 1), jnp.float32)
                 return 0
             jax.lax.fori_loop(0, max_elems, fin, 0)
 
-        def store_row_vec(vec_2d, row, base, width, valid=None):
+        def store_row_vec(buf, row, base, width, valid=None):
             """Single-row store, chunk-masked like ``store_tile``."""
             _count(1)
-            if valid is None:
-                cp = pltpu.make_async_copy(
-                    vec_2d.at[row, pl.ds(0, width)],
-                    heap.at[pl.ds(base, width)], sem)
-                cp.start()
-                cp.wait()
-                return
-            chw = min(SCH_W, width)
-            for j in range(-(-width // chw)):
-                @pl.when(j * chw < jnp.asarray(valid))
-                def _(j=j):
-                    cp = pltpu.make_async_copy(
-                        vec_2d.at[row, pl.ds(j * chw, chw)],
-                        heap.at[pl.ds(base + j * chw, chw)], sem)
-                    cp.start()
-                    cp.wait()
+            _chunked(lambda c0, cw: _copy(vrow(buf, row, cw, c0),
+                                          hrow(base + c0, cw)),
+                     width, valid)
 
         def store_primary_row(r, base, valid):
             """Write one row of the primary tile back to the heap (the
             cache-update path stores prefetched K/V rows directly),
             chunk-masked to the new rows' true width."""
             _count(1)
-            chw = min(SCH_W, TN)
-            for j in range(-(-TN // chw)):
-                @pl.when(j * chw < jnp.asarray(valid))
-                def _(j=j):
-                    cp = pltpu.make_async_copy(
-                        sP.at[w_id, slot, r, pl.ds(j * chw, chw)],
-                        heap.at[pl.ds(base + j * chw, chw)], sem)
-                    cp.start()
-                    cp.wait()
+            _chunked(lambda c0, cw: _copy(
+                sP.at[w_id, slot, r, :, pl.ds(c0, cw)], hrow(base + c0, cw)),
+                TN, valid)
 
         # --------------- queue-word helpers (dynamic scheduler only) ----
         # Raw single-row / single-word heap traffic for the ready pools,
@@ -380,32 +468,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         # scheduler traffic is visible through words 8-11 instead.
         def _qrow(r, base, width=QCAP):
             """sQ[r, :width] = heap[base : base + width] (one pool row)."""
-            cp = pltpu.make_async_copy(
-                heap.at[pl.ds(base, width)],
-                sQ.at[r, pl.ds(0, width)], sem)
-            cp.start()
-            cp.wait()
-
-        def _qword_out(col, addr):
-            """heap[addr] = sE[0, col] (pool slot / trace word store)."""
-            cp = pltpu.make_async_copy(
-                sE.at[0, pl.ds(col, 1)], heap.at[pl.ds(addr, 1)], sem)
-            cp.start()
-            cp.wait()
-
-        def _rmw_add(addr, delta):
-            """heap[addr] += delta through the sE scratch (the same RMW
-            the event counters use; sequential interpret order makes it
-            exact — on hardware this is the queue's atomic)."""
-            cpi = pltpu.make_async_copy(
-                heap.at[pl.ds(addr, 1)], sE.at[0, pl.ds(3, 1)], sem)
-            cpi.start()
-            cpi.wait()
-            sE[0, pl.ds(3, 1)] = sE[0, pl.ds(3, 1)] + delta
-            cpo = pltpu.make_async_copy(
-                sE.at[0, pl.ds(3, 1)], heap.at[pl.ds(addr, 1)], sem)
-            cpo.start()
-            cpo.wait()
+            _copy(heap.at[:, pl.ds(base, width)], vrow(sQ, r, width))
 
         # --------------- task selection: static grid row or dynamic pop
         if DYN:
@@ -417,7 +480,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             sS[1] = jnp.int32(0)        #   2 steal; [1] consumed slot
             sS[4] = jnp.int32(0)        #   offset; [4] popped row id
             _qrow(0, QOFF + w_id * QCAP)
-            vals = sQ[0, :]
+            vals = sQ[0, 0, :]
             mn = jnp.min(vals)
 
             @pl.when(mn < _QTH)
@@ -431,7 +494,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             def _():                    # overflow queue scan
                 for r in range(OV_ROWS):
                     _qrow(r, OVOFF + r * QCAP)
-                tile = sQ[:OV_ROWS, :]
+                tile = sQ[:OV_ROWS, 0, :]
                 mo = jnp.min(tile)
 
                 @pl.when(mo < _QTH)
@@ -446,7 +509,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 def _(k=k):
                     vw = jax.lax.rem(w_id + k, W)
                     _qrow(0, QOFF + vw * QCAP)
-                    sv = sQ[0, :]
+                    sv = sQ[0, 0, :]
                     ms = jnp.min(sv)
 
                     @pl.when(ms < _QTH)
@@ -461,11 +524,9 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
 
             @pl.when(popped)
             def _():                    # consume: mark slot empty
-                sE[0, pl.ds(1, 1)] = jnp.full((1,), QUEUE_EMPTY,
-                                              jnp.float32)
-                _qword_out(1, QOFF + sS[1])
+                poke(QOFF + sS[1], jnp.float32(QUEUE_EMPTY))
                 pool = jnp.minimum(sS[1] // QCAP, W)
-                _rmw_add(QC_OFF + 2 * pool + 1, 1.0)   # popped cursor
+                poke_add(QC_OFF + 2 * pool + 1, 1.0)   # popped cursor
 
                 @pl.when(sS[0] == 0)
                 def _():
@@ -485,10 +546,9 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
 
             # pop trace: what this grid slot executed (QUEUE_EMPTY when
             # idle) — asserted equal to dyn_sched.replay_sequential
-            sE[0, pl.ds(2, 1)] = jnp.where(
-                popped, sS[4].astype(jnp.float32),
-                QUEUE_EMPTY).reshape(1)
-            _qword_out(2, TRACE_OFF + s * W + w_id)
+            poke(TRACE_OFF + s * W + w_id,
+                 jnp.where(popped, sS[4].astype(jnp.float32),
+                           jnp.float32(QUEUE_EMPTY)))
         else:
             popped = None
             t = s * W + w_id            # row in the static grid
@@ -497,22 +557,14 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         # ------------------- trace ring (CompileOptions.trace) ---------
         # Logical clock: one in-heap tick word at TR_OFF, fetch-and-
         # incremented at slot start and slot end (exact under the
-        # sequential interpret grid; on parallel hardware this is a
-        # global atomic).  sT column 7 doubles as the RMW staging word
-        # and is zeroed before the record store.
+        # in-order grid; on parallel hardware this is a global atomic).
+        # The record is staged word by word in the sT SMEM scratch.
         if TRACE:
             def _tick(col):
-                cpi = pltpu.make_async_copy(
-                    heap.at[pl.ds(TR_OFF, 1)],
-                    sT.at[0, pl.ds(col, 1)], sem)
-                cpi.start()
-                cpi.wait()
-                sT[0, pl.ds(7, 1)] = sT[0, pl.ds(col, 1)] + 1.0
-                cpo = pltpu.make_async_copy(
-                    sT.at[0, pl.ds(7, 1)],
-                    heap.at[pl.ds(TR_OFF, 1)], sem)
-                cpo.start()
-                cpo.wait()
+                o = blk_load(TR_OFF)
+                sT[col] = sBlk[0, o]
+                sBlk[0, o] = sT[col] + 1.0
+                blk_store(TR_OFF)
             _tick(3)                    # record word 3: start tick
 
         # ------------------------------------------------ prefetch phase
@@ -524,6 +576,12 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         # rows) at words 24-26 only when the tile does not overlap
         # anything any worker writes in this or the next step, so reading
         # before those stores land is safe (that is the hazard analysis).
+        def _primary_copy(off, ld, i, side):
+            return pltpu.make_async_copy(
+                hrow(off + i * ld, TN),
+                sP.at[w_id, side, i, :, pl.ds(0, TN)],
+                psem.at[w_id, side])
+
         if not DYN:
             pf_rows = d(26)
 
@@ -535,10 +593,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             def pf_body(i, _):
                 @pl.when(i < pf_rows)
                 def _():
-                    pltpu.make_async_copy(
-                        heap.at[pl.ds(d(24) + i * d(25), TN)],
-                        sP.at[w_id, nslot, i, pl.ds(0, TN)],
-                        psem.at[w_id, nslot]).start()
+                    _primary_copy(d(24), d(25), i, nslot).start()
                 return 0
             jax.lax.fori_loop(0, TM, pf_body, 0)
 
@@ -549,22 +604,18 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
 
         # ------------------------------------------- event wait (word 32)
         # Cross-worker producers synchronize through the in-heap event
-        # table.  The sequential interpret-mode order already satisfies
-        # every dependency (static: proved by the compiler; dynamic: a
-        # task is only ever popped after its event fully triggered), so
-        # the hardware spin-wait degrades to a checked assertion: the
-        # counter must ALREADY equal the trigger count; anything else is
-        # a compiler/scheduler bug, counted as a violation.
+        # table.  The in-order grid already satisfies every dependency
+        # (static: proved by the compiler; dynamic: a task is only ever
+        # popped after its event fully triggered), so the hardware
+        # spin-wait degrades to a checked assertion: the counter must
+        # ALREADY equal the trigger count; anything else is a
+        # compiler/scheduler bug, counted as a violation.
         @pl.when(_gate(d(32) >= 0))
         def _():
-            cpw = pltpu.make_async_copy(
-                heap.at[pl.ds(EVENT_OFF + d(32), 1)],
-                sE.at[0, pl.ds(0, 1)], sem)
-            cpw.start()
-            cpw.wait()
+            seen = peek(EVENT_OFF + d(32))
             cadd(5, 1.0)
 
-            @pl.when(sE[0, 0] != d(33).astype(jnp.float32))
+            @pl.when(seen != d(33).astype(jnp.float32))
             def _():
                 cadd(6, 1.0)
 
@@ -582,10 +633,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 def wbody(i, _):
                     @pl.when(i < rows)
                     def _():
-                        pltpu.make_async_copy(
-                            heap.at[pl.ds(d(28) + i * d(29), TN)],
-                            sP.at[w_id, slot, i, pl.ds(0, TN)],
-                            psem.at[w_id, slot]).wait()
+                        _primary_copy(d(28), d(29), i, slot).wait()
                     return 0
                 jax.lax.fori_loop(0, TM, wbody, 0)
 
@@ -597,33 +645,26 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 def sbody(i, _):
                     @pl.when(i < rows)
                     def _():
-                        pltpu.make_async_copy(
-                            heap.at[pl.ds(d(28) + i * d(29), TN)],
-                            sP.at[w_id, slot, i, pl.ds(0, TN)],
-                            psem.at[w_id, slot]).start()
+                        _primary_copy(d(28), d(29), i, slot).start()
                     return 0
                 jax.lax.fori_loop(0, TM, sbody, 0)
 
                 def fbody(i, _):
                     @pl.when(i < rows)
                     def _():
-                        pltpu.make_async_copy(
-                            heap.at[pl.ds(d(28) + i * d(29), TN)],
-                            sP.at[w_id, slot, i, pl.ds(0, TN)],
-                            psem.at[w_id, slot]).wait()
+                        _primary_copy(d(28), d(29), i, slot).wait()
                     return 0
                 jax.lax.fori_loop(0, TM, fbody, 0)
 
             def zbody(i, _):
                 @pl.when(i >= rows)
                 def _():
-                    sP[pl.ds(w_id, 1), pl.ds(slot, 1), pl.ds(i, 1),
-                       :] = jnp.zeros((1, 1, 1, TN), jnp.float32)
+                    sP[w_id, slot, i] = jnp.zeros((1, TN), jnp.float32)
                 return 0
             jax.lax.fori_loop(0, TM, zbody, 0)
-            return sP[pl.ds(w_id, 1), pl.ds(slot, 1)][0, 0]
+            return sP[w_id, slot].reshape(TM, TN)
 
-        cols = jax.lax.iota(jnp.int32, TN)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, TN), 1)
 
         # --------------- COMM span engine (kinds 14/15, multichip TP) ---
         # A per-row column window (m rows, independent src/dst row
@@ -633,11 +674,10 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         # and the current destination block, combine, write back with
         # words past the window restored from the loaded destination
         # (reads may run past the window into the neighbouring columns
-        # or the stamper's trailing pad; writes never do).  Sequential
-        # interpret-mode execution makes the read-modify-write exact —
-        # on real hardware each chip only ever combines into its OWN
-        # output windows and staging buffers, so the blocks race with
-        # nothing.
+        # or the stamper's trailing pad; writes never do).  In-order
+        # execution makes the read-modify-write exact — on real hardware
+        # each chip only ever combines into its OWN output windows and
+        # staging buffers, so the blocks race with nothing.
         def span_op(src0, s_ld, dst0, d_ld, m, nwords, combine):
             nblk = (nwords + COMM_BLOCK - 1) // COMM_BLOCK
 
@@ -645,7 +685,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             def _():
                 _count(3 * nblk * m)    # src load + dst load + writeback
 
-            lane0 = jax.lax.iota(jnp.int32, COMM_BLOCK)
+            lane0 = jax.lax.broadcasted_iota(jnp.int32, (1, COMM_BLOCK), 1)
 
             def row_body(r, _):
                 sbase = src0 + r * s_ld
@@ -653,24 +693,15 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
 
                 def body(i, _):
                     base = i * COMM_BLOCK
-                    cps = pltpu.make_async_copy(
-                        heap.at[pl.ds(sbase + base, COMM_BLOCK)],
-                        sR.at[0, pl.ds(0, COMM_BLOCK)], sem)
-                    cps.start()
-                    cps.wait()
-                    cpd = pltpu.make_async_copy(
-                        heap.at[pl.ds(dbase + base, COMM_BLOCK)],
-                        sR.at[1, pl.ds(0, COMM_BLOCK)], sem)
-                    cpd.start()
-                    cpd.wait()
+                    _copy(heap.at[:, pl.ds(sbase + base, COMM_BLOCK)],
+                          sR.at[0])
+                    _copy(heap.at[:, pl.ds(dbase + base, COMM_BLOCK)],
+                          sR.at[1])
                     rel = lane0 + base      # window-relative col index
-                    new = combine(sR[0, :], sR[1, :], rel)
-                    sR[1, :] = jnp.where(rel < nwords, new, sR[1, :])
-                    cpo = pltpu.make_async_copy(
-                        sR.at[1, pl.ds(0, COMM_BLOCK)],
-                        heap.at[pl.ds(dbase + base, COMM_BLOCK)], sem)
-                    cpo.start()
-                    cpo.wait()
+                    new = combine(sR[0], sR[1], rel)
+                    sR[1] = jnp.where(rel < nwords, new, sR[1])
+                    _copy(sR.at[1],
+                          heap.at[:, pl.ds(dbase + base, COMM_BLOCK)])
                     return 0
                 jax.lax.fori_loop(0, nblk, body, 0)
                 return 0
@@ -693,9 +724,9 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                     def body(i, _):
                         base = i * COMM_BLOCK
                         cp = pltpu.make_async_remote_copy(
-                            src_ref=heap.at[pl.ds(
+                            src_ref=heap.at[:, pl.ds(
                                 d(6) + r * d(7) + base, COMM_BLOCK)],
-                            dst_ref=heap.at[pl.ds(
+                            dst_ref=heap.at[:, pl.ds(
                                 d(4) + r * d(5) + base, COMM_BLOCK)],
                             send_sem=rsend, recv_sem=rrecv,
                             device_id=d(21),
@@ -731,31 +762,30 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         def k_matmul():
             m, n, k = d(1), d(2), d(3)
             pa = primary()
-            acc[...] = jnp.zeros((TM, TN), jnp.float32)
-            for kc in range(KCH):
-                k0 = kc * TKC
-                if kc == 0:
-                    xa = pa[:, :TKC]
-                else:
-                    load_tile(sA, d(6) + k0, d(7), m, TM, TKC)
-                    xa = sA[:, :TKC]
+
+            def chunk(k0, xa):
                 load_tile(sB, d(8) + k0 * d(9), d(9),
                           jnp.clip(k - k0, 0, TKC), SB_ROWS, TN)
-                acc[...] += jax.lax.dot_general(
-                    xa, sB[:TKC, :],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                acc[...] += _dot(xa, mat(sB, TKC, TN), ((1,), (0,)))
+
+            acc[...] = jnp.zeros((TM, TN), jnp.float32)
+            chunk(0, pa[:, :TKC])
+
+            def kbody(kc, _):
+                k0 = kc * TKC
+                load_tile(sA, d(6) + k0, d(7), m, TM, TKC)
+                chunk(k0, mat(sA, TM, TKC))
+                return 0
+            jax.lax.fori_loop(1, KCH, kbody, 0)
             y = acc[...]
             @pl.when(d(10) >= 0)
             def _():
                 load_row(sC, 0, d(10), TN)
             @pl.when(d(10) < 0)
             def _():
-                sC[0, :] = jnp.zeros((TN,), jnp.float32)
-            y = y + sC[0, :][None, :]
-            y = _act(y, d(14))
-            acc[...] = y
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=n)
+                sC[0, :, :TN] = jnp.zeros((1, TN), jnp.float32)
+            y = y + sC[0, :, :TN]
+            store_out(_act(y, d(14)), m, n)
 
         def k_rmsnorm():
             m, n = d(1), d(2)
@@ -764,51 +794,54 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             x = pa[:, :TN]
             mean = jnp.sum(x * x, axis=1, keepdims=True) / n.astype(jnp.float32)
             inv = jax.lax.rsqrt(mean + _f32(d(17)))
-            w = sC[0, :][None, :]
+            w = sC[0, :, :TN]
             wg = jnp.where(d(14) == 1, 1.0 + w, w)
             y = x * inv * wg
             # keep pad columns zero (gemma's 1+w would leak 1·0=0 anyway)
-            y = jnp.where(cols[None, :] < n, y, 0.0)
-            acc[...] = y
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=n)
+            y = jnp.where(cols < n, y, 0.0)
+            store_out(y, m, n)
 
         def k_rope():
+            # rotate-half per head, evaluated across the whole row: lane
+            # j of head h pairs with lane j ± HD/2 of the same head
             m, n = d(1), d(2)
             pa = primary()
             half = HD // 2
-            inv_freq = THETA ** (-jnp.arange(0, half, dtype=jnp.float32)
-                                 / half)
-            is_mrope = d(15) == 1
-            pw = 4 if MROPE else 1
-            load_tile(sC, d(19), d(20), m, TM, pw)
-            if MROPE:
-                ang_parts = []
-                start = 0
-                for si, sec in enumerate(MROPE):
-                    ang_parts.append(sC[:TM, si][:, None]
-                                     * inv_freq[None, start:start + sec])
-                    start += sec
-                ang = jnp.concatenate(ang_parts, axis=1)
-            else:
-                ang = sC[:TM, 0][:, None] * inv_freq[None, :]
-            cosv, sinv = jnp.cos(ang), jnp.sin(ang)
-            y = pa[:, :TN]
-            out = jnp.zeros((TM, TN), jnp.float32)
-            for h in range(TN // HD):
-                x1 = y[:, h * HD : h * HD + half]
-                x2 = y[:, h * HD + half : (h + 1) * HD]
-                rot = jnp.concatenate(
-                    [x1 * cosv - x2 * sinv, x2 * cosv + x1 * sinv], axis=1)
-                out = jax.lax.dynamic_update_slice(out, rot, (0, h * HD))
-            acc[...] = out
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=n)
+            hl = jax.lax.rem(cols, HD)           # lane within its head
+            fi = jax.lax.rem(hl, half)           # frequency index
+            inv_freq = THETA ** (-fi.astype(jnp.float32) / half)
+            first = hl < half
+            heads = cols < (TN // HD) * HD
+            _count_tile(m)                       # the positions gather
+            for r in range(TM):
+                @pl.when(r < m)
+                def _(r=r):
+                    if MROPE:
+                        p = [peek(d(19) + r * d(20) + si)
+                             for si in range(len(MROPE))]
+                        # section si covers frequencies [start_si, end_si)
+                        pos = jnp.full((1, TN), p[-1], jnp.float32)
+                        end = sum(MROPE[:-1])
+                        for si in range(len(MROPE) - 2, -1, -1):
+                            pos = jnp.where(fi < end, p[si], pos)
+                            end -= MROPE[si]
+                    else:
+                        pos = peek(d(19) + r * d(20))
+                    ang = pos * inv_freq
+                    cosv, sinv = jnp.cos(ang), jnp.sin(ang)
+                    y = pa[r:r + 1, :TN]
+                    nxt = pltpu.roll(y, TN - half, 1)    # y[j + half]
+                    prv = pltpu.roll(y, half, 1)         # y[j - half]
+                    rot = jnp.where(first, y * cosv - nxt * sinv,
+                                    y * cosv + prv * sinv)
+                    sO[r] = jnp.where(heads, rot, 0.0)
+            store_tile(sO, d(4), d(5), m, TM, TN, valid=n)
 
         def k_glu():
             m = d(1)
             pa = primary()
             load_tile(sD, d(8), d(9), m, TM, TN)
-            acc[...] = _act(pa[:, :TN], d(14)) * sD[:TM, :TN]
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=d(2))
+            store_out(_act(pa[:, :TN], d(14)) * mat(sD, TM, TN), m, d(2))
 
         def k_resid():
             m = d(1)
@@ -819,84 +852,89 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 load_tile(sD, d(8), d(9), m, TM, TN)
             @pl.when(d(8) < 0)
             def _():
-                sD[:TM, :] = jnp.zeros((TM, TN), jnp.float32)
-            acc[...] = y + sD[:TM, :TN]
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=d(2))
+                sD[:TM] = jnp.zeros((TM, 1, TN), jnp.float32)
+            store_out(y + mat(sD, TM, TN), m, d(2))
 
         def k_attn():
-            m, n, s_len = d(1), d(2), d(3)
+            m, n = d(1), d(2)
             scale = _f32(d(17))
-            pa = primary()                                 # q tile
-            load_row(sC, 0, d(12), TM)                     # live lens row
-            for r in range(TM):
-                @pl.when(r < m)
-                def _(r=r):
-                    live = sC[0, r].astype(jnp.int32)
-                    row_out = jnp.zeros((TN,), jnp.float32)
-                    for gi in range(NG):
-                        qg = pa[r, gi * G * HD : (gi + 1) * G * HD]
-                        qm = qg.reshape(G, HD) * scale
-                        mrun = jnp.full((G,), -1e30, jnp.float32)
-                        lrun = jnp.zeros((G,), jnp.float32)
-                        arun = jnp.zeros((G, HD), jnp.float32)
-                        for sc in range(SCH):
-                            s0 = sc * TS
-                            valid = jnp.clip(live - s0, 0, TS)
-                            load_tile(sB, d(8) + r * d(15) + gi * HD
-                                      + s0 * d(9), d(9), valid, TS, HD)
-                            load_tile(sD, d(10) + r * d(15) + gi * HD
-                                      + s0 * d(11), d(11), valid, TS, HD)
-                            logits = jax.lax.dot_general(
-                                sB[:TS, :HD], qm,
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (TS,G)
-                            srow = jax.lax.iota(jnp.int32, TS)
-                            logits = jnp.where(srow[:, None] < valid,
-                                               logits, -1e30)
-                            mnew = jnp.maximum(mrun,
-                                               jnp.max(logits, axis=0))
-                            p = jnp.exp(logits - mnew[None, :])
-                            corr = jnp.exp(mrun - mnew)
-                            lrun = lrun * corr + jnp.sum(p, axis=0)
-                            arun = arun * corr[:, None] + jax.lax.dot_general(
-                                p, sD[:TS, :HD],
-                                (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-                            mrun = mnew
-                        og = (arun / jnp.maximum(lrun, 1e-30)[:, None]
-                              ).reshape(G * HD)
-                        row_out = jax.lax.dynamic_update_slice(
-                            row_out, og, (gi * G * HD,))
-                    acc[r, :] = row_out
-                    store_row_vec(acc, r, d(4) + r * d(5), TN,
-                                  valid=n)
+            primary()                                      # q tile
+            _count(1)                                      # live lens row
+            lo = blk_load(d(12))
+            srow = jax.lax.broadcasted_iota(jnp.int32, (TS, 1), 0)
+
+            def row_body(r, _):
+                live = sBlk[0, lo + r].astype(jnp.int32)
+                sO[r] = jnp.zeros((1, TN), jnp.float32)
+
+                def group_body(gi, _):
+                    q0 = pl.multiple_of(gi * (G * HD), G * HD)
+                    qg = sP[w_id, slot, r, :, pl.ds(q0, G * HD)]
+                    qm = qg.reshape(G, HD) * scale
+
+                    def chunk_body(sc, carry):
+                        mrun, lrun, arun = carry
+                        s0 = sc * TS
+                        valid = jnp.clip(live - s0, 0, TS)
+                        load_tile(sB, d(8) + r * d(15) + gi * HD
+                                  + s0 * d(9), d(9), valid, TS, HD)
+                        load_tile(sD, d(10) + r * d(15) + gi * HD
+                                  + s0 * d(11), d(11), valid, TS, HD)
+                        logits = _dot(mat(sB, TS, HD), qm,
+                                      ((1,), (1,)))             # (TS,G)
+                        logits = jnp.where(srow < valid, logits, -1e30)
+                        mnew = jnp.maximum(
+                            mrun, jnp.max(logits, axis=0, keepdims=True))
+                        p = jnp.exp(logits - mnew)
+                        corr = jnp.exp(mrun - mnew)
+                        lrun = lrun * corr + jnp.sum(p, axis=0,
+                                                     keepdims=True)
+                        arun = (arun * corr.reshape(G, 1)
+                                + _dot(p, mat(sD, TS, HD), ((0,), (0,))))
+                        return mnew, lrun, arun
+
+                    mrun, lrun, arun = jax.lax.fori_loop(
+                        0, SCH, chunk_body,
+                        (jnp.full((1, G), -1e30, jnp.float32),
+                         jnp.zeros((1, G), jnp.float32),
+                         jnp.zeros((G, HD), jnp.float32)))
+                    og = arun / jnp.maximum(lrun, 1e-30).reshape(G, 1)
+                    sO[r, :, pl.ds(q0, G * HD)] = og.reshape(1, G * HD)
+                    return 0
+                jax.lax.fori_loop(0, NG, group_body, 0)
+                store_row_vec(sO, r, d(4) + r * d(5), TN, valid=n)
+                return 0
+            jax.lax.fori_loop(0, m, row_body, 0)
 
         def k_cache_update():
             m = d(1)
             primary()                                      # new K/V rows
-            load_row(sC, 0, d(12), TM)                     # seq lens
-            for r in range(TM):
-                @pl.when(r < m)
-                def _(r=r):
-                    seq = sC[0, r].astype(jnp.int32)
-                    store_primary_row(r, d(4) + r * d(15) + seq * d(5),
-                                      valid=d(2))
+            _count(1)                                      # seq lens row
+            lo = blk_load(d(12))
+
+            def row_body(r, _):
+                seq = sBlk[0, lo + r].astype(jnp.int32)
+                store_primary_row(r, d(4) + r * d(15) + seq * d(5),
+                                  valid=d(2))
+                return 0
+            jax.lax.fori_loop(0, m, row_body, 0)
 
         def k_embed():
             m = d(1)
-            ids = primary()                                # token ids (f32)
-            for r in range(TM):
-                @pl.when(r < m)
-                def _(r=r):
-                    tok = ids[0, r].astype(jnp.int32)
-                    load_row(sA, r, d(8) + tok * d(9), TN)
-                    store_row_vec(sA, r, d(4) + r * d(5), TN,
-                                  valid=d(2))
+            primary()                                      # token ids (f32)
+            lo = blk_load(d(6))
+
+            def row_body(r, _):
+                tok = sBlk[0, lo + r].astype(jnp.int32)
+                load_row(sA, r, d(8) + tok * d(9), TN)
+                store_row_vec(sA, r, d(4) + r * d(5), TN, valid=d(2))
+                return 0
+            jax.lax.fori_loop(0, m, row_body, 0)
 
         def k_softmax_topk():
             m, n = d(1), d(2)
             pa = primary()
-            masked = jnp.where(cols[None, :] < n, pa[:, :TN], -jnp.inf)
+            masked = jnp.where(cols < n, pa[:, :TN], -jnp.inf)
             sel = jnp.zeros((TM, TN, TOPK), jnp.float32)
             vals = jnp.zeros((TM, TOPK), jnp.float32)
             for i in range(TOPK):
@@ -907,16 +945,14 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 sel = sel.at[:, :, i].set(first.astype(jnp.float32))
                 masked = jnp.where(first, -jnp.inf, masked)
             w = jax.nn.softmax(vals, axis=1)                  # (TM, K)
-            out = jnp.einsum("mek,mk->me", sel, w)
-            acc[...] = out
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=n)
+            store_out(jnp.einsum("mek,mk->me", sel, w), m, n)
 
         def k_moe_gg():
             m, n, k = d(1), d(2), d(3)
             pa = primary()
             # router column for this expert -> per-token mask
             load_col(1, d(10), d(11), m, TM)
-            mask = (sC[1, :TM] > 0).astype(jnp.float32)[:, None]
+            mask = (sC[1, 0, :TM] > 0).astype(jnp.float32)[:, None]
             acc[...] = jnp.zeros((TM, TN), jnp.float32)
             acc2[...] = jnp.zeros((TM, TN), jnp.float32)
             for kc in range(KCH):
@@ -925,24 +961,19 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                     xa = pa[:, :TKC] * mask
                 else:
                     load_tile(sA, d(6) + k0, d(7), m, TM, TKC)
-                    xa = sA[:, :TKC] * mask
+                    xa = mat(sA, TM, TKC) * mask
                 load_tile(sB, d(8) + k0 * d(9), d(9),
                           jnp.clip(k - k0, 0, TKC), SB_ROWS, TN)
-                acc[...] += jax.lax.dot_general(
-                    xa, sB[:TKC, :], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                acc[...] += _dot(xa, mat(sB, TKC, TN), ((1,), (0,)))
                 @pl.when(d(15) == 1)
                 def _():
                     load_tile(sB, d(19) + k0 * d(9), d(9),
                               jnp.clip(k - k0, 0, TKC), SB_ROWS, TN)
-                    acc2[...] += jax.lax.dot_general(
-                        xa, sB[:TKC, :], (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
+                    acc2[...] += _dot(xa, mat(sB, TKC, TN), ((1,), (0,)))
             y = jnp.where(d(15) == 1,
                           _act(acc[...], d(14)) * acc2[...],
                           acc[...])
-            acc[...] = y
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=n)
+            store_out(y, m, n)
 
         def k_moe_combine():
             m, n, n_exp = d(1), d(2), d(3)
@@ -953,47 +984,61 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                           jnp.where(live, m, 0), TM, TN)
                 load_col(1, d(10) + e, d(11),
                          jnp.where(live, m, 0), TM)
-                acc[...] += sD[:TM, :TN] * sC[1, :TM][:, None]
-            store_tile(acc, d(4), d(5), m, TM, TN, valid=n)
+                acc[...] += mat(sD, TM, TN) * sC[1, 0, :TM][:, None]
+            store_out(acc[...], m, n)
 
         def k_ssm():
+            # the x tile's heads [0, NHT) hold HDS lanes each; the state
+            # update needs each head's x as a column, so the tile is
+            # transposed once and each (row, head) column is picked out
             m = d(1)
             pa = primary()                                 # x tile
-            load_row(sC, 2, d(12), TN)                     # A_log, loaded ONCE
-            a_log = sC[2, :]
+            xT = pa[:, :NHT * HDS].T                       # (NHT*HDS, TM)
+            hcol = jax.lax.broadcasted_iota(jnp.int32, (HDS, TM), 1)
+            _count(1)                                      # A_log row
             @pl.when(d(23) >= 0)
             def _():
-                load_row(sC, 3, d(23), TN)                 # D skip (own row)
-            @pl.when(d(23) < 0)
-            def _():
-                sC[3, :] = jnp.zeros((TN,), jnp.float32)
-            dsk = jnp.where(d(23) >= 0, sC[3, :], 0.0)
+                _count(1)                                  # D skip row
+            has_d = d(23) >= 0
+            heads = []
+            for hh in range(NHT):                          # per-head scalars
+                a_log = jnp.full((1, 1), peek(d(12) + hh), jnp.float32)
+                dsk = jnp.where(has_d, peek(jnp.maximum(d(23), 0) + hh),
+                                0.0)
+                heads.append((a_log, jnp.full((1, 1), dsk, jnp.float32)))
             for r in range(TM):
                 @pl.when(r < m)
                 def _(r=r):
-                    load_row(sC, 0, d(10) + r * d(11), TN)
-                    dt_row = sC[0, :]                       # dt (head slice)
+                    _count(1)                              # dt (head slice)
                     load_row(sC, 0, d(19) + r * d(20), TN)
-                    bvec = sC[0, :NS]
-                    load_row(sC, 0, d(21) + r * d(22), TN)
-                    cvec = sC[0, :NS]
-                    row_out = jnp.zeros((TN,), jnp.float32)
+                    bvec = sC[0, :, :NS]
+                    load_row(sC, 1, d(21) + r * d(22), TN)
+                    cvec = sC[1, :, :NS]
                     for hh in range(NHT):
+                        a_log, dsk = heads[hh]
                         base = d(8) + r * d(15) + hh * d(16)
                         load_tile(sB, base, d(9), HDS, SB_ROWS, NS)
-                        x_h = pa[r, hh * HDS : (hh + 1) * HDS]
-                        dt_sp = jax.nn.softplus(dt_row[hh])
-                        da = jnp.exp(dt_sp * (-jnp.exp(a_log[hh])))
-                        new_state = (sB[:HDS, :NS] * da
-                                     + (dt_sp * x_h)[:, None] * bvec[None, :])
-                        y_h = new_state @ cvec + dsk[hh] * x_h
-                        sB[:HDS, :NS] = new_state
+                        x_h = jnp.sum(jnp.where(
+                            hcol == r, xT[hh * HDS:(hh + 1) * HDS], 0.0),
+                            axis=1, keepdims=True)          # (HDS, 1)
+                        dt_sp = jax.nn.softplus(jnp.full(
+                            (1, 1), peek(d(10) + r * d(11) + hh),
+                            jnp.float32))
+                        da = jnp.exp(dt_sp * (-jnp.exp(a_log)))
+                        new_state = (mat(sB, HDS, NS) * da
+                                     + (dt_sp * x_h) * bvec)
+                        y_h = (_dot(new_state, cvec, ((1,), (1,)))
+                               + dsk * x_h)                 # (HDS, 1)
+                        sB[:HDS, :, :NS] = new_state.reshape(HDS, 1, NS)
                         store_tile(sB, base, d(9), HDS, SB_ROWS, NS)
-                        row_out = jax.lax.dynamic_update_slice(
-                            row_out, y_h, (hh * HDS,))
-                    acc[r, :] = row_out
-                    store_row_vec(acc, r, d(4) + r * d(5), TN,
-                                  valid=d(2))
+                        sY[hh * HDS:(hh + 1) * HDS, r:r + 1] = y_h
+            y = sY[...].T                                   # (TM, NHT*HDS)
+            sO[...] = jnp.zeros((TM, 1, TN), jnp.float32)
+            sO[:, :, :NHT * HDS] = y.reshape(TM, 1, NHT * HDS)
+            for r in range(TM):
+                @pl.when(r < m)
+                def _(r=r):
+                    store_row_vec(sO, r, d(4) + r * d(5), TN, valid=d(2))
 
         def k_conv():
             m = d(1)
@@ -1004,29 +1049,39 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 load_row(sC, 0, d(12), TN)
             @pl.when(d(12) < 0)
             def _():
-                sC[0, :] = jnp.zeros((TN,), jnp.float32)
-            bias = sC[0, :]
+                sC[0, :, :TN] = jnp.zeros((1, TN), jnp.float32)
+            bias = sC[0, :, :TN]
             for r in range(TM):
                 @pl.when(r < m)
                 def _(r=r):
                     base = d(8) + r * d(15)
                     load_tile(sD, base, d(9), WC, WC, TN)
-                    rows = [sD[j, :TN] for j in range(1, WC)] + [pa[r, :TN]]
+                    rows = ([sD[j, :, :TN] for j in range(1, WC)]
+                            + [pa[r:r + 1, :TN]])
                     y = bias
                     for j in range(WC):
-                        sD[j, :] = rows[j]
-                        y = y + rows[j] * sB[j, :TN]
+                        sD[j, :, :TN] = rows[j]
+                        y = y + rows[j] * sB[j, :, :TN]
                     store_tile(sD, base, d(9), WC, WC, TN, valid=d(2))
-                    acc[r, :] = jax.nn.silu(y)
-                    store_row_vec(acc, r, d(4) + r * d(5), TN,
+                    sO[r] = jax.nn.silu(y)
+                    store_row_vec(sO, r, d(4) + r * d(5), TN,
                                   valid=d(2))
 
+        branches = [k_noop, k_matmul, k_rmsnorm, k_rope, k_glu, k_resid,
+                    k_attn, k_cache_update, k_embed, k_softmax_topk,
+                    k_moe_gg, k_moe_combine, k_ssm, k_conv, k_remote_copy,
+                    k_ar_chunk]
+
+        present = sorted(kinds)
+
         def _dispatch():
-            jax.lax.switch(d(0), [
-                k_noop, k_matmul, k_rmsnorm, k_rope, k_glu, k_resid,
-                k_attn, k_cache_update, k_embed, k_softmax_topk, k_moe_gg,
-                k_moe_combine, k_ssm, k_conv, k_remote_copy, k_ar_chunk,
-            ])
+            # switch over the kinds this plan holds only: Mosaic lowers a
+            # switch as nested ifs, and its compiler crashes on a task
+            # branch nested 12 or more levels deep
+            idx = jnp.int32(0)
+            for i, code in enumerate(present):
+                idx = jnp.where(d(0) == code, jnp.int32(i), idx)
+            jax.lax.switch(idx, [branches[code] for code in present])
 
         if DYN:
             @pl.when(popped)
@@ -1042,50 +1097,40 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             overflow queue when the pool is full."""
             aw = desc[crow, 35]
             _qrow(0, QOFF + aw * QCAP)
-            free = sQ[0, :] >= _QTH
-            sE[0, pl.ds(4, 1)] = crow.astype(jnp.float32).reshape(1)
+            free = sQ[0, 0, :] >= _QTH
+            row_id = crow.astype(jnp.float32)
 
             @pl.when(jnp.any(free))
             def _():
                 fidx = jnp.argmax(free).astype(jnp.int32)
-                _qword_out(4, QOFF + aw * QCAP + fidx)
-                _rmw_add(QC_OFF + 2 * aw, 1.0)       # pushed cursor
+                poke(QOFF + aw * QCAP + fidx, row_id)
+                poke_add(QC_OFF + 2 * aw, 1.0)       # pushed cursor
 
             @pl.when(jnp.logical_not(jnp.any(free)))
             def _():                     # affinity pool full: overflow
                 for r in range(OV_ROWS):
                     _qrow(r, OVOFF + r * QCAP)
-                ofree = (sQ[:OV_ROWS, :] >= _QTH).reshape(-1)
+                ofree = (sQ[:OV_ROWS, 0, :] >= _QTH).reshape(-1)
                 oidx = jnp.argmax(ofree).astype(jnp.int32)
-                _qword_out(4, OVOFF + oidx)
-                _rmw_add(QC_OFF + 2 * W, 1.0)
+                poke(OVOFF + oidx, row_id)
+                poke_add(QC_OFF + 2 * W, 1.0)
 
         # ------------------------- event signal-and-enqueue (word 34)
         # After this task's stores have landed, increment its triggering
-        # event's in-heap counter (a read-modify-write through VMEM; on
+        # event's in-heap counter (a read-modify-write through SMEM; on
         # real parallel hardware this is the atomic the event table
-        # provides — interpret mode's sequential grid makes it exact).
-        # Under the dynamic scheduler, the producer whose increment
-        # brings the counter to the trigger count walks the event's
-        # consumer list and enqueues every newly-ready task.
+        # provides — the in-order grid makes it exact).  Under the
+        # dynamic scheduler, the producer whose increment brings the
+        # counter to the trigger count walks the event's consumer list
+        # and enqueues every newly-ready task.
         @pl.when(_gate(d(34) >= 0))
         def _():
-            cpi = pltpu.make_async_copy(
-                heap.at[pl.ds(EVENT_OFF + d(34), 1)],
-                sE.at[0, pl.ds(0, 1)], sem)
-            cpi.start()
-            cpi.wait()
-            sE[0, pl.ds(0, 1)] = sE[0, pl.ds(0, 1)] + 1.0
-            cpo = pltpu.make_async_copy(
-                sE.at[0, pl.ds(0, 1)],
-                heap.at[pl.ds(EVENT_OFF + d(34), 1)], sem)
-            cpo.start()
-            cpo.wait()
+            count = poke_add(EVENT_OFF + d(34), 1.0)
             cadd(7, 1.0)
             if DYN and MAX_OUT > 0:
                 trig = sched[d(34), 0].astype(jnp.float32)
 
-                @pl.when(sE[0, 0] == trig)
+                @pl.when(count == trig)
                 def _():                 # event fully triggered
                     def push_body(j, _):
                         @pl.when(j < sched[d(34), 1])
@@ -1094,35 +1139,34 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                         return 0
                     jax.lax.fori_loop(0, MAX_OUT, push_body, 0)
 
+        def blk_write(addr, words):
+            """heap[addr + j] = words[j] through the SMEM block."""
+            o = blk_load(addr)
+            for j, v in enumerate(words):
+                sBlk[0, o + j] = v
+            blk_store(addr)
+
         # ------------------- trace ring: assemble + store the record ---
         # Every grid slot writes its full TRACE_WORDS record (static
         # noops record kind 0; dynamic idle slots record row/kind -1) so
         # no stale data from a previous launch survives in the ring.
         if TRACE:
             _tick(4)                    # record word 4: end tick
-            sT[0, pl.ds(0, 1)] = w_id.astype(jnp.float32).reshape(1)
+            sT[0] = w_id.astype(jnp.float32)
             if DYN:
-                sT[0, pl.ds(1, 1)] = jnp.where(
-                    popped, t.astype(jnp.float32), -1.0).reshape(1)
-                sT[0, pl.ds(2, 1)] = jnp.where(
-                    popped, d(0).astype(jnp.float32), -1.0).reshape(1)
-                sT[0, pl.ds(5, 1)] = sS[0].astype(
-                    jnp.float32).reshape(1)
+                sT[1] = jnp.where(popped, t.astype(jnp.float32), -1.0)
+                sT[2] = jnp.where(popped, d(0).astype(jnp.float32), -1.0)
+                sT[5] = sS[0].astype(jnp.float32)
             else:
-                sT[0, pl.ds(1, 1)] = t.astype(jnp.float32).reshape(1)
-                sT[0, pl.ds(2, 1)] = d(0).astype(jnp.float32).reshape(1)
-                sT[0, pl.ds(5, 1)] = jnp.full((1,), -1.0, jnp.float32)
-            sT[0, pl.ds(6, 1)] = jnp.where(
-                _gate(d(32) >= 0), d(33).astype(jnp.float32),
-                0.0).reshape(1)
-            sT[0, pl.ds(7, 1)] = jnp.zeros((1,), jnp.float32)
-            cpr = pltpu.make_async_copy(
-                sT.at[0, pl.ds(0, TRACE_WORDS)],
-                heap.at[pl.ds(TR_OFF + TRACE_HEADER
-                              + (s * W + w_id) * TRACE_WORDS,
-                              TRACE_WORDS)], sem)
-            cpr.start()
-            cpr.wait()
+                sT[1] = t.astype(jnp.float32)
+                sT[2] = d(0).astype(jnp.float32)
+                sT[5] = jnp.float32(-1.0)
+            sT[6] = jnp.where(_gate(d(32) >= 0),
+                              d(33).astype(jnp.float32), 0.0)
+            sT[7] = jnp.float32(0.0)
+            blk_write(TR_OFF + TRACE_HEADER
+                      + (s * W + w_id) * TRACE_WORDS,
+                      [sT[j] for j in range(TRACE_WORDS)])
 
         # flush the per-worker counter blocks to their reserved heap
         # slots — only the final grid iteration: the totals accumulate in
@@ -1130,39 +1174,37 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         @pl.when(jnp.logical_and(s == num_steps - 1, w_id == W - 1))
         def _():
             for ww in range(W):
-                cp = pltpu.make_async_copy(
-                    cnt.at[ww, pl.ds(0, STATS_WORDS)],
-                    heap.at[pl.ds(STATS_OFF + ww * STATS_WORDS,
-                                  STATS_WORDS)], sem)
-                cp.start()
-                cp.wait()
+                blk_write(STATS_OFF + ww * STATS_WORDS,
+                          [cnt[ww, j] for j in range(STATS_WORDS)])
 
     sd_rows = max(TM, TS, WC, 8)
     scratch_shapes = [
-        pltpu.VMEM((TM, TNK), jnp.float32),        # sA
-        pltpu.VMEM((SB_ROWS, TN), jnp.float32),    # sB
-        pltpu.VMEM((max(8, TM), max(TN, TM)), jnp.float32),  # sC
-        pltpu.VMEM((sd_rows, TN), jnp.float32),    # sD
-        pltpu.VMEM((TM, TN), jnp.float32),         # acc
-        pltpu.VMEM((TM, TN), jnp.float32),         # acc2
-        pltpu.VMEM((W, 2, TM, TN), jnp.float32),   # sP (per-worker
-                                                   #     double buffer)
-        pltpu.VMEM((1, 8), jnp.float32),           # sE (event counter)
-        pltpu.VMEM((W, STATS_WORDS), jnp.float32),  # cnt (per-worker)
-        pltpu.VMEM((2, COMM_BLOCK), jnp.float32),  # sR (comm span blocks)
-        pltpu.SemaphoreType.DMA,                   # sem (bulk tiles)
-        pltpu.SemaphoreType.DMA((W, 2)),           # psem (worker, slot)
-        pltpu.SemaphoreType.DMA,                   # rsend (remote DMA)
-        pltpu.SemaphoreType.DMA,                   # rrecv (remote DMA)
+        pltpu.VMEM((TM, 1, TNK), jnp.float32),        # sA
+        pltpu.VMEM((SB_ROWS, 1, TN), jnp.float32),    # sB
+        pltpu.VMEM((max(8, TM), 1, max(TN, TM)), jnp.float32),  # sC
+        pltpu.VMEM((sd_rows, 1, TN), jnp.float32),    # sD
+        pltpu.VMEM((TM, TN), jnp.float32),            # acc (matmul sums)
+        pltpu.VMEM((TM, TN), jnp.float32),            # acc2
+        pltpu.VMEM((TM, 1, TN), jnp.float32),         # sO (output rows)
+        pltpu.VMEM((NHT * HDS, TM), jnp.float32),     # sY (SSM out cols)
+        pltpu.VMEM((W, 2, TM, 1, TN), jnp.float32),   # sP (per-worker
+                                                      #     double buffer)
+        pltpu.SMEM((1, _BLK), jnp.float32),           # sBlk (scalar words)
+        pltpu.SMEM((W, STATS_WORDS), jnp.float32),    # cnt (per-worker)
+        pltpu.VMEM((2, 1, COMM_BLOCK), jnp.float32),  # sR (comm span blocks)
+        pltpu.SemaphoreType.DMA,                      # sem (bulk tiles)
+        pltpu.SemaphoreType.DMA((W, 2)),              # psem (worker, slot)
+        pltpu.SemaphoreType.DMA,                      # rsend (remote DMA)
+        pltpu.SemaphoreType.DMA,                      # rrecv (remote DMA)
     ]
     if DYN:
         scratch_shapes += [
-            pltpu.VMEM((OV_ROWS, QCAP), jnp.float32),  # sQ (pool scans)
-            pltpu.SMEM((8,), jnp.int32),               # sS (pop state)
+            pltpu.VMEM((OV_ROWS, 1, QCAP), jnp.float32),  # sQ (pool scans)
+            pltpu.SMEM((8,), jnp.int32),                  # sS (pop state)
         ]
     if TRACE:
         scratch_shapes += [
-            pltpu.VMEM((1, TRACE_WORDS), jnp.float32),  # sT (trace rec)
+            pltpu.SMEM((TRACE_WORDS,), jnp.float32),      # sT (trace rec)
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2 if DYN else 1,
@@ -1174,7 +1216,8 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
     return functools.partial(
         pl.pallas_call,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((heap_size,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, HEAP), jnp.float32),
         input_output_aliases={2 if DYN else 1: 0},
-        interpret=True,
+        interpret=interpret,
+        name="mpk_megakernel",
     )(kernel)

@@ -36,11 +36,12 @@ from ...core.compile import CompileOptions, megakernelize
 from ...core.decompose import DecomposeConfig
 from ...core.lowering import build_decode_graph
 from .desc import (STATS_WORDS, TRACE_HEADER, TRACE_WORDS, MegakernelPlan,
-                   lower_tgraph, stamp_multichip)
+                   heap_words, lower_tgraph, stamp_multichip)
 from .kernel import make_megakernel
 
 __all__ = ["compile_decode_megakernel", "MegakernelExecutor",
-           "STATS_FIELDS", "decode_stats_row", "read_stats_block"]
+           "STATS_FIELDS", "decode_stats_row", "read_stats_block",
+           "interprets_on"]
 
 #: named field map of the per-worker STATS block: counter name → word
 #: index.  Word 4 (``ROW_SPILL_WORD``) is the 2^20-unit spill of
@@ -75,12 +76,26 @@ def decode_stats_row(v) -> Dict[str, int]:
 
 def read_stats_block(heap, stats_offset: int,
                      num_workers: int) -> List[Dict[str, int]]:
-    """Read + decode the per-worker STATS blocks from a heap (array or
-    device buffer): one named-counter dict per worker lane."""
+    """Read + decode the per-worker STATS blocks from a heap (flat or
+    ``(1, N)``, host array or device buffer): one named-counter dict per
+    worker lane."""
     flat = np.asarray(
-        heap[stats_offset : stats_offset + num_workers * STATS_WORDS])
+        heap[..., stats_offset : stats_offset + num_workers * STATS_WORDS]
+    ).reshape(-1)
     return [decode_stats_row(flat[w * STATS_WORDS : (w + 1) * STATS_WORDS])
             for w in range(num_workers)]
+
+
+def interprets_on(platform: str) -> bool:
+    """Whether the megakernel runs in the Pallas interpreter on a device
+    of ``platform``: the CPU interprets, a TPU runs the compiled kernel,
+    and nothing else runs it at all."""
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"the megakernel runs on a TPU (compiled) or the "
+                       f"CPU (interpreted), not on {platform!r}")
 
 
 def compile_decode_megakernel(cfg, batch: int, max_seq: int,
@@ -143,11 +158,17 @@ class MegakernelExecutor:
         ex.upload(plan.build_heap(bindings))   # ONE full heap upload
         logits = ex.step(tokens, seq_lens)     # partial update + 1 launch
         logits = ex.step(tokens, seq_lens + 1) # state carried in-heap
+
+    The heap lives on the default device as one ``(1, N)`` f32 row; the
+    device's platform decides once whether the kernel is interpreted
+    (CPU) or compiled (TPU) — see :func:`interprets_on`.
     """
 
     def __init__(self, plan: MegakernelPlan, cfg):
         self.plan = plan
         self.cfg = cfg
+        self.device = jax.devices()[0]
+        self.interpret = interprets_on(self.device.platform)
         self.trace_count = 0       # jit traces of the step function
         self.upload_count = 0      # full heap uploads (weights included)
         self.step_count = 0
@@ -162,112 +183,125 @@ class MegakernelExecutor:
         self._chip_offsets = (np.arange(self._n_chips, dtype=np.int64)
                               * plan.chip_stride)
 
-        # ---- flat heap indices of every per-step input element ----
-        idx_parts, self._entries = [], []
-        for name in self._per_step:
-            slot = plan.layout[name]
-            cols = slot.shape[-1] if slot.shape else 1
-            grid = (slot.offset
-                    + np.arange(slot.rows)[:, None] * slot.ld
-                    + np.arange(cols)[None, :])
-            self._entries.append((name, slot.rows, cols))
-            idx_parts.append((grid.ravel()[None, :]
-                              + self._chip_offsets[:, None]).ravel())
-        # the in-heap event-counter table is re-zeroed through the same
-        # per-step scatter (the kernel increments counters during the
-        # launch, so every launch starts from a clean table)
+        # ---- heap spans rewritten before every launch ----
+        # Every update of the resident heap is a contiguous span written
+        # with ``dynamic_update_slice``: an index scatter into the (1, N)
+        # heap makes XLA:TPU relayout the whole heap into a temporary.
+        # Per-step inputs are written as whole row slots (pad columns 0).
+        self._entries = [(name, plan.layout[name])
+                         for name in self._per_step]
+        spans = [(sl.offset + c, sl.rows * sl.ld)
+                 for c in self._chip_offsets for _, sl in self._entries]
+        # the in-heap event-counter table is re-zeroed with the inputs
+        # (the kernel increments counters during the launch, so every
+        # launch starts from a clean table)
         self._n_events = plan.num_events
         if self._n_events:
-            idx_parts.append(np.arange(plan.event_offset,
-                                       plan.event_offset + self._n_events))
+            spans.append((plan.event_offset, self._n_events))
         # dynamic scheduler: the ready pools, overflow queue and cursor
-        # counters are consumed during the launch — the same scatter
+        # counters are consumed during the launch — the same update
         # re-writes the initial queue image before every step
         self._dynamic = plan.scheduler == "dynamic"
         if self._dynamic:
             pools, counters = plan.dyn.queue_image()
             self._queue_reset = np.concatenate([pools, counters])
-            idx_parts.append(np.arange(
-                plan.queue_offset,
-                plan.queue_offset + self._queue_reset.size))
+            spans.append((plan.queue_offset, self._queue_reset.size))
             self._sched = jnp.asarray(plan.dyn.sched_table())
         # trace ring: only the logical tick counter at the ring head
         # needs re-zeroing — the kernel rewrites every record slot each
         # launch (idle/noop slots included)
         if plan.trace:
-            idx_parts.append(np.arange(plan.ring_offset,
-                                       plan.ring_offset + 1))
-        self._upd_idx = jnp.asarray(
-            np.concatenate(idx_parts).astype(np.int32))
+            spans.append((plan.ring_offset, 1))
+        self._step_spans = spans
         self._descs = jnp.asarray(plan.descs)
 
-        # ---- per-slot state element indices (for init/reset zeroing) ----
+        # ---- state tensors: whole spans (read/write), per-slot spans
+        # (reset) ----
         self._batch = g.spec("seq_lens").shape[0]
-        self._state_idx = [self._state_indices(b)
-                           for b in range(self._batch)]
-        self._state_idx_all = np.concatenate(self._state_idx) \
-            if self._state_idx else np.zeros((0,), np.int32)
-        # ---- whole-state spans (for write_state scatter) ----
         self._state_spans = []
-        span_idx = []
         for name in self._state_inputs:
             slot = plan.layout[name]
             cols = slot.shape[-1] if slot.shape else 1
-            self._state_spans.append((name, slot.rows, slot.ld, cols))
-            span_idx.append(np.arange(slot.offset,
-                                      slot.offset + slot.rows * slot.ld))
-        if span_idx:
-            span0 = np.concatenate(span_idx)
-            self._state_span_idx = jnp.asarray(span0.astype(np.int32))
-            self._state_span_idx_all = jnp.asarray(
-                (span0[None, :] + self._chip_offsets[:, None])
-                .ravel().astype(np.int32))
-        else:
-            self._state_span_idx = None
-            self._state_span_idx_all = None
+            self._state_spans.append((name, slot.offset, slot.rows,
+                                      slot.ld, cols))
+        slot_spans = []
+        for name in self._state_inputs:
+            slot = plan.layout[name]
+            rpb = slot.rows // slot.shape[0]   # heap rows per batch entry
+            slot_spans += [(slot.offset + c, rpb * slot.ld)
+                           for c in self._chip_offsets]
         self.state_scatter_count = 0
 
         # ---- the ONE kernel + the ONE jitted step ----
-        kern = make_megakernel(plan.statics, plan.num_steps,
-                               plan.heap_size)
+        self._jstep = jax.jit(self.step_fn(self.interpret),
+                              donate_argnums=(0,))
+
+        def zero_slot(heap, b):
+            for off, n in slot_spans:
+                heap = jax.lax.dynamic_update_slice(
+                    heap, jnp.zeros((1, n), heap.dtype), (0, off + b * n))
+            return heap
+
+        def set_state(heap, vals):
+            for c in self._chip_offsets:
+                for v, (_, off, _, _, _) in zip(vals, self._state_spans):
+                    heap = jax.lax.dynamic_update_slice(
+                        heap, v[None], (0, off + c))
+            return heap
+
+        self._jzero = jax.jit(zero_slot, donate_argnums=(0,))
+        self._jset = jax.jit(set_state, donate_argnums=(0,))
+        self._heap: Optional[jax.Array] = None
+
+    def step_fn(self, interpret: bool):
+        """The decode step before ``jit``: write the per-step inputs into
+        the ``(1, heap_words(heap_size))`` heap, launch the kernel once,
+        slice out the logits.  ``(heap, vals) -> (heap, logits)``.
+        ``interpret`` picks the Pallas interpreter or the compiled
+        kernel; the executor passes its device's choice."""
+        plan = self.plan
+        if not interpret:
+            errs = plan.compiled_layout_errors()
+            if errs:
+                raise ValueError("plan cannot run on the compiled TPU "
+                                 "kernel: " + "; ".join(errs))
+        kern = make_megakernel(plan.statics, plan.num_steps, plan.heap_size,
+                               interpret=interpret,
+                               kinds=np.unique(plan.descs[:, 0]))
         lg = plan.layout["logits"]
         lg_cols = lg.shape[-1]
 
         def _step(heap, vals):
             self.trace_count += 1  # python side effect: runs at trace only
-            heap = heap.at[self._upd_idx].set(vals)
+            pos = 0
+            for off, n in self._step_spans:
+                heap = jax.lax.dynamic_update_slice(
+                    heap, vals[None, pos : pos + n], (0, off))
+                pos += n
             if self._dynamic:
                 heap = kern(self._descs, self._sched, heap)
             else:
                 heap = kern(self._descs, heap)
-            logits = heap[lg.offset : lg.offset + lg.rows * lg.ld]
+            logits = heap[:, lg.offset : lg.offset + lg.rows * lg.ld]
             logits = logits.reshape(lg.rows, lg.ld)[:, :lg_cols]
             return heap, logits
 
-        self._jstep = jax.jit(_step, donate_argnums=(0,))
-        self._jzero = jax.jit(
-            lambda heap, idx: heap.at[idx].set(0.0), donate_argnums=(0,))
-        self._jset = jax.jit(
-            lambda heap, idx, vals: heap.at[idx].set(vals),
-            donate_argnums=(0,))
-        self._heap: Optional[jax.Array] = None
+        return _step
+
+    @property
+    def step_input_words(self) -> int:
+        """Length of the packed per-step input vector ``step_fn`` takes."""
+        return sum(n for _, n in self._step_spans)
+
+    def lowered_step(self):
+        """The jitted decode step lowered (not compiled) for this plan's
+        heap and input shapes."""
+        return self._jstep.lower(
+            jax.ShapeDtypeStruct((1, heap_words(self.plan.heap_size)),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((self.step_input_words,), jnp.float32))
 
     # ------------------------------------------------------------ helpers
-    def _state_indices(self, b: int) -> np.ndarray:
-        """Flat heap indices of batch row ``b`` of every state tensor
-        (replicated across every chip's heap region)."""
-        parts = []
-        for name in self._state_inputs:
-            slot = self.plan.layout[name]
-            rpb = slot.rows // slot.shape[0]   # heap rows per batch entry
-            lo = slot.offset + b * rpb * slot.ld
-            parts.append(np.arange(lo, lo + rpb * slot.ld))
-        if not parts:
-            return np.zeros((0,), np.int32)
-        flat = np.concatenate(parts)
-        return (flat[None, :] + self._chip_offsets[:, None]) \
-            .ravel().astype(np.int32)
-
     def _pack_step_inputs(self, tokens_or_embeds, seq_lens,
                           positions=None) -> jax.Array:
         lens = np.asarray(seq_lens, np.int32)
@@ -282,10 +316,14 @@ class MegakernelExecutor:
             if self.cfg.mrope_sections is not None and pos.ndim == 1:
                 pos = np.stack([pos] * 3, axis=-1)
             vals["positions"] = pos
-        flat = [np.tile(np.asarray(vals[name],
-                                   np.float32).reshape(rows * cols),
-                        self._n_chips)
-                for name, rows, cols in self._entries]
+        flat = []
+        for name, sl in self._entries:
+            img = np.zeros((sl.rows, sl.ld), np.float32)
+            cols = sl.shape[-1] if sl.shape else 1
+            img[:, :cols] = np.asarray(vals[name],
+                                       np.float32).reshape(sl.rows, cols)
+            flat.append(img.ravel())
+        flat = flat * self._n_chips
         if self._n_events:
             flat.append(np.zeros((self._n_events,), np.float32))
         if self._dynamic:
@@ -296,18 +334,22 @@ class MegakernelExecutor:
 
     # ------------------------------------------------------------- public
     def upload(self, heap: np.ndarray) -> None:
-        """Full heap upload — happens once per ``bind`` (weights + state)."""
-        self._heap = jnp.asarray(np.asarray(heap, np.float32))
+        """Full heap upload — happens once per ``bind`` (weights + state).
+        ``heap`` is the flat ``heap_words(heap_size)``-word host image
+        (``plan.build_heap``)."""
+        heap = np.asarray(heap, np.float32)
+        assert heap.shape == (heap_words(self.plan.heap_size),), heap.shape
+        self._heap = jax.device_put(heap.reshape(1, -1), self.device)
         self.upload_count += 1
 
     def reset_state(self, slot: Optional[int] = None) -> None:
         """Zero cache/conv/SSM state in place on device (one batch row, or
-        all of them in a single scatter) — a partial update, not a
-        re-upload."""
+        all of them) — a partial update, not a re-upload."""
         assert self._heap is not None, "upload() before reset_state()"
-        idx = self._state_idx_all if slot is None else self._state_idx[slot]
-        if idx.size:
-            self._heap = self._jzero(self._heap, jnp.asarray(idx))
+        if not self._state_spans:
+            return
+        for b in range(self._batch) if slot is None else (slot,):
+            self._heap = self._jzero(self._heap, jnp.int32(b))
 
     def step(self, tokens_or_embeds, seq_lens, positions=None) -> np.ndarray:
         """One decode step inside the persistent kernel; returns logits
@@ -351,7 +393,7 @@ class MegakernelExecutor:
         assert self._heap is not None, "upload() before counters"
         W = self.plan.num_workers
         off = self.plan.qc_offset
-        qc = np.asarray(self._heap[off : off + 2 * (W + 1)])
+        qc = np.asarray(self._heap[:, off : off + 2 * (W + 1)])[0]
         per = self.pipeline_counters()
         return {
             "queue_pushed": [int(qc[2 * i]) for i in range(W + 1)],
@@ -373,7 +415,7 @@ class MegakernelExecutor:
         assert self._heap is not None, "upload() before pop_trace()"
         off = self.plan.trace_offset
         n = self.plan.num_steps * self.plan.num_workers
-        tr = np.asarray(self._heap[off : off + n])
+        tr = np.asarray(self._heap[:, off : off + n])[0]
         return np.where(tr >= QUEUE_EMPTY / 2, -1, tr).astype(np.int64)
 
     def task_ring(self) -> np.ndarray:
@@ -385,51 +427,46 @@ class MegakernelExecutor:
         assert self._heap is not None, "upload() before task_ring()"
         off = self.plan.ring_offset + TRACE_HEADER
         n = self.plan.num_steps * self.plan.num_workers
-        flat = np.asarray(self._heap[off : off + n * TRACE_WORDS])
+        flat = np.asarray(self._heap[:, off : off + n * TRACE_WORDS])[0]
         return flat.reshape(n, TRACE_WORDS)
 
     def read_heap(self) -> np.ndarray:
-        """Host copy of the resident heap (state inspection / snapshots)."""
+        """Flat host copy of the resident heap (state inspection /
+        snapshots)."""
         assert self._heap is not None, "upload() before read_heap()"
-        return np.array(self._heap)  # writable host copy
+        return np.array(self._heap)[0]  # writable host copy
 
     def write_heap(self, heap: np.ndarray) -> None:
         """Replace the resident heap (state restore); counts as an upload."""
         self.upload(heap)
 
     def read_state(self) -> Dict[str, np.ndarray]:
-        """Gather every state tensor from the resident heap — a device
-        gather of the state spans only (O(state), weights never move).
-        Returns graph-shaped arrays keyed by state input name."""
+        """Read every state tensor from the resident heap (chip 0) — its
+        span only (O(state), weights never move).  Returns graph-shaped
+        arrays keyed by state input name."""
         assert self._heap is not None, "upload() before read_state()"
         out: Dict[str, np.ndarray] = {}
-        if self._state_span_idx is None:
-            return out
-        flat = np.asarray(self._heap[self._state_span_idx])
-        off = 0
-        for name, rows, ld, cols in self._state_spans:
-            img = flat[off : off + rows * ld].reshape(rows, ld)
-            out[name] = img[:, :cols].reshape(
+        for name, off, rows, ld, cols in self._state_spans:
+            img = np.asarray(self._heap[:, off : off + rows * ld])
+            out[name] = img.reshape(rows, ld)[:, :cols].reshape(
                 self.plan.layout[name].shape)
-            off += rows * ld
         return out
 
     def write_state(self, tensors: Dict[str, np.ndarray]) -> None:
-        """Scatter new values for every state tensor into the resident
+        """Write new values for every state tensor into the resident
         heap (partial update — weights are never re-moved, the write is
         replicated into every chip's heap region).  ``tensors`` maps
         state input names to graph-shaped arrays."""
         assert self._heap is not None, "upload() before write_state()"
-        if self._state_span_idx is None:
+        if not self._state_spans:
             return
-        parts = []
-        for name, rows, ld, cols in self._state_spans:
+        vals = []
+        for name, _, rows, ld, cols in self._state_spans:
             img = np.zeros((rows, ld), np.float32)  # pad columns stay 0
             img[:, :cols] = np.asarray(tensors[name],
                                        np.float32).reshape(rows, cols)
-            parts.append(img.ravel())
-        vals = jnp.asarray(np.tile(np.concatenate(parts), self._n_chips))
-        self._heap = self._jset(self._heap, self._state_span_idx_all, vals)
+            vals.append(jnp.asarray(img.ravel()))
+        self._heap = self._jset(self._heap, vals)
         self.state_scatter_count += 1
 
     def run_once(self, bindings: Dict[str, np.ndarray]

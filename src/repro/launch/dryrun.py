@@ -155,8 +155,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     def costs_of(c):
         cost = c.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one dict per device
-            cost = cost[0] if cost else {}
         coll = parse_hlo_collectives(c.as_text())
         return (float(cost.get("flops", 0.0)),
                 float(cost.get("bytes accessed", 0.0)),

@@ -9,8 +9,9 @@ heap).  Chunked prefill (``--chunk`` prompt tokens per iteration,
 page-pressure preemption (``--total-pages`` oversubscribes the KV page
 pool), and per-request latency metrics (TTFT / TPOT / queue time) over a
 Poisson-arrival workload (``--arrival-rate`` req/s; 0 = all requests
-arrive at t=0).  ``--crosscheck`` additionally decodes a batch through
-every backend and asserts logits parity.
+arrive at t=0).  ``--crosscheck`` additionally decodes a prefilled batch
+through the backend and the f32 reference and asserts logits parity
+(``api.parity.decode_parity``).
 """
 from __future__ import annotations
 
@@ -26,9 +27,13 @@ import numpy as np
 
 def poisson_workload(rng: np.random.Generator, n_requests: int,
                      prompt_len: int, max_new: int, vocab: int,
-                     arrival_rate: float) -> List["Request"]:
+                     arrival_rate: float,
+                     prompt_len_max: Optional[int] = None
+                     ) -> List["Request"]:
     """Requests with exponential inter-arrival gaps (a Poisson process);
-    ``arrival_rate <= 0`` degenerates to an offline batch at t=0."""
+    ``arrival_rate <= 0`` degenerates to an offline batch at t=0.  Prompt
+    lengths are ``prompt_len``, or uniform in ``[prompt_len,
+    prompt_len_max]`` when that is given."""
     from repro.runtime import Request
 
     t = 0.0
@@ -36,7 +41,9 @@ def poisson_workload(rng: np.random.Generator, n_requests: int,
     for rid in range(n_requests):
         if arrival_rate > 0:
             t += float(rng.exponential(1.0 / arrival_rate))
-        prompt = rng.integers(1, vocab, size=prompt_len).tolist()
+        n = prompt_len if prompt_len_max is None else int(
+            rng.integers(prompt_len, prompt_len_max + 1))
+        prompt = rng.integers(1, vocab, size=n).tolist()
         reqs.append(Request(rid, prompt, max_new_tokens=max_new,
                             arrival_time=t))
     return reqs
@@ -114,13 +121,16 @@ def main() -> None:
                          "other backends export their own timeline)")
     ap.add_argument("--crosscheck", "--megakernel", dest="crosscheck",
                     action="store_true",
-                    help="decode a batch through every backend and assert "
-                         "logits parity (--megakernel is the deprecated "
-                         "alias)")
+                    help="decode a prefilled batch through the backend "
+                         "and the f32 reference and assert logits parity "
+                         "(--megakernel is the deprecated alias)")
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import init_params
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     assert not cfg.embed_input, "serve demo uses token-input archs"
@@ -221,26 +231,27 @@ def main() -> None:
               f"(open at https://ui.perfetto.dev)")
 
     if args.crosscheck:
-        from repro.api import BACKENDS, compile as mpk_compile
+        from repro.api import compile as mpk_compile
+        from repro.api.parity import PARITY_TOL, decode_parity
 
-        b, s, n_steps = 2, 16, 4
-        progs = {bk: mpk_compile(cfg, b, s, backend=bk).bind(params)
-                 .init_state() for bk in BACKENDS}
-        lens = np.zeros((b,), np.int32)
-        err = 0.0
-        for _ in range(n_steps):
-            toks = np.asarray(rng.integers(1, cfg.vocab, size=b), np.int32)
-            outs = {bk: p.step(toks, lens) for bk, p in progs.items()}
-            for bk in BACKENDS[1:]:
-                err = max(err, float(
-                    np.abs(outs[bk] - outs["jax"]).max()))
-            lens += 1
-        mk = progs["megakernel"]
-        print(f"[serve] crosscheck: {n_steps}-step decode, "
-              f"{len(mk.plan.compiled.order)} tasks/launch, "
-              f"{mk.trace_count} trace / {mk.upload_count} upload, "
-              f"max |logits - jax| = {err:.2e}")
-        assert err < 3e-4, "backend logits diverged"
+        # prefill one chunk per slot through the served program, then
+        # decode the same tokens through it and the f32 reference
+        program.init_state()
+        n = min(args.chunk, args.max_seq // 2)
+        prompt = rng.integers(1, cfg.vocab, size=(args.slots, n),
+                              dtype=np.int32)
+        lens = rng.integers(1, n + 1, size=args.slots, dtype=np.int32)
+        program.prefill(prompt, np.zeros(args.slots, np.int32), lens)
+        ref = mpk_compile(cfg, args.slots, args.max_seq,
+                          backend="jax").bind(params)
+        toks = rng.integers(1, cfg.vocab, size=(4, args.slots),
+                            dtype=np.int32)
+        errs = decode_parity(program, ref, toks, lens)
+        print(f"[serve] crosscheck: 4-step decode on {args.backend}, "
+              f"max|logits - ref| / max|ref| = {errs['highest']:.2e} vs "
+              f"the f32 reference, {errs['default']:.2e} at DEFAULT "
+              f"matmul precision (tolerance {PARITY_TOL:.0e})")
+        assert errs["highest"] <= PARITY_TOL, "backend logits diverged"
 
 
 if __name__ == "__main__":

@@ -45,8 +45,11 @@ def main() -> None:
 
     from repro.api import compile as mpk_compile
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import init_params
     from repro.obs import check_event_order, reconcile, write_chrome_trace
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     assert not cfg.embed_input, "trace demo uses token-input archs"

@@ -128,9 +128,7 @@ def blockwise_attention(
         ci, k_blk, v_blk = inp
         # logits: (B, S, KV, G, chunk)
         logits = jnp.einsum(
-            "bskgd,bckd->bskgc", qg, k_blk.astype(jnp.float32),
-            precision=jax.lax.Precision.DEFAULT,
-        )
+            "bskgd,bckd->bskgc", qg, k_blk.astype(jnp.float32))
         k_idx = ci * chunk + jnp.arange(chunk)
         valid = k_idx < s
         if causal:

@@ -278,7 +278,10 @@ def _ssm_chunk(h, p, states, cfg, valid):
     return ys.swapaxes(0, 1), states
 
 
-def _ffn(h, kind, p, cfg, policy, mesh):
+def _ffn(h, kind, p, cfg, policy, mesh, dropless=False):
+    """``dropless`` sizes MoE expert capacity so no token is dropped (the
+    serving path, like the tGraph interpreter and the megakernel);
+    otherwise ``cfg.capacity_factor`` applies (training)."""
     if kind == "none":
         return h
     shape = h.shape
@@ -291,12 +294,15 @@ def _ffn(h, kind, p, cfg, policy, mesh):
         y = (act(hh[..., 0, :]) * hh[..., 1, :]) @ p["wo"]
     else:  # moe
         x2d = x.reshape(-1, shape[-1])
+        # capacity T·top_k·cf/E >= T: every token fits in every expert
+        cf = cfg.n_experts / cfg.top_k if dropless else None
         if mesh is not None and policy is not None:
             y = moe_ffn(x2d, p, cfg, mesh=mesh, ep_axis=policy.ep_axis,
                         dp_axes=policy.dp, fsdp_axes=policy.fsdp_axes,
-                        two_d=getattr(policy, "moe_2d", False))
+                        two_d=getattr(policy, "moe_2d", False),
+                        capacity_factor=cf)
         else:
-            y = moe_ffn(x2d, p, cfg, mesh=None)
+            y = moe_ffn(x2d, p, cfg, mesh=None, capacity_factor=cf)
         y = y.reshape(shape)
     return h + (policy.act(y, "resid") if policy else y)
 
@@ -480,7 +486,8 @@ def prefill_chunk(params, cfg, cache, tokens_or_embeds, seq_lens,
                 h = _ffn(h, "mlp", _take(bp["mlp"], mi), cfg, policy, mesh)
                 mi += 1
             elif fk == "moe":
-                h = _ffn(h, "moe", _take(bp["moe"], ei), cfg, policy, mesh)
+                h = _ffn(h, "moe", _take(bp["moe"], ei), cfg, policy, mesh,
+                         dropless=True)
                 ei += 1
         return h, new_cache
 

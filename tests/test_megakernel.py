@@ -91,3 +91,17 @@ def test_descriptor_prefetch_stats():
     # every non-dummy task maps to a known kind (14/15 are the COMM
     # kinds of the multi-chip subsystem)
     assert set(np.unique(prog.descs[:, 0])) <= set(range(16))
+
+
+def test_kernel_mode_follows_the_heap_device():
+    """The CPU interprets the kernel, a TPU runs it compiled, and no other
+    platform runs it at all — there is no fallback."""
+    from repro.kernels.megakernel.ops import interprets_on
+
+    assert interprets_on("cpu") is True
+    assert interprets_on("tpu") is False
+    with pytest.raises(RuntimeError, match="gpu"):
+        interprets_on("gpu")
+    cfg = get_config("deepseek-7b").reduced()
+    ex = MegakernelExecutor(compile_decode_megakernel(cfg, 2, 16), cfg)
+    assert ex.device.platform == "cpu" and ex.interpret

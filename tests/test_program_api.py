@@ -271,3 +271,27 @@ def test_workspace_liveness_reuse():
             if o2 >= o1 + s1:
                 break  # sorted by offset: no further spatial overlap
             assert not (a1 <= b2 and a2 <= b1), "live tensors overlap"
+
+
+@pytest.mark.parametrize("backend", ["interpreter", "megakernel"])
+def test_decode_parity_against_f32_reference(backend):
+    """``api.parity.decode_parity`` — the check ``chip_smoke.py`` and
+    ``mpk-serve --crosscheck`` run — from a prefilled state: the program
+    decodes teacher-forced tokens within ``PARITY_TOL`` of the jax
+    reference, and advances by exactly those steps."""
+    from repro.api.parity import PARITY_TOL, decode_parity
+
+    cfg = _cfg("deepseek-7b", layers=2)
+    params = _params(cfg)
+    prog = mpk.compile(cfg, 3, 24, backend=backend).bind(params).init_state()
+    rng = np.random.default_rng(0)
+    lens = np.array([4, 1, 3], np.int32)
+    prog.prefill(rng.integers(1, cfg.vocab, size=(3, 4)).astype(np.int32),
+                 np.zeros(3, np.int32), lens)
+    ref = mpk.compile(cfg, 3, 24, backend="jax").bind(params)
+    toks = rng.integers(1, cfg.vocab, size=(3, 3)).astype(np.int32)
+    steps0 = prog.step_count
+    errs = decode_parity(prog, ref, toks, lens)
+    assert prog.step_count == steps0 + len(toks)
+    assert set(errs) == {"highest", "default"}
+    assert 0.0 <= errs["highest"] <= PARITY_TOL

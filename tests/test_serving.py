@@ -85,3 +85,38 @@ def test_submit_rejects_oversized_request():
                         page_size=16)
     with np.testing.assert_raises(ValueError):
         eng.submit(Request(0, list(range(1, 13)), max_new_tokens=8))
+
+
+def test_poisson_workload_prompt_length_range():
+    from repro.launch.serve import poisson_workload
+
+    reqs = poisson_workload(np.random.default_rng(0), 32, 4, 3, 100, 0.0,
+                            prompt_len_max=9)
+    lens = {len(r.prompt) for r in reqs}
+    assert lens <= set(range(4, 10)) and len(lens) > 1
+    assert all(r.arrival_time == 0.0 and r.max_new_tokens == 3
+               for r in reqs)
+    fixed = poisson_workload(np.random.default_rng(0), 4, 6, 3, 100, 0.0)
+    assert {len(r.prompt) for r in fixed} == {6}
+
+
+def test_compile_cache_dir(monkeypatch):
+    """Entry points keep the compile cache where ``JAX_COMPILATION_CACHE_
+    DIR`` says, and otherwise at the fixed ``<repo>/.jax_cache``."""
+    import os
+
+    from repro.launch.compile_cache import REPO_ROOT, use_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = use_compile_cache()
+        assert path == os.path.join(str(REPO_ROOT), ".jax_cache")
+        assert (REPO_ROOT / "chip_smoke.py").is_file()   # the checkout
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
