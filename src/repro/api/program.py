@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.compile import CompiledTGraph, CompileOptions, megakernelize
 from ..core.decompose import DecomposeConfig
 from ..core.interpreter import execute_tgraph
@@ -169,11 +170,13 @@ class Program:
         if key not in self._steps:
             cfg = self.cfg
 
-            def fn(params, cache, tokens, seq_lens, chunk_lens):
+            # the name is the jit's, so its device ops read ``mpk_prefill``
+            # in a profile
+            def mpk_prefill(params, cache, tokens, seq_lens, chunk_lens):
                 return prefill_chunk(params, cfg, cache, tokens, seq_lens,
                                      chunk_lens)
 
-            self._steps[key] = jax.jit(fn, donate_argnums=(1,))
+            self._steps[key] = jax.jit(mpk_prefill, donate_argnums=(1,))
         return self._steps[key]
 
     def _dev_params(self):
@@ -188,19 +191,29 @@ class Program:
         Positions >= ``chunk_lens`` are padding (no state written).
 
         Prefill always runs through the JAX chunked path against this
-        program's state; decode steps go through the backend."""
+        program's state; decode steps go through the backend.
+
+        Spans (``obs.span``): ``prefill`` (attribute ``width``), and inside
+        it ``prefill.gather`` (``get_state``), ``prefill.compute`` (the
+        jitted chunk until its results are ready), ``prefill.scatter``
+        (``set_state``) and ``prefill.readback`` (logits to the host)."""
         n = np.asarray(tokens_or_embeds).shape[1]
         b = self.batch
         if chunk_lens is None:
             chunk_lens = np.full((b,), n, np.int32)
         fn = self._prefill_fn(n)
-        cache = jax.tree.map(jnp.asarray, self.get_state())
-        logits, cache = fn(self._dev_params(), cache,
-                           jnp.asarray(tokens_or_embeds),
-                           jnp.asarray(np.asarray(seq_lens, np.int32)),
-                           jnp.asarray(np.asarray(chunk_lens, np.int32)))
-        self.set_state(cache)
-        return np.asarray(logits)
+        with obs.span("prefill", width=n):
+            with obs.span("prefill.gather"):
+                cache = jax.tree.map(jnp.asarray, self.get_state())
+            with obs.span("prefill.compute"):
+                logits, cache = jax.block_until_ready(fn(
+                    self._dev_params(), cache, jnp.asarray(tokens_or_embeds),
+                    jnp.asarray(np.asarray(seq_lens, np.int32)),
+                    jnp.asarray(np.asarray(chunk_lens, np.int32))))
+            with obs.span("prefill.scatter"):
+                self.set_state(cache)
+            with obs.span("prefill.readback"):
+                return np.asarray(logits)
 
     # ------------------------------------------------------------- stats
     @property
@@ -571,15 +584,20 @@ class PallasProgram(Program):
         not copied), so the device holds the heap and one weight copy."""
         self._params_dev = jax.tree.map(
             lambda a: jnp.asarray(a, jnp.float32), params)
-        zero_cache = _np_tree(init_cache(self.cfg, self.batch,
-                                         self.max_seq, dtype=jnp.float32))
         if self.cfg.embed_input:
             tok0 = np.zeros((self.batch, self.cfg.d_model), np.float32)
         else:
             tok0 = np.zeros((self.batch,), np.int32)
-        binds = decode_bindings(self.cfg, _np_tree(params), zero_cache,
-                                tok0, np.zeros((self.batch,), np.int32))
-        self.executor.upload(self.plan.build_heap(binds))
+        # spans: the host heap image (weights copied to the host
+        # included), then its upload
+        with obs.span("bind.heap"):
+            zero_cache = _np_tree(init_cache(self.cfg, self.batch,
+                                             self.max_seq, dtype=jnp.float32))
+            binds = decode_bindings(self.cfg, _np_tree(params), zero_cache,
+                                    tok0, np.zeros((self.batch,), np.int32))
+            heap = self.plan.build_heap(binds)
+        with obs.span("bind.upload"):
+            self.executor.upload(heap)
         return self
 
     def init_state(self) -> "Program":

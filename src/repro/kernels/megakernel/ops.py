@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
 from ...core.compile import CompileOptions, megakernelize
 from ...core.decompose import DecomposeConfig
 from ...core.lowering import build_decode_graph
@@ -233,8 +234,8 @@ class MegakernelExecutor:
         self.state_scatter_count = 0
 
         # ---- the ONE kernel + the ONE jitted step ----
-        self._jstep = jax.jit(self.step_fn(self.interpret),
-                              donate_argnums=(0,))
+        self._jrun = jax.jit(self.step_fn(self.interpret),
+                             donate_argnums=(0,))
 
         def zero_slot(heap, b):
             for off, n in slot_spans:
@@ -256,7 +257,8 @@ class MegakernelExecutor:
     def step_fn(self, interpret: bool):
         """The decode step before ``jit``: write the per-step inputs into
         the ``(1, heap_words(heap_size))`` heap, launch the kernel once,
-        slice out the logits.  ``(heap, vals) -> (heap, logits)``.
+        slice out the logits and the per-worker counter blocks.
+        ``(heap, vals) -> (heap, logits, stats)``.
         ``interpret`` picks the Pallas interpreter or the compiled
         kernel; the executor passes its device's choice."""
         plan = self.plan
@@ -270,6 +272,8 @@ class MegakernelExecutor:
                                kinds=np.unique(plan.descs[:, 0]))
         lg = plan.layout["logits"]
         lg_cols = lg.shape[-1]
+        stats = slice(plan.stats_offset,
+                      plan.stats_offset + plan.num_workers * STATS_WORDS)
 
         def _step(heap, vals):
             self.trace_count += 1  # python side effect: runs at trace only
@@ -284,9 +288,16 @@ class MegakernelExecutor:
                 heap = kern(self._descs, heap)
             logits = heap[:, lg.offset : lg.offset + lg.rows * lg.ld]
             logits = logits.reshape(lg.rows, lg.ld)[:, :lg_cols]
-            return heap, logits
+            return heap, logits, heap[0, stats]
 
         return _step
+
+    def _jstep(self, heap, vals):
+        """The jitted decode step without its counter blocks:
+        ``(heap, vals) -> (heap, logits)`` (``bench/tools/backend_compare.py``
+        times it)."""
+        heap, logits, _ = self._jrun(heap, vals)
+        return heap, logits
 
     @property
     def step_input_words(self) -> int:
@@ -296,7 +307,7 @@ class MegakernelExecutor:
     def lowered_step(self):
         """The jitted decode step lowered (not compiled) for this plan's
         heap and input shapes."""
-        return self._jstep.lower(
+        return self._jrun.lower(
             jax.ShapeDtypeStruct((1, heap_words(self.plan.heap_size)),
                                  jnp.float32),
             jax.ShapeDtypeStruct((self.step_input_words,), jnp.float32))
@@ -353,12 +364,36 @@ class MegakernelExecutor:
 
     def step(self, tokens_or_embeds, seq_lens, positions=None) -> np.ndarray:
         """One decode step inside the persistent kernel; returns logits
-        (B, vocab).  State advances in the device-resident heap."""
+        (B, vocab).  State advances in the device-resident heap.
+
+        Spans (``obs.span``): ``step``, and inside it ``step.pack``
+        (input image, host to device), ``step.launch`` (the jitted call
+        until it returns), ``step.wait`` (until the logits are ready) and
+        ``step.readback`` (logits to the host).  While they record, the
+        ``step`` span also carries the launch's ``row_copies`` counter,
+        an output of the same jitted call: on its way to the host while
+        the logits are awaited, and read after the readback."""
         assert self._heap is not None, "upload() before step()"
-        vals = self._pack_step_inputs(tokens_or_embeds, seq_lens, positions)
-        self._heap, logits = self._jstep(self._heap, vals)
-        self.step_count += 1
-        return np.asarray(logits)
+        with obs.span("step") as sp:
+            with obs.span("step.pack"):
+                vals = self._pack_step_inputs(tokens_or_embeds, seq_lens,
+                                              positions)
+            with obs.span("step.launch"):
+                self._heap, logits, stats = self._jrun(self._heap, vals)
+            if sp.recording:
+                stats.copy_to_host_async()
+            with obs.span("step.wait"):
+                logits.block_until_ready()
+            with obs.span("step.readback"):
+                out = np.asarray(logits)
+            self.step_count += 1
+            if sp.recording:
+                per_worker = read_stats_block(np.asarray(stats), 0,
+                                              self.plan.num_workers)
+                sp.set(row_copies=sum(d["row_copies"] for d in per_worker))
+            # the step's device buffers are freed inside its span
+            del vals, logits, stats
+        return out
 
     def worker_counters(self) -> List[Dict[str, int]]:
         """Per-worker kernel counters for the LAST step, one dict per

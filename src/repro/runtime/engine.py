@@ -32,6 +32,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from .. import obs
 from .kv_cache import PagedKVCache
 
 __all__ = ["Request", "RequestMetrics", "ServingEngine"]
@@ -241,91 +242,114 @@ class ServingEngine:
 
     # --------------------------------------------------------------- step
     def step(self) -> int:
-        """One serving iteration; returns number of live requests."""
-        self._ticks += 1
-        now = self._now()
-        # (1) retire finished
-        for rid in [r for r, q in self.running.items() if q.done]:
-            req = self.running.pop(rid)
-            self.kv.release(rid)
-            req.metrics.finish_s = now
-            self.finished.append(req)
-        # (2) admit arrived requests while slots are free (page pressure
-        # is handled by preemption below, not by blocking admission)
-        self.waiting.sort(key=lambda r: (r.arrival_time, r.priority))
-        while self.kv.has_free_slot and self.kv.free_pages > 0:
-            req = next(
-                (r for r in self.waiting if r.arrival_time <= now
-                 and self._backoff.get(r.request_id, 0) <= self._ticks),
-                None)
-            if req is None:
-                break
-            self.waiting.remove(req)
-            self._backoff.pop(req.request_id, None)
-            req.slot = self.kv.admit(req.request_id, 0)
-            # slot reuse: zero the slot's cache/conv/SSM state so the new
-            # (or replayed) request never sees a predecessor's state
-            self.program.reset_slot(req.slot)
-            self.running[req.request_id] = req
-            # replay stream: prompt plus anything sampled before a
-            # preemption (empty output for fresh requests)
-            self._pending_prefill[req.request_id] = \
-                list(req.prompt) + list(req.output)
-            if req.metrics.first_sched_s is None:
-                req.metrics.first_sched_s = now
-        if not self.running:
-            return 0  # idle poll: not a serving iteration
-        self.iterations += 1
-        # (3) plan chunks under the token budget, (4) resolve page pressure
-        plan = self._plan()
-        self._resolve_page_pressure(plan)
-        maxn = max(plan.values(), default=0)
-        if maxn == 0:
-            return len(self.running)
-        # (5) one batched program call; width padded to a power of two so
-        # the jit cache stays small (padding is masked via chunk_lens)
-        n_pad = 1 << (maxn - 1).bit_length()
-        tokens = np.zeros((self.kv.n_slots, n_pad), np.int32)
-        chunk_lens = np.zeros((self.kv.n_slots,), np.int32)
-        seq_lens = np.asarray(self.kv.seq_lens(), np.int32)
-        # every running request decoding exactly one token -> the pure
-        # decode path, served inside the backend (for the megakernel this
-        # is one persistent-kernel launch; free slots are reset at admit)
-        pure_decode = (not self._pending_prefill
-                       and all(plan.get(rid, 0) == 1 for rid in self.running))
-        for rid, n in plan.items():
-            if n == 0:
-                continue
-            req = self.running[rid]
-            pending = self._pending_prefill.get(rid)
-            if pending:
-                tokens[req.slot, :n] = pending[:n]
-                del pending[:n]
-                if not pending:
-                    del self._pending_prefill[rid]
+        """One serving iteration; returns number of live requests.
+
+        Spans (``obs.span``): ``engine.step`` (attributes ``iteration``,
+        ``kind`` = ``decode``, ``mixed`` or ``idle`` when nothing runs,
+        and ``running``), and inside it ``engine.schedule`` (retire,
+        admit, plan, page pressure, the token arrays), the program call
+        and ``engine.sample`` (argmax and bookkeeping)."""
+        with obs.span("engine.step") as sp:
+            with obs.span("engine.schedule"):
+                self._ticks += 1
+                now = self._now()
+                # (1) retire finished
+                for rid in [r for r, q in self.running.items() if q.done]:
+                    req = self.running.pop(rid)
+                    self.kv.release(rid)
+                    req.metrics.finish_s = now
+                    self.finished.append(req)
+                # (2) admit arrived requests while slots are free (page
+                # pressure is handled by preemption below, not by blocking
+                # admission)
+                self.waiting.sort(
+                    key=lambda r: (r.arrival_time, r.priority))
+                while self.kv.has_free_slot and self.kv.free_pages > 0:
+                    req = next(
+                        (r for r in self.waiting if r.arrival_time <= now
+                         and self._backoff.get(r.request_id, 0)
+                         <= self._ticks),
+                        None)
+                    if req is None:
+                        break
+                    self.waiting.remove(req)
+                    self._backoff.pop(req.request_id, None)
+                    req.slot = self.kv.admit(req.request_id, 0)
+                    # slot reuse: zero the slot's cache/conv/SSM state so
+                    # the new (or replayed) request never sees a
+                    # predecessor's state
+                    self.program.reset_slot(req.slot)
+                    self.running[req.request_id] = req
+                    # replay stream: prompt plus anything sampled before a
+                    # preemption (empty output for fresh requests)
+                    self._pending_prefill[req.request_id] = \
+                        list(req.prompt) + list(req.output)
+                    if req.metrics.first_sched_s is None:
+                        req.metrics.first_sched_s = now
+                if not self.running:
+                    sp.set(iteration=self.iterations, kind="idle",
+                           running=0)
+                    return 0  # idle poll: not a serving iteration
+                self.iterations += 1
+                # (3) plan chunks under the token budget, (4) resolve page
+                # pressure
+                plan = self._plan()
+                self._resolve_page_pressure(plan)
+                maxn = max(plan.values(), default=0)
+                # every running request decoding exactly one token -> the
+                # pure decode path, served inside the backend (for the
+                # megakernel this is one persistent-kernel launch; free
+                # slots are reset at admit)
+                pure_decode = (not self._pending_prefill
+                               and all(plan.get(rid, 0) == 1
+                                       for rid in self.running))
+                sp.set(iteration=self.iterations,
+                       kind="decode" if pure_decode else "mixed",
+                       running=len(self.running))
+                if maxn == 0:
+                    return len(self.running)
+                # (5) one batched program call; width padded to a power of
+                # two so the jit cache stays small (padding is masked via
+                # chunk_lens)
+                n_pad = 1 << (maxn - 1).bit_length()
+                tokens = np.zeros((self.kv.n_slots, n_pad), np.int32)
+                chunk_lens = np.zeros((self.kv.n_slots,), np.int32)
+                seq_lens = np.asarray(self.kv.seq_lens(), np.int32)
+                for rid, n in plan.items():
+                    if n == 0:
+                        continue
+                    req = self.running[rid]
+                    pending = self._pending_prefill.get(rid)
+                    if pending:
+                        tokens[req.slot, :n] = pending[:n]
+                        del pending[:n]
+                        if not pending:
+                            del self._pending_prefill[rid]
+                    else:
+                        tokens[req.slot, 0] = self._slot_tokens[req.slot]
+                    chunk_lens[req.slot] = n
+            if pure_decode:
+                logits = self.program.step(tokens[:, 0], seq_lens)[:, None]
+                self.decode_iterations += 1
             else:
-                tokens[req.slot, 0] = self._slot_tokens[req.slot]
-            chunk_lens[req.slot] = n
-        if pure_decode:
-            logits = self.program.step(tokens[:, 0], seq_lens)[:, None]
-            self.decode_iterations += 1
-        else:
-            logits = self.program.prefill(tokens, seq_lens, chunk_lens)
-        # (6) sample + bookkeeping: a request samples only once its whole
-        # replay stream has been consumed (logits of its LAST fed token)
-        t_done = self._now()
-        for rid, n in plan.items():
-            if n == 0:
-                continue
-            req = self.running[rid]
-            self.kv.advance_n(rid, n)
-            if rid not in self._pending_prefill:
-                nxt = int(np.argmax(logits[req.slot, n - 1]))
-                req.output.append(nxt)
-                self._slot_tokens[req.slot] = nxt
-                if req.metrics.first_token_s is None:
-                    req.metrics.first_token_s = t_done
-        return len(self.running)
+                logits = self.program.prefill(tokens, seq_lens, chunk_lens)
+            with obs.span("engine.sample"):
+                # (6) sample + bookkeeping: a request samples only once its
+                # whole replay stream has been consumed (logits of its LAST
+                # fed token)
+                t_done = self._now()
+                for rid, n in plan.items():
+                    if n == 0:
+                        continue
+                    req = self.running[rid]
+                    self.kv.advance_n(rid, n)
+                    if rid not in self._pending_prefill:
+                        nxt = int(np.argmax(logits[req.slot, n - 1]))
+                        req.output.append(nxt)
+                        self._slot_tokens[req.slot] = nxt
+                        if req.metrics.first_token_s is None:
+                            req.metrics.first_token_s = t_done
+            return len(self.running)
 
     # ---------------------------------------------------------------- run
     def run(self, max_iterations: int = 10_000) -> List[Request]:
