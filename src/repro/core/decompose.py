@@ -16,7 +16,7 @@ from .graph import ComputationGraph, OpKind, OpNode
 from .regions import Region, TensorSpec, tile_regions
 from .tgraph import Task, TGraph
 
-__all__ = ["DecomposeConfig", "decompose"]
+__all__ = ["DecomposeConfig", "decompose", "widest_tile"]
 
 
 @dataclasses.dataclass
@@ -29,6 +29,13 @@ class DecomposeConfig:
     align: int = 128
     #: maximum rows per task tile (sublane-friendly)
     max_rows: int = 256
+    #: when set, every MATMUL output is cut into the megakernel's own
+    #: tile instead: at most ``max_rows`` rows by ``ceil(cols /
+    #: matmul_cols)`` balanced column tiles.  The kernel copies and multiplies
+    #: ``matmul_cols``-wide (TN) weight rows whatever a task's width, so
+    #: a narrower matmul tile only repeats that work.  None keeps the
+    #: ``target_tasks_per_op`` partition.
+    matmul_cols: int | None = None
 
 
 # --------------------------------------------------------------------------
@@ -70,6 +77,11 @@ def _partition_primary(
         tile = (row_tile,) + tuple(shape[1:])
         return list(tile_regions(shape, tile))
 
+    if op.kind == OpKind.MATMUL and cfg.matmul_cols:
+        rows, cols = shape
+        col = _col_tile(cols, math.ceil(cols / cfg.matmul_cols), cfg.align)
+        return list(tile_regions(shape, (min(rows, cfg.max_rows), col)))
+
     if op.kind == OpKind.MOE_GATHER_GEMM:
         # output (E, tokens, d_ff): one expert per task row-group, f tiled
         e, toks, dff = shape
@@ -98,6 +110,15 @@ def _partition_primary(
     row = max(1, min(cfg.max_rows, math.ceil(rows / row_budget)))
     tile = (row,) + tuple(shape[1:-1]) + (col,)
     return list(tile_regions(shape, tile))
+
+
+def widest_tile(g: ComputationGraph, cfg: DecomposeConfig) -> int:
+    """Widest column tile that ``cfg`` cuts from any op's 2-D (or wider)
+    primary output."""
+    return max((r.shape[-1]
+                for op in g.ops if len(g.spec(op.outputs[0]).shape) >= 2
+                for r in _partition_primary(op, g.spec(op.outputs[0]), cfg)),
+               default=1)
 
 
 # --------------------------------------------------------------------------

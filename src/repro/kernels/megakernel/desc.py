@@ -290,12 +290,16 @@ class MegakernelPlan:
         """The static half of the schedule→kernel pipeline contract:
         scheduler stalls plus the prefetch plan's coverage over the
         descriptor table (the dynamic half — actual bulk-DMA counts — is
-        ``MegakernelExecutor.pipeline_counters()``)."""
+        ``MegakernelExecutor.pipeline_counters()``), and ``tile_fill``: the
+        words of the matmul tasks' own tiles (k × n) over the words their
+        TN-wide weight-row copies move (k × TN)."""
         s = self.compiled.stats
         kinds = self.descs[:, 0]
         prefetchable = int(np.isin(kinds, list(_PRIMARY_ROWS_M)
                                    + [KIND_CODES[OpKind.EMBED_LOOKUP]]).sum())
         prefetched = int((self.descs[:, 27] == 1).sum())
+        mm = self.descs[kinds == KIND_CODES[OpKind.MATMUL]].astype(np.int64)
+        copied = int(mm[:, 3].sum()) * self.statics["TN"]
         return {
             "stalls": s.get("pipeline_stalls", 0),
             "stalls_naive": s.get("pipeline_stalls_naive",
@@ -304,6 +308,7 @@ class MegakernelPlan:
             "prefetchable_tasks": prefetchable,
             "prefetched_tasks": prefetched,
             "prefetch_coverage": prefetched / max(1, prefetchable),
+            "tile_fill": int((mm[:, 3] * mm[:, 2]).sum()) / max(1, copied),
         }
 
     # ---------------------------------------------------- input classes

@@ -26,6 +26,7 @@ tests).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -34,10 +35,10 @@ import numpy as np
 
 from ... import obs
 from ...core.compile import CompileOptions, megakernelize
-from ...core.decompose import DecomposeConfig
+from ...core.decompose import DecomposeConfig, widest_tile
 from ...core.lowering import build_decode_graph
 from .desc import (STATS_WORDS, TRACE_HEADER, TRACE_WORDS, MegakernelPlan,
-                   heap_words, lower_tgraph, stamp_multichip)
+                   _align, heap_words, lower_tgraph, stamp_multichip)
 from .kernel import make_megakernel
 
 __all__ = ["compile_decode_megakernel", "MegakernelExecutor",
@@ -112,8 +113,11 @@ def compile_decode_megakernel(cfg, batch: int, max_seq: int,
     """Lower cfg's decode step end-to-end: op graph → tGraph → descriptors.
 
     ``max_rows`` caps tile rows (the megakernel's TM) — decode batches are
-    small, so row tiles stay register-friendly.  ``pipeline_depth`` is the
-    separation the scheduler enforces between producer→consumer pairs
+    small, so row tiles stay register-friendly.  The kernel's tile width
+    TN is the widest tile of the default partition; every matmul is then
+    cut into TM × ≤TN tiles (``DecomposeConfig.matmul_cols``), the same
+    for any ``num_workers``, scheduler or ``tp``.  ``pipeline_depth`` is
+    the separation the scheduler enforces between producer→consumer pairs
     (2 = the kernel's double buffer).  ``num_workers`` partitions the
     schedule into W decentralized per-worker descriptor streams
     synchronized through in-heap event counters (paper §5).
@@ -134,8 +138,10 @@ def compile_decode_megakernel(cfg, batch: int, max_seq: int,
             "tp > 1 megakernels require scheduler='static' (the dynamic "
             "ready queues are not chip-stamped yet)")
     g = build_decode_graph(cfg, batch, max_seq, tp=tp)
+    dec = DecomposeConfig(max_rows=max_rows)
+    tn = _align(widest_tile(g, dec))
     opts = CompileOptions(
-        decompose=DecomposeConfig(max_rows=max_rows),
+        decompose=dataclasses.replace(dec, matmul_cols=tn),
         latency_aware_schedule=latency_aware,
         event_fusion=event_fusion,
         pipeline_depth=pipeline_depth,
@@ -144,7 +150,8 @@ def compile_decode_megakernel(cfg, batch: int, max_seq: int,
         trace=trace,
     )
     compiled = megakernelize(g, opts)
-    plan = lower_tgraph(compiled, cfg, scheduler=scheduler, trace=trace)
+    plan = lower_tgraph(compiled, cfg, tn=tn, scheduler=scheduler,
+                        trace=trace)
     if tp > 1:
         plan = stamp_multichip(plan, tp)
     return plan
