@@ -1,5 +1,5 @@
-"""mamba2-2.7b [ssm] — SSD (state-space duality), attention-free
-[arXiv:2405.21060; unverified]."""
+"""mamba2-2.7b [ssm] — SSD (state-space duality), attention-free, tied
+embeddings [arXiv:2405.21060; hf:state-spaces/mamba2-2.7b]."""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -10,7 +10,7 @@ CONFIG = ModelConfig(
     n_heads=0,             # attention-free
     n_kv_heads=0,
     d_ff=0,                # no MLP: Mamba2 blocks are mixer-only
-    vocab=50280,
+    vocab=50280,           # 50277 padded to a multiple of 16
     ssm_state=128,
     ssm_expand=2,          # d_inner = 5120
     ssm_head_dim=64,       # 80 SSM heads
@@ -18,5 +18,6 @@ CONFIG = ModelConfig(
     ssm_conv=4,
     norm_eps=1e-5,
     activation="silu",
+    tie_embeddings=True,
     source="arXiv:2405.21060; hf:state-spaces/mamba2-2.7b",
 )

@@ -135,8 +135,10 @@ DESC_WORDS = 36
 #: checked, [6] event-wait violations (must stay 0), [7] event signals,
 #: and — dynamic scheduler only, zero under static — [8] pops from the
 #: worker's own pool, [9] pops from the shared overflow queue,
-#: [10] steals from other workers' pools, [11] idle grid slots
-STATS_WORDS = 12
+#: [10] steals from other workers' pools, [11] idle grid slots;
+#: [12] the row copies of [1] that read or write state (SSM and conv
+#: state tiles, K/V cache rows), [13] 2^20-unit spill of [12]
+STATS_WORDS = 14
 
 #: f32 words PER GRID SLOT in the optional trace ring
 #: (``CompileOptions.trace``): [0] worker lane, [1] descriptor row

@@ -54,7 +54,8 @@ itself: [0] bulk tile DMAs issued, [1] row copies inside them (what the
 pre-pipelining kernel issued as individual DMAs), [2] prefetch tiles
 issued, [3] primary tiles demand-loaded, [5] event waits checked,
 [6] event-wait violations, [7] event signals, [8-11] dynamic-scheduler
-pops (own pool / overflow / steals / idle slots).
+pops (own pool / overflow / steals / idle slots), [12] the row copies of
+[1] that read or write state: SSM and conv state tiles, K/V cache rows.
 ``MegakernelExecutor.pipeline_counters()`` / ``worker_counters()`` read
 it back.
 
@@ -110,6 +111,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...models.layers import softplus
 from ...runtime.dyn_sched import QUEUE_EMPTY
 from .desc import (LANE, STATS_WORDS, TRACE_HEADER, TRACE_WORDS,
                    heap_words)
@@ -260,23 +262,31 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             """Accumulate into this worker's counter row."""
             cnt[w_id, j] = cnt[w_id, j] + v
 
-        def _count(nrows):
-            """One bulk tile DMA moving ``nrows`` strided rows.  The row
-            total spills into a 2^20-unit high word so the f32 counters
-            stay exact far past 2^24 rows/launch (full-size models)."""
-            cadd(0, 1.0)
-            cadd(1, jnp.asarray(nrows).astype(jnp.float32))
+        def _rows(j, spill, n):
+            """Add ``n`` rows to word ``j``; the total spills into a
+            2^20-unit high word ``spill`` so the f32 counters stay exact
+            far past 2^24 rows/launch (full-size models)."""
+            cadd(j, n)
 
-            @pl.when(cnt[w_id, 1] >= 1048576.0)
+            @pl.when(cnt[w_id, j] >= 1048576.0)
             def _():
-                cadd(4, 1.0)
-                cadd(1, -1048576.0)
+                cadd(spill, 1.0)
+                cadd(j, -1048576.0)
 
-        def _count_tile(nrows):
+        def _count(nrows, state=False):
+            """One bulk tile DMA moving ``nrows`` strided rows; ``state``
+            marks rows of SSM, conv or K/V state."""
+            n = jnp.asarray(nrows).astype(jnp.float32)
+            cadd(0, 1.0)
+            _rows(1, 4, n)
+            if state:
+                _rows(12, 13, n)
+
+        def _count_tile(nrows, state=False):
             """``_count`` for a bulk tile DMA that may move no rows."""
             @pl.when(nrows > 0)
             def _():
-                _count(nrows)
+                _count(nrows, state)
 
         # ---------------- heap views ----------------------------------
         # The heap is one (1, N) f32 row.  Row-tile operands move between
@@ -333,13 +343,13 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             return new
 
         # ---------------- DMA helpers (all through the aliased out ref) ---
-        def load_tile(dst, base, ld, nrows, max_rows, width):
+        def load_tile(dst, base, ld, nrows, max_rows, width, state=False):
             """dst[i, :width] = heap[base + i*ld : +width], zero if i>=nrows.
 
             ONE bulk strided DMA: every row copy is issued back-to-back
             (start), then completion is awaited once."""
             nrows = jnp.asarray(nrows, jnp.int32)
-            _count_tile(nrows)
+            _count_tile(nrows, state)
 
             def start_body(i, _):
                 @pl.when(i < nrows)
@@ -386,11 +396,12 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 return 0
             jax.lax.fori_loop(0, live, body, 0)
 
-        def store_tile(src, base, ld, nrows, max_rows, width, valid=None):
+        def store_tile(src, base, ld, nrows, max_rows, width, valid=None,
+                       state=False):
             """Bulk strided write-back: issue all row copies, wait once;
             chunk-masked by ``valid`` (see ``_chunked``)."""
             nrows = jnp.asarray(nrows, jnp.int32)
-            _count_tile(nrows)
+            _count_tile(nrows, state)
 
             def row_copies(i, op):
                 _chunked(lambda c0, cw: getattr(pltpu.make_async_copy(
@@ -456,7 +467,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             """Write one row of the primary tile back to the heap (the
             cache-update path stores prefetched K/V rows directly),
             chunk-masked to the new rows' true width."""
-            _count(1)
+            _count(1, state=True)
             _chunked(lambda c0, cw: _copy(
                 sP.at[w_id, slot, r, :, pl.ds(c0, cw)], hrow(base + c0, cw)),
                 TN, valid)
@@ -877,9 +888,11 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                         s0 = sc * TS
                         valid = jnp.clip(live - s0, 0, TS)
                         load_tile(sB, d(8) + r * d(15) + gi * HD
-                                  + s0 * d(9), d(9), valid, TS, HD)
+                                  + s0 * d(9), d(9), valid, TS, HD,
+                                  state=True)
                         load_tile(sD, d(10) + r * d(15) + gi * HD
-                                  + s0 * d(11), d(11), valid, TS, HD)
+                                  + s0 * d(11), d(11), valid, TS, HD,
+                                  state=True)
                         logits = _dot(mat(sB, TS, HD), qm,
                                       ((1,), (1,)))             # (TS,G)
                         logits = jnp.where(srow < valid, logits, -1e30)
@@ -1017,11 +1030,12 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                     for hh in range(NHT):
                         a_log, dsk = heads[hh]
                         base = d(8) + r * d(15) + hh * d(16)
-                        load_tile(sB, base, d(9), HDS, SB_ROWS, NS)
+                        load_tile(sB, base, d(9), HDS, SB_ROWS, NS,
+                                  state=True)
                         x_h = jnp.sum(jnp.where(
                             hcol == r, xT[hh * HDS:(hh + 1) * HDS], 0.0),
                             axis=1, keepdims=True)          # (HDS, 1)
-                        dt_sp = jax.nn.softplus(jnp.full(
+                        dt_sp = softplus(jnp.full(
                             (1, 1), peek(d(10) + r * d(11) + hh),
                             jnp.float32))
                         da = jnp.exp(dt_sp * (-jnp.exp(a_log)))
@@ -1030,7 +1044,8 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                         y_h = (_dot(new_state, cvec, ((1,), (1,)))
                                + dsk * x_h)                 # (HDS, 1)
                         sB[:HDS, :, :NS] = new_state.reshape(HDS, 1, NS)
-                        store_tile(sB, base, d(9), HDS, SB_ROWS, NS)
+                        store_tile(sB, base, d(9), HDS, SB_ROWS, NS,
+                                   state=True)
                         sY[hh * HDS:(hh + 1) * HDS, r:r + 1] = y_h
             y = sY[...].T                                   # (TM, NHT*HDS)
             sO[...] = jnp.zeros((TM, 1, TN), jnp.float32)
@@ -1055,14 +1070,15 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 @pl.when(r < m)
                 def _(r=r):
                     base = d(8) + r * d(15)
-                    load_tile(sD, base, d(9), WC, WC, TN)
+                    load_tile(sD, base, d(9), WC, WC, TN, state=True)
                     rows = ([sD[j, :, :TN] for j in range(1, WC)]
                             + [pa[r:r + 1, :TN]])
                     y = bias
                     for j in range(WC):
                         sD[j, :, :TN] = rows[j]
                         y = y + rows[j] * sB[j, :, :TN]
-                    store_tile(sD, base, d(9), WC, WC, TN, valid=d(2))
+                    store_tile(sD, base, d(9), WC, WC, TN, valid=d(2),
+                               state=True)
                     sO[r] = jax.nn.silu(y)
                     store_row_vec(sO, r, d(4) + r * d(5), TN,
                                   valid=d(2))

@@ -46,9 +46,9 @@ __all__ = ["compile_decode_megakernel", "MegakernelExecutor",
            "interprets_on"]
 
 #: named field map of the per-worker STATS block: counter name → word
-#: index.  Word 4 (``ROW_SPILL_WORD``) is the 2^20-unit spill of
-#: ``row_copies`` — folded back into that field by ``decode_stats_row``
-#: instead of surfacing as its own counter.
+#: index.  Words 4 and 13 (``SPILL_WORDS``) are the 2^20-unit spills of
+#: ``row_copies`` and ``state_row_copies`` — folded back into those
+#: fields by ``decode_stats_row`` instead of surfacing as counters.
 STATS_FIELDS = {
     "bulk_copies": 0,
     "row_copies": 1,
@@ -61,18 +61,20 @@ STATS_FIELDS = {
     "pops_overflow": 9,
     "steals": 10,
     "idle_slots": 11,
+    "state_row_copies": 12,
 }
 
-ROW_SPILL_WORD = 4
+SPILL_WORDS = {"row_copies": 4, "state_row_copies": 13}
 ROW_SPILL_UNIT = 1 << 20
 
 
 def decode_stats_row(v) -> Dict[str, int]:
     """Decode one worker's STATS block (``STATS_WORDS`` f32 words) into
-    named integer counters, folding the 2^20-unit ``row_copies`` spill
-    word back in so values far past 2^24 rows/launch stay exact."""
+    named integer counters, folding the 2^20-unit spill words back in so
+    row counts far past 2^24 rows/launch stay exact."""
     out = {name: int(v[i]) for name, i in STATS_FIELDS.items()}
-    out["row_copies"] += ROW_SPILL_UNIT * int(v[ROW_SPILL_WORD])
+    for name, i in SPILL_WORDS.items():
+        out[name] += ROW_SPILL_UNIT * int(v[i])
     return out
 
 
@@ -377,9 +379,10 @@ class MegakernelExecutor:
         (input image, host to device), ``step.launch`` (the jitted call
         until it returns), ``step.wait`` (until the logits are ready) and
         ``step.readback`` (logits to the host).  While they record, the
-        ``step`` span also carries the launch's ``row_copies`` counter,
-        an output of the same jitted call: on its way to the host while
-        the logits are awaited, and read after the readback."""
+        ``step`` span also carries the launch's ``row_copies`` and
+        ``state_row_copies`` counters, an output of the same jitted call:
+        on its way to the host while the logits are awaited, and read
+        after the readback."""
         assert self._heap is not None, "upload() before step()"
         with obs.span("step") as sp:
             with obs.span("step.pack"):
@@ -397,7 +400,9 @@ class MegakernelExecutor:
             if sp.recording:
                 per_worker = read_stats_block(np.asarray(stats), 0,
                                               self.plan.num_workers)
-                sp.set(row_copies=sum(d["row_copies"] for d in per_worker))
+                sp.set(row_copies=sum(d["row_copies"] for d in per_worker),
+                       state_row_copies=sum(d["state_row_copies"]
+                                            for d in per_worker))
             # the step's device buffers are freed inside its span
             del vals, logits, stats
         return out
@@ -406,11 +411,11 @@ class MegakernelExecutor:
         """Per-worker kernel counters for the LAST step, one dict per
         worker lane, read from the reserved per-worker blocks at the heap
         tail (each worker re-zeroes its block at grid step 0 of every
-        launch): bulk tile DMAs issued, row copies inside them (with the
-        2^20-unit spill word folded back in), prefetch tiles issued,
-        primary tiles demand-loaded (pipeline misses), event waits
-        checked, event-wait violations (a compiler bug if nonzero) and
-        event signals."""
+        launch): bulk tile DMAs issued, row copies inside them and those
+        of them that read or write state (with the 2^20-unit spill words
+        folded back in), prefetch tiles issued, primary tiles
+        demand-loaded (pipeline misses), event waits checked, event-wait
+        violations (a compiler bug if nonzero) and event signals."""
         assert self._heap is not None, "upload() before worker_counters()"
         return read_stats_block(self._heap, self.plan.stats_offset,
                                 self.plan.num_workers)

@@ -20,7 +20,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .layers import rmsnorm
+from .layers import rmsnorm, softplus
 
 __all__ = ["ssm_prefill", "ssm_decode", "causal_conv", "conv_decode"]
 
@@ -146,7 +146,7 @@ def ssm_prefill(x_seq: jax.Array, p: Dict[str, jax.Array], cfg,
     xs = xx.reshape(b, s, nh, hd)
     bmat = bb.reshape(b, s, ng, n)
     cmat = cc.reshape(b, s, ng, n)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+    dt = softplus(dt_raw.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
     y = _ssd_scan(xs, dt, a, bmat, cmat, chunk, unroll=unroll)
@@ -180,7 +180,7 @@ def ssm_decode(x_t: jax.Array, states: Dict[str, jax.Array],
     xs = xx.reshape(bsz, nh, hd).astype(jnp.float32)
     bmat = bb.reshape(bsz, ng, n).astype(jnp.float32)
     cmat = cc.reshape(bsz, ng, n).astype(jnp.float32)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+    dt = softplus(dt_raw.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))  # (B, nh)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
     da = jnp.exp(dt * a)  # (B, nh)

@@ -214,22 +214,26 @@ def test_sequential_trace_static(quickstart):
 
 
 def test_stats_spill_roundtrip_past_f32_precision():
-    """row_copies counts past 2^24 (f32 integer precision) round-trip
-    exactly through the (low, spill) pair the kernel maintains."""
-    from repro.kernels.megakernel.ops import (ROW_SPILL_UNIT, STATS_FIELDS,
+    """row_copies and state_row_copies counts past 2^24 (f32 integer
+    precision) round-trip exactly through the (low, spill) pair the
+    kernel maintains for each."""
+    from repro.kernels.megakernel.ops import (ROW_SPILL_UNIT, SPILL_WORDS,
+                                              STATS_FIELDS, STATS_WORDS,
                                               decode_stats_row)
 
     assert ROW_SPILL_UNIT == 1 << 20
+    assert SPILL_WORDS == {"row_copies": 4, "state_row_copies": 13}
     for value in [0, 1, (1 << 20) - 1, 1 << 20, (1 << 24) + 1,
                   5 * (1 << 24) + 12345, (1 << 40) + 987654]:
         low, spill = value % ROW_SPILL_UNIT, value // ROW_SPILL_UNIT
         # both halves must be f32-exact for the decode to be exact
         assert int(np.float32(low)) == low
         assert int(np.float32(spill)) == spill
-        v = np.zeros((12,), np.float32)
-        v[STATS_FIELDS["row_copies"]] = low
-        v[4] = spill
-        assert decode_stats_row(v)["row_copies"] == value
+        for name, spill_word in SPILL_WORDS.items():
+            v = np.zeros((STATS_WORDS,), np.float32)
+            v[STATS_FIELDS[name]] = low
+            v[spill_word] = spill
+            assert decode_stats_row(v)[name] == value
 
 
 def test_read_stats_block_named_fields():

@@ -115,15 +115,19 @@ def test_row_copies_on_each_step_equal_the_kernel_counter(tiny, tmp_path):
     with jax.profiler.trace(str(tmp_path)):
         prog.bind(params).init_state()
         lens = np.zeros((B,), np.int32)
-        counters = []
+        counters, state = [], []
         for i in range(3):
             prog.step(np.full((B,), i + 1, np.int32), lens)
-            counters.append(prog.executor.pipeline_counters()["row_copies"])
+            c = prog.executor.pipeline_counters()
+            counters.append(c["row_copies"])
+            state.append(c["state_row_copies"])
             lens += 1 + np.arange(B, dtype=np.int32)   # ragged lengths
     recs = spans.recorded()
     steps = [s for s in recs if s.name == "mpk.step"]
     assert [s.attrs["row_copies"] for s in steps] == counters
+    assert [s.attrs["state_row_copies"] for s in steps] == state
     assert counters[0] > 0 and len(set(counters)) > 1  # K/V rows grow
+    assert 0 < state[0] < counters[0] and len(set(state)) > 1
     names = set(_names(recs))
     assert {"mpk.bind.heap", "mpk.bind.upload"} <= names
     # the four children of every step, in order, inside it
@@ -133,7 +137,8 @@ def test_row_copies_on_each_step_equal_the_kernel_counter(tiny, tmp_path):
                                 "mpk.step.wait", "mpk.step.readback"]
         assert all(st.t0 <= k.t0 <= k.t1 <= st.t1 for k in kids)
     written = _xplane_spans(tmp_path)
-    assert written["mpk.step"] == {"row_copies": counters[-1]}
+    assert written["mpk.step"] == {"row_copies": counters[-1],
+                                   "state_row_copies": state[-1]}
 
 
 def test_decode_step_logits_bitwise_same_recording_on_and_off(tiny,

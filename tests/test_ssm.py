@@ -74,3 +74,16 @@ def test_unrolled_matches_scan():
     a = np.asarray(_ssd_scan(*jargs, 8, unroll=False))
     bb = np.asarray(_ssd_scan(*jargs, 8, unroll=True))
     np.testing.assert_allclose(a, bb, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("lo,hi", [(-40.0, 40.0), (-0.72, -0.67),
+                                   (0.67, 0.72)])
+def test_softplus_is_f32_exact_and_branch_free(lo, hi):
+    """``layers.softplus`` (the program's dt, in the kernel and in
+    ``ssm.py``) stays within a few ulp of ``log(1 + e^x)`` over the whole
+    range and around ``|x| = ln 2``, where a TPU's ``log1p`` jumps."""
+    from repro.models.layers import softplus
+    x = np.linspace(lo, hi, 200_001).astype(np.float32)
+    exact = np.logaddexp(x.astype(np.float64), 0.0)
+    got = np.asarray(jax.jit(softplus)(jnp.asarray(x)), np.float64)
+    assert np.max(np.abs(got - exact) / exact) < 4e-7
