@@ -111,7 +111,9 @@ class Program:
 
     Subclasses implement ``step`` (one decode step through the backend)
     and ``get_state``/``set_state``; ``prefill`` and ``reset_slot`` are
-    shared.  All public array returns are numpy.
+    shared.  ``step`` and ``prefill`` return numpy logits; ``get_state``
+    returns the backend's own arrays (device arrays on the jax and
+    megakernel backends).
     """
 
     backend = "abstract"
@@ -152,7 +154,9 @@ class Program:
 
     # ------------------------------------------------------------- state
     def get_state(self) -> Dict[str, Any]:
-        """The cache/state pytree (``init_cache`` layout)."""
+        """The cache/state pytree (``init_cache`` layout).  On the
+        megakernel backend a fresh snapshot, which later ``step``,
+        ``prefill`` and ``set_state`` calls leave as it is."""
         raise NotImplementedError
 
     def set_state(self, state: Dict[str, Any]) -> None:
@@ -402,7 +406,9 @@ class JaxProgram(Program):
         return self._cache
 
     def set_state(self, state) -> None:
-        self._cache = jax.tree.map(jnp.asarray, state)
+        # a copy: ``step`` donates the cache, and ``state`` may be a
+        # snapshot its caller sets again
+        self._cache = jax.tree.map(lambda a: jnp.array(a, copy=True), state)
 
     def step(self, tokens_or_embeds, seq_lens, positions=None) -> np.ndarray:
         # a decode step IS a width-1 chunk (models.serve_step contract)
@@ -523,7 +529,28 @@ class PallasProgram(Program):
             scheduler=scheduler, tp=tp, trace=trace)
         self._compiled = self.plan.compiled
         self.executor = MegakernelExecutor(self.plan, cfg)
-        self._smap = _state_map(cfg)
+        smap = _state_map(cfg)
+        leaves = jax.eval_shape(lambda: init_cache(cfg, batch, max_seq,
+                                                   dtype=jnp.float32))
+        shapes = {e["in"]: self.plan.compiled.graph.spec(e["in"]).shape
+                  for e in smap}
+
+        # graph state tensors <-> the init_cache pytree, on the device
+        def to_tree(tensors):
+            tree = {k: jnp.zeros(s.shape, s.dtype) for k, s in leaves.items()}
+            for e in smap:
+                leaf = tree[e["key"]]
+                tree[e["key"]] = leaf.at[e["blk"], e["idx"]].set(
+                    tensors[e["in"]].reshape(leaf.shape[2:]))
+            return tree
+
+        def from_tree(state):
+            return {e["in"]: state[e["key"]][e["blk"], e["idx"]].astype(
+                        jnp.float32).reshape(shapes[e["in"]])
+                    for e in smap}
+
+        self._jto_tree = jax.jit(to_tree)
+        self._jfrom_tree = jax.jit(from_tree)
 
     def trace(self):
         """The kernel-written trace ring of the LAST step as an
@@ -615,26 +642,14 @@ class PallasProgram(Program):
         self.executor.reset_state(slot)
 
     def get_state(self):
-        # device gather of the state spans only — O(state), not O(heap)
-        tensors = self.executor.read_state()
-        state = _np_tree(init_cache(self.cfg, self.batch, self.max_seq,
-                                    dtype=jnp.float32))
-        for ent in self._smap:
-            leaf = state[ent["key"]]
-            leaf[ent["blk"], ent["idx"]] = tensors[ent["in"]].reshape(
-                leaf.shape[2:])
-        return state
+        # gather of the state spans only — O(state), not O(heap) — all on
+        # the device: a fresh snapshot of device arrays
+        return self._jto_tree(self.executor.read_state())
 
     def set_state(self, state) -> None:
-        # state-only scatter into the resident heap: weights are never
-        # re-moved, so prefill/restore costs O(state), not O(heap)
-        g = self.plan.compiled.graph
-        tensors = {}
-        for ent in self._smap:
-            leaf = np.asarray(state[ent["key"]], np.float32)
-            tensors[ent["in"]] = leaf[ent["blk"], ent["idx"]].reshape(
-                g.spec(ent["in"]).shape)
-        self.executor.write_state(tensors)
+        # state-only scatter into the resident heap, on the device:
+        # weights are never re-moved, so prefill/restore costs O(state)
+        self.executor.write_state(self._jfrom_tree(state))
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,10 @@ into a live program:
   in-place aliasing plus jit buffer donation keep the heap resident,
 * per-step inputs (tokens, seq_lens, live_lens, positions) go through a
   small scatter into the heap (``at[idx].set``) instead of a host-side
-  full-heap rebuild.
+  full-heap rebuild,
+* the state gather and scatter a prefill round-trips through
+  (``read_state`` / ``write_state``) copy the state spans on the device,
+  in bounded blocks: no state byte reaches the host.
 
 ``tp > 1`` compiles the TP-sharded graph and stamps the plan into a
 multi-chip task table (``desc.stamp_multichip``): per-chip descriptor
@@ -66,6 +69,53 @@ STATS_FIELDS = {
 
 SPILL_WORDS = {"row_copies": 4, "state_row_copies": 13}
 ROW_SPILL_UNIT = 1 << 20
+
+#: most padded heap words one block of a state gather or scatter copies:
+#: the copies run block by block, so their temporaries stay this size
+#: whatever the state's (an SSM state span is ~860 MB padded)
+STATE_BLOCK_WORDS = 1 << 22
+
+
+def _block_rows(rows: int, ld: int) -> int:
+    """The most rows, dividing ``rows``, whose heap span at row stride
+    ``ld`` fits ``STATE_BLOCK_WORDS`` (at least one row)."""
+    cap = max(1, min(rows, STATE_BLOCK_WORDS // ld))
+    return next(d for d in range(cap, 0, -1) if rows % d == 0)
+
+
+def _gather_span(heap, off: int, rows: int, ld: int, cols: int):
+    """The ``(rows, cols)`` tensor whose rows start every ``ld`` words
+    from word ``off`` of the ``(1, N)`` heap, cropped block by block on
+    the device (traceable)."""
+    br = _block_rows(rows, ld)
+
+    def block(i, out):
+        img = jax.lax.dynamic_slice(heap, (0, off + i * br * ld),
+                                    (1, br * ld))
+        return jax.lax.dynamic_update_slice(
+            out, img.reshape(br, ld)[:, :cols], (i * br, 0))
+
+    return jax.lax.fori_loop(0, rows // br, block,
+                             jnp.zeros((rows, cols), heap.dtype))
+
+
+def _scatter_span(heap, v, offs, ld: int):
+    """Write the ``(rows, cols)`` tensor ``v`` into the ``(1, N)`` heap
+    as rows ``ld`` words apart from each word offset in ``offs``, pad
+    columns zeroed, block by block (traceable).  Each block is one
+    contiguous ``dynamic_update_slice``."""
+    rows, cols = v.shape
+    br = _block_rows(rows, ld)
+
+    def block(i, heap):
+        rows_i = jax.lax.dynamic_slice_in_dim(v, i * br, br)
+        img = jnp.pad(rows_i, ((0, 0), (0, ld - cols))).reshape(1, -1)
+        for off in offs:
+            heap = jax.lax.dynamic_update_slice(heap, img,
+                                                (0, off + i * br * ld))
+        return heap
+
+    return jax.lax.fori_loop(0, rows // br, block, heap)
 
 
 def decode_stats_row(v) -> Dict[str, int]:
@@ -252,14 +302,22 @@ class MegakernelExecutor:
                     heap, jnp.zeros((1, n), heap.dtype), (0, off + b * n))
             return heap
 
-        def set_state(heap, vals):
-            for c in self._chip_offsets:
-                for v, (_, off, _, _, _) in zip(vals, self._state_spans):
-                    heap = jax.lax.dynamic_update_slice(
-                        heap, v[None], (0, off + c))
+        # state gather (chip 0) and scatter (every chip) stay on the
+        # device: one span after another, each in bounded blocks
+        def get_state(heap):
+            return {name: _gather_span(heap, off, rows, ld, cols).reshape(
+                        plan.layout[name].shape)
+                    for name, off, rows, ld, cols in self._state_spans}
+
+        def set_state(heap, tensors):
+            for name, off, rows, ld, cols in self._state_spans:
+                heap = _scatter_span(
+                    heap, tensors[name].reshape(rows, cols),
+                    [int(off + c) for c in self._chip_offsets], ld)
             return heap
 
         self._jzero = jax.jit(zero_slot, donate_argnums=(0,))
+        self._jget = jax.jit(get_state)
         self._jset = jax.jit(set_state, donate_argnums=(0,))
         self._heap: Optional[jax.Array] = None
 
@@ -487,32 +545,25 @@ class MegakernelExecutor:
         """Replace the resident heap (state restore); counts as an upload."""
         self.upload(heap)
 
-    def read_state(self) -> Dict[str, np.ndarray]:
-        """Read every state tensor from the resident heap (chip 0) — its
-        span only (O(state), weights never move).  Returns graph-shaped
-        arrays keyed by state input name."""
+    def read_state(self) -> Dict[str, jax.Array]:
+        """Every state tensor of the resident heap (chip 0), cropped on
+        the device — its span only (O(state), weights never move, nothing
+        reaches the host).  Returns fresh graph-shaped device arrays
+        keyed by state input name: later steps leave them as they are."""
         assert self._heap is not None, "upload() before read_state()"
-        out: Dict[str, np.ndarray] = {}
-        for name, off, rows, ld, cols in self._state_spans:
-            img = np.asarray(self._heap[:, off : off + rows * ld])
-            out[name] = img.reshape(rows, ld)[:, :cols].reshape(
-                self.plan.layout[name].shape)
-        return out
+        return self._jget(self._heap)
 
-    def write_state(self, tensors: Dict[str, np.ndarray]) -> None:
+    def write_state(self, tensors: Dict[str, Any]) -> None:
         """Write new values for every state tensor into the resident
-        heap (partial update — weights are never re-moved, the write is
-        replicated into every chip's heap region).  ``tensors`` maps
-        state input names to graph-shaped arrays."""
+        heap on the device (partial update — weights are never re-moved,
+        pad columns stay 0, the write is replicated into every chip's
+        heap region).  ``tensors`` maps state input names to graph-shaped
+        arrays, device or numpy."""
         assert self._heap is not None, "upload() before write_state()"
         if not self._state_spans:
             return
-        vals = []
-        for name, _, rows, ld, cols in self._state_spans:
-            img = np.zeros((rows, ld), np.float32)  # pad columns stay 0
-            img[:, :cols] = np.asarray(tensors[name],
-                                       np.float32).reshape(rows, cols)
-            vals.append(jnp.asarray(img.ravel()))
+        vals = {name: jnp.asarray(tensors[name], jnp.float32)
+                for name, *_ in self._state_spans}
         self._heap = self._jset(self._heap, vals)
         self.state_scatter_count += 1
 

@@ -5,8 +5,9 @@ Each test lowers and compiles the jitted decode step of
 ``MegakernelExecutor`` with ``interpret=False`` against one chip of a
 described ``v5e:2x2`` topology, at published widths cut to one layer,
 8 slots × 1024 positions: deepseek-7b as ``chip_smoke.py`` serves it
-(also with the trace ring on) and mamba2-2.7b.  Shapes only — the 7 GB
-heap is a ``ShapeDtypeStruct``.
+(also with the trace ring on) and mamba2-2.7b; and the prefill's state
+gather and scatter at the cells' sizes.  Shapes only — the 7 GB heap is
+a ``ShapeDtypeStruct``.
 What Mosaic or XLA:TPU refuses (an unaligned DMA, too much VMEM, a heap
 that does not fit the chip) fails here, at no chip time.  A compile that
 passes says nothing about results or speed.
@@ -87,6 +88,36 @@ def test_step_compiles_for_v5e(one_chip, no_persistent_cache, arch, trace):
     # the heap is donated and aliased in place: no second copy
     assert mem.alias_size_in_bytes >= 4 * plan.heap_size
     assert total < CHIP_BYTES, total
+
+
+@pytest.mark.parametrize("arch,layers", [("deepseek-7b", 1),
+                                         ("mamba2-2.7b", 4)],
+                         ids=["deepseek-L1", "mamba2-L4"])
+def test_state_gather_scatter_compile_in_bounded_memory(
+        one_chip, no_persistent_cache, arch, layers):
+    """The prefill's state gather and scatter at the cells' sizes (8 slots
+    × 1024): the heap is donated to the scatter, and neither makes a
+    temporary the size of a padded state span (an SSM state span is
+    ~860 MB padded for 21 MB live)."""
+    from repro.configs import get_config
+    from repro.kernels.megakernel.desc import heap_words
+    from repro.kernels.megakernel.ops import (MegakernelExecutor,
+                                              compile_decode_megakernel)
+
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
+    plan = compile_decode_megakernel(cfg, 8, 1024)
+    ex = MegakernelExecutor(plan, cfg)
+    heap = jax.ShapeDtypeStruct((1, heap_words(plan.heap_size)),
+                                jnp.float32, sharding=one_chip)
+    state = {name: jax.ShapeDtypeStruct(plan.layout[name].shape,
+                                        jnp.float32, sharding=one_chip)
+             for name, *_ in ex._state_spans}
+    span = max(4 * rows * ld for _, _, rows, ld, _ in ex._state_spans)
+    gather = ex._jget.lower(heap).compile().memory_analysis()
+    scatter = ex._jset.lower(heap, state).compile().memory_analysis()
+    assert scatter.alias_size_in_bytes >= 4 * plan.heap_size
+    for mem in (gather, scatter):
+        assert mem.temp_size_in_bytes < span, (mem.temp_size_in_bytes, span)
 
 
 def test_compiled_kernel_refuses_unported_plans():
