@@ -18,7 +18,6 @@ SECTIONS = [
     "fig12_pipelining",
     "fig13_overlap",
     "fig14_worker_scaling",
-    "fig15_dyn_sched",
     "trace_reconcile",
     "launch_reduction",
     "serving_load",

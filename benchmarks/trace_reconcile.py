@@ -1,15 +1,14 @@
 """Observability section: trace-ring overhead and predicted-vs-observed
 reconciliation.
 
-For scheduler × W cells on the quickstart model, decode one step twice —
+For W cells on the quickstart model, decode one step twice —
 trace off, then trace on — through the megakernel backend, and report
 
 * the wall-clock cost of the in-kernel trace ring (per-step delta),
 * the ring-decode + Chrome-trace export time,
 * the reconciliation of the compiler's predicted timeline against the
   kernel's observed one (mean |rank skew| and worker agreement — the
-  numbers that make ``replay_partition``/``simulate_dynamic`` a
-  trustworthy cost oracle).
+  numbers that make ``replay_partition`` a trustworthy cost oracle).
 
 ``--json PATH`` writes the table as BENCH_trace.json for the nightly
 perf-trajectory artifact.
@@ -25,10 +24,10 @@ import numpy as np
 
 from .common import emit
 
-CELLS = [("static", 1), ("static", 2), ("static", 4), ("dynamic", 4)]
+WORKERS = (1, 2, 4)
 
 
-def _run_cell(cfg, params, scheduler: str, workers: int) -> dict:
+def _run_cell(cfg, params, workers: int) -> dict:
     import jax.numpy as jnp  # noqa: F401
 
     from repro.api import compile as mpk_compile
@@ -39,8 +38,8 @@ def _run_cell(cfg, params, scheduler: str, workers: int) -> dict:
 
     def step_time(trace: bool):
         prog = mpk_compile(cfg, 2, 16, backend="megakernel",
-                           num_workers=workers, scheduler=scheduler,
-                           trace=trace).bind(params).init_state()
+                           num_workers=workers, trace=trace)
+        prog = prog.bind(params).init_state()
         prog.step(toks, lens)          # trace + upload, not timed
         t0 = time.time()
         prog.step(toks, lens)
@@ -57,7 +56,7 @@ def _run_cell(cfg, params, scheduler: str, workers: int) -> dict:
     rep = reconcile(predicted, observed)
     problems = check_event_order(observed)
     return {
-        "scheduler": scheduler, "workers": workers,
+        "workers": workers,
         "step_us_off": dt_off * 1e6, "step_us_on": dt_on * 1e6,
         "trace_overhead_pct": 100.0 * (dt_on - dt_off) / max(dt_off, 1e-12),
         "decode_export_us": dt_decode * 1e6,
@@ -88,10 +87,10 @@ def main() -> None:
 
     print("# trace ring: per-step overhead + predicted-vs-observed skew")
     rows = []
-    for scheduler, workers in CELLS:
-        r = _run_cell(cfg, params, scheduler, workers)
+    for workers in WORKERS:
+        r = _run_cell(cfg, params, workers)
         rows.append(r)
-        emit(f"trace_{scheduler}_w{workers}", r["step_us_on"],
+        emit(f"trace_static_w{workers}", r["step_us_on"],
              f"overhead={r['trace_overhead_pct']:.1f}%"
              f" rank_skew={r['mean_abs_rank_skew']:.4f}"
              f" agree={r['worker_agreement']:.2f}"

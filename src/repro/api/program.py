@@ -120,14 +120,12 @@ class Program:
 
     def __init__(self, cfg, batch: int, max_seq: int,
                  step_cache: Optional[Dict[tuple, Callable]] = None,
-                 pipeline_depth: int = 2, num_workers: int = 1,
-                 scheduler: str = "static"):
+                 pipeline_depth: int = 2, num_workers: int = 1):
         self.cfg = cfg
         self.batch = batch
         self.max_seq = max_seq
         self.pipeline_depth = pipeline_depth
         self.num_workers = num_workers
-        self.scheduler = scheduler
         self.step_count = 0
         # (cfg, width)-keyed jitted prefill fns; pass a shared dict to
         # reuse compiled steps across programs/engines (benchmark warmup)
@@ -136,7 +134,6 @@ class Program:
         self._params: Any = None
         self._params_dev: Any = None   # jnp mirror for the prefill path
         self._compiled: Optional[CompiledTGraph] = None
-        self._dyn_stats_cache: Optional[Dict[str, Any]] = None
 
     # ----------------------------------------------------------- lifecycle
     def bind(self, params) -> "Program":
@@ -227,8 +224,7 @@ class Program:
             g = build_decode_graph(self.cfg, self.batch, self.max_seq)
             self._compiled = megakernelize(g, CompileOptions(
                 pipeline_depth=self.pipeline_depth,
-                num_workers=self.num_workers,
-                scheduler=self.scheduler))
+                num_workers=self.num_workers))
         return self._compiled
 
     @property
@@ -256,18 +252,14 @@ class Program:
         """The W-worker schedule→runtime contract: the compiler's worker
         partition (queue lengths, cross-worker event cut) plus the
         simulator's replay of that exact partition (makespan, per-worker
-        utilization).  Under ``scheduler="dynamic"`` the dynamic
-        scheduler's own numbers are added: the event-driven ``mpk_dyn``
-        makespan and the protocol replay's queue-depth / pop-source
-        profile.  The megakernel backend extends this with the kernel's
-        own per-worker DMA/event/queue counters after a step."""
+        utilization).  The megakernel backend extends this with the
+        kernel's own per-worker DMA/event counters after a step."""
         from ..core.runtime_sim import SimConfig, simulate
         part = self.compiled.partition
         res = simulate(self.compiled,
                        SimConfig(mode="mpk", n_workers=part.requested_workers,
                                  pipeline_depth=self.pipeline_depth))
-        out = {
-            "scheduler": self.scheduler,
+        return {
             "num_workers": part.num_workers,
             "requested_workers": part.requested_workers,
             "queue_lens": [len(q) for q in part.queues],
@@ -276,36 +268,6 @@ class Program:
             "sim_makespan_us": res.makespan * 1e6,
             "worker_utilization": list(res.worker_busy or []),
         }
-        if self.scheduler == "dynamic":
-            out.update(self._dyn_sched_stats())
-        return out
-
-    def _dyn_sched_stats(self) -> Dict[str, Any]:
-        """The dynamic scheduler's static numbers (protocol replay +
-        ``mpk_dyn`` simulation), computed once per program — they only
-        depend on the compiled plan, and the replay is O(tasks × pool
-        scan) python."""
-        if getattr(self, "_dyn_stats_cache", None) is None:
-            from ..core.runtime_sim import SimConfig, simulate
-            from ..runtime.dyn_sched import (build_dyn_sched,
-                                             replay_sequential)
-            part = self.compiled.partition
-            dres = simulate(self.compiled,
-                            SimConfig(mode="mpk_dyn",
-                                      n_workers=part.requested_workers,
-                                      pipeline_depth=self.pipeline_depth))
-            dyn = getattr(getattr(self, "plan", None), "dyn", None)
-            if dyn is None:
-                dyn = build_dyn_sched(self.compiled)
-            tr = replay_sequential(dyn)
-            self._dyn_stats_cache = {
-                "dyn_sim_makespan_us": dres.makespan * 1e6,
-                "queue_max_depth": tr.max_depth,
-                "replay_pops_own": tr.pops_own,
-                "replay_pops_overflow": tr.pops_overflow,
-                "replay_steals": tr.steals,
-            }
-        return self._dyn_stats_cache
 
     def describe(self) -> Dict[str, Any]:
         c = self.compiled
@@ -323,14 +285,12 @@ class Program:
     # -------------------------------------------------- observability
     def predicted_trace(self):
         """The compiler's *predicted* per-task timeline as an
-        ``obs.TaskTrace`` (roofline seconds): ``replay_partition`` under
-        the static scheduler, ``simulate_dynamic`` under the dynamic one
-        — the prediction ``obs.reconcile`` checks against an observed
-        trace."""
+        ``obs.TaskTrace`` (roofline seconds): ``replay_partition`` — the
+        prediction ``obs.reconcile`` checks against an observed trace."""
         from ..obs import predicted_task_trace
         part = self.compiled.partition
         return predicted_task_trace(
-            self.compiled, self.scheduler,
+            self.compiled,
             num_workers=(part.requested_workers if part is not None
                          else self.num_workers),
             pipeline_depth=self.pipeline_depth,
@@ -351,7 +311,7 @@ class Program:
                          ) -> Dict[str, Any]:
         """One JSON-ready dict joining every metrics surface: program
         identity (``describe``), compiler stats, the schedule→kernel
-        pipeline contract, the worker/scheduler/COMM counters, and —
+        pipeline contract, the worker/COMM counters, and —
         when a serving engine passes its ``metrics_summary()`` — the
         TTFT/TPOT/queue latency percentiles."""
         snap: Dict[str, Any] = {
@@ -379,10 +339,9 @@ class JaxProgram(Program):
     backend = "jax"
 
     def __init__(self, cfg, batch, max_seq, step_cache=None,
-                 pipeline_depth: int = 2, num_workers: int = 1,
-                 scheduler: str = "static"):
+                 pipeline_depth: int = 2, num_workers: int = 1):
         super().__init__(cfg, batch, max_seq, step_cache, pipeline_depth,
-                         num_workers, scheduler)
+                         num_workers)
         self._cache = None
         # donated slot zeroing: no full-cache copy per admission
         self._jreset = jax.jit(
@@ -440,8 +399,7 @@ class InterpreterProgram(Program):
                  options: Optional[CompileOptions] = None, tp: int = 1):
         super().__init__(cfg, batch, max_seq, step_cache,
                          options.pipeline_depth if options else 2,
-                         options.num_workers if options else 1,
-                         options.scheduler if options else "static")
+                         options.num_workers if options else 1)
         g = build_decode_graph(cfg, batch, max_seq, tp=tp)
         t0 = time.perf_counter()
         self._compiled = megakernelize(g, options)
@@ -449,25 +407,12 @@ class InterpreterProgram(Program):
         self._compiled.stats["compile_wall_s"] = time.perf_counter() - t0
         self._smap = _state_map(cfg)
         self._cache = None
-        # dynamic scheduler: execute in the protocol-replay order (one
-        # legal execution of the ready-queue runtime — bitwise-identical
-        # results prove order-independence of the compiled tasks)
-        self._dyn_order = None
-        self._seq_trace = None
-        if self.scheduler == "dynamic":
-            from ..runtime.dyn_sched import (build_dyn_sched,
-                                             replay_sequential)
-            dyn = build_dyn_sched(self._compiled)
-            self._seq_trace = replay_sequential(dyn)
-            self._dyn_order = self._seq_trace.task_order(dyn)
 
     def trace(self):
         """The interpreter's sequential execution as an ``obs.TaskTrace``
-        on the kernel ring's two-ticks-per-task clock (dynamic: the
-        protocol replay's pop order/lanes/sources)."""
+        on the kernel ring's two-ticks-per-task clock."""
         from ..obs import sequential_trace
-        return sequential_trace(self._compiled, self.scheduler,
-                                seq=self._seq_trace)
+        return sequential_trace(self._compiled)
 
     def bind(self, params) -> "Program":
         self._params = _np_tree(params)
@@ -494,7 +439,7 @@ class InterpreterProgram(Program):
         assert self._params is not None, "bind() first"
         binds = decode_bindings(self.cfg, self._params, self._cache,
                                 tokens_or_embeds, seq_lens, positions)
-        out = execute_tgraph(self._compiled, binds, order=self._dyn_order)
+        out = execute_tgraph(self._compiled, binds)
         for ent in self._smap:  # fold updated state back into the pytree
             leaf = self._cache[ent["key"]]
             leaf[ent["blk"], ent["idx"]] = np.asarray(
@@ -514,10 +459,9 @@ class PallasProgram(Program):
     def __init__(self, cfg, batch, max_seq, step_cache=None, *,
                  max_rows: int = 8, latency_aware: bool = True,
                  event_fusion: bool = True, pipeline_depth: int = 2,
-                 num_workers: int = 1, scheduler: str = "static",
-                 tp: int = 1, trace: bool = False):
+                 num_workers: int = 1, tp: int = 1, trace: bool = False):
         super().__init__(cfg, batch, max_seq, step_cache, pipeline_depth,
-                         num_workers, scheduler)
+                         num_workers)
         self.tp = tp
         # late import keeps the api package importable without pallas
         from ..kernels.megakernel import (MegakernelExecutor,
@@ -526,7 +470,7 @@ class PallasProgram(Program):
             cfg, batch, max_seq, max_rows=max_rows,
             latency_aware=latency_aware, event_fusion=event_fusion,
             pipeline_depth=pipeline_depth, num_workers=num_workers,
-            scheduler=scheduler, tp=tp, trace=trace)
+            tp=tp, trace=trace)
         self._compiled = self.plan.compiled
         self.executor = MegakernelExecutor(self.plan, cfg)
         smap = _state_map(cfg)
@@ -590,9 +534,7 @@ class PallasProgram(Program):
     def worker_stats(self) -> Dict[str, Any]:
         """Simulator-side partition stats plus — after a step — the
         kernel's live per-worker DMA/event counters (the decentralized
-        runtime's own accounting, read from the heap stats blocks).
-        Under the dynamic scheduler the in-heap queue cursors and
-        pop-source counters are merged in too."""
+        runtime's own accounting, read from the heap stats blocks)."""
         out = dict(Program.worker_stats.fget(self))
         if self.step_count > 0:
             per_worker = self.executor.worker_counters()
@@ -600,9 +542,6 @@ class PallasProgram(Program):
             for k in ("event_waits", "event_wait_violations",
                       "event_signals"):
                 out[k] = sum(d[k] for d in per_worker)
-            if self.scheduler == "dynamic":
-                out.update({f"kernel_{k}": v for k, v in
-                            self.executor.scheduler_counters().items()})
         return out
 
     def bind(self, params) -> "Program":
@@ -667,8 +606,8 @@ def compile(cfg, batch: int, max_seq: int, backend: str = "jax", *,
             step_cache: Optional[Dict[tuple, Callable]] = None,
             max_rows: Optional[int] = None, latency_aware: bool = True,
             event_fusion: bool = True, pipeline_depth: int = 2,
-            num_workers: int = 1, scheduler: str = "static",
-            tp: int = 1, trace: bool = False) -> Program:
+            num_workers: int = 1, tp: int = 1,
+            trace: bool = False) -> Program:
     """Compile ``cfg``'s decode step once; returns a stateful
     :class:`Program` for ``backend`` ("jax" | "interpreter" |
     "megakernel").
@@ -683,18 +622,12 @@ def compile(cfg, batch: int, max_seq: int, backend: str = "jax", *,
     the schedule onto W decentralized workers (per-worker descriptor
     streams + in-heap event counters on the megakernel; see
     ``Program.worker_stats`` — outputs are bitwise-identical across W),
-    ``scheduler`` picks the runtime dispatch: ``"static"`` executes the
-    partition as lowered, ``"dynamic"`` dispatches from heap-resident
-    ready queues at execution time (pop → wait → compute →
-    signal-and-enqueue; outputs stay bitwise-identical to static —
-    the megakernel runs the in-kernel protocol, the interpreter executes
-    its sequential replay, the jax oracle is unaffected), ``tp`` inserts
-    AllReduce ops (paper §6.5) — on the interpreter backend they compile
-    to graph stats, on the megakernel backend ``tp > 1`` stamps the plan
-    into per-chip task tables whose collectives execute in-kernel as
-    chunked ring-allreduce COMM tasks (``desc.stamp_multichip``; static
-    scheduler only; per-chip outputs are bitwise-identical across
-    TP ∈ {1, 2, 4}).  ``step_cache`` shares (cfg, width)-keyed jitted
+    ``tp`` inserts AllReduce ops (paper §6.5) — on the interpreter
+    backend they compile to graph stats, on the megakernel backend
+    ``tp > 1`` stamps the plan into per-chip task tables whose
+    collectives execute in-kernel as chunked ring-allreduce COMM tasks
+    (``desc.stamp_multichip``; per-chip outputs are bitwise-identical
+    across TP ∈ {1, 2, 4}).  ``step_cache`` shares (cfg, width)-keyed jitted
     prefill steps across programs.  ``trace=True`` enables the
     megakernel's heap-resident task trace ring (``Program.trace()``
     decodes it into an ``obs.TaskTrace``); trace-off programs are
@@ -705,9 +638,6 @@ def compile(cfg, batch: int, max_seq: int, backend: str = "jax", *,
             f"unknown backend {backend!r}; expected one of {BACKENDS}")
     if num_workers < 1:
         raise ValueError(f"num_workers must be >= 1, got {num_workers}")
-    if scheduler not in ("static", "dynamic"):
-        raise ValueError(f"unknown scheduler {scheduler!r}; "
-                         "expected 'static' or 'dynamic'")
     if backend == "interpreter":
         dec = (DecomposeConfig() if max_rows is None
                else DecomposeConfig(max_rows=max_rows))
@@ -717,7 +647,6 @@ def compile(cfg, batch: int, max_seq: int, backend: str = "jax", *,
             event_fusion=event_fusion,
             pipeline_depth=pipeline_depth,
             num_workers=num_workers,
-            scheduler=scheduler,
             trace=trace)
         return InterpreterProgram(cfg, batch, max_seq, step_cache,
                                   options=opts, tp=tp)
@@ -727,11 +656,10 @@ def compile(cfg, batch: int, max_seq: int, backend: str = "jax", *,
                              latency_aware=latency_aware,
                              event_fusion=event_fusion,
                              pipeline_depth=pipeline_depth,
-                             num_workers=num_workers,
-                             scheduler=scheduler, tp=tp, trace=trace)
+                             num_workers=num_workers, tp=tp, trace=trace)
     if tp != 1:
         raise ValueError(f"tp={tp} is only supported on the interpreter "
                          "and megakernel backends")
     return JaxProgram(cfg, batch, max_seq, step_cache,
                       pipeline_depth=pipeline_depth,
-                      num_workers=num_workers, scheduler=scheduler)
+                      num_workers=num_workers)

@@ -53,16 +53,9 @@ class CompileOptions:
     #: makespan-minimizing partitioner, lowered to per-worker descriptor
     #: streams synchronized through in-heap event counters
     num_workers: int = 1
-    #: task dispatch at runtime (paper §5.1): "static" executes the
-    #: partition's per-worker streams as lowered; "dynamic" replaces
-    #: them with heap-resident ready queues — workers pop the next ready
-    #: task, event-counter triggers enqueue newly-ready consumers, and
-    #: the partition survives only as a placement hint
-    #: (``runtime/dyn_sched.py``)
-    scheduler: str = "static"
     #: emit the heap-resident per-task trace ring (observability): the
     #: kernel timestamps every executed task slot with a logical tick
-    #: counter and records worker/task/kind/pop-source/wait-count.  Off
+    #: counter and records worker/task/kind/wait-count.  Off
     #: by default so the descriptor table and heap layout stay bitwise
     #: identical to the untraced build.
     trace: bool = False
@@ -290,9 +283,6 @@ def megakernelize(
 ) -> CompiledTGraph:
     """The MPK compiler: computation graph → compiled SM-level tGraph."""
     opts = options or CompileOptions()
-    if opts.scheduler not in ("static", "dynamic"):
-        raise ValueError(f"unknown scheduler {opts.scheduler!r}; "
-                         "expected 'static' or 'dynamic'")
     g.validate()
 
     tg = decompose(g, opts.decompose)
@@ -331,7 +321,6 @@ def megakernelize(
                for n in layout)
     stats["workspace_elements_no_reuse"] = bump
     stats["workspace_reuse_x"] = bump / max(ws_size, 1)
-    stats["scheduler"] = opts.scheduler
     stats["num_workers"] = partition.num_workers
     stats["worker_queue_lens"] = [len(q) for q in partition.queues]
     stats["cross_worker_deps"] = len(partition.cross_deps)

@@ -3,7 +3,7 @@
 CPU-only container: wall-clock GPU numbers can't be measured, so the
 paper's latency figures are reproduced *structurally*: the compiled tGraph
 is executed by a discrete-event model of workers / schedulers / DMA
-channels with per-task times derived from the roofline terms, under three
+channels with per-task times derived from the roofline terms, under four
 execution models:
 
   kernel_per_op — operator-at-a-time with a kernel barrier + launch
@@ -31,16 +31,7 @@ execution models:
                   path).  ``comm_plan="serialized"`` is the fig13
                   baseline: the whole tensor crosses the wire twice per
                   collective with no chunking (pair with
-                  ``overlap_comm=False`` for the fully blocking variant),
-  mpk_dyn       — the decentralized *dynamic* scheduler
-                  (``runtime/dyn_sched.py``): workers pop ready tasks
-                  from heap-resident queues (own pool → shared overflow
-                  → stealing), event-counter triggers enqueue newly-
-                  ready consumers at runtime.  Charges match the mpk
-                  replay task for task (same pipelined costs, same
-                  cross-worker event waits, the same demand-load stall
-                  rule applied to pop gaps), so mpk vs mpk_dyn isolates
-                  exactly what runtime dispatch buys.
+                  ``overlap_comm=False`` for the fully blocking variant).
 
 Per-task time = max(flops/worker_flops, bytes/worker_bw) + task_overhead;
 comm-task time = bytes/ici_bw.  Hardware constants come from
@@ -51,9 +42,8 @@ roofline, scheduler and simulator share one source of truth.
 lengths scale every ATTENTION_DECODE task's cost by
 ``mean(kv_lens[rows]) / max(kv_lens)`` — the nominal roofline cost
 assumes every slot reads the full cache, so a ragged decode batch makes
-some attention tiles proportionally cheaper.  The static partition was
-balanced for uniform costs and cannot react; the dynamic scheduler
-rebalances by construction.  ``fig15_dyn_sched.py`` sweeps this.
+some attention tiles proportionally cheaper.  The partition was
+balanced for uniform costs and cannot react.
 """
 from __future__ import annotations
 
@@ -85,7 +75,7 @@ class SimConfig:
     jit_hop: float = JIT_HOP          # worker->scheduler->worker (§5.2)
     aot_wait: float = AOT_EVENT_WAIT  # one event wait
     launch_overhead: float = 3.8e-6  # per-kernel launch (paper §6.6)
-    mode: str = "mpk"   # kernel_per_op | mpk | mpk_coarse | mpk_dyn | mpk_tp
+    mode: str = "mpk"   # kernel_per_op | mpk | mpk_coarse | mpk_tp
     overlap_comm: bool = True
     #: number of TP chips (mode="mpk_tp"); tp<=1 reduces exactly to "mpk"
     tp: int = 1
@@ -105,11 +95,6 @@ class SimConfig:
     #: ``pipelined=False`` is the per-row synchronous-copy baseline.
     pipelined: bool = True
     pipeline_depth: int = 2
-    #: extra per-pop cost of the dynamic scheduler (mode="mpk_dyn").
-    #: Default 0: the queue-head pop-ahead hides the dequeue behind the
-    #: previous task's compute exactly as descriptor prefetch hides the
-    #: static stream's decode — set > 0 for sensitivity analysis.
-    queue_overhead: float = 0.0
 
 
 @dataclasses.dataclass
@@ -162,7 +147,7 @@ def skewed_time_fn(base_fn, kv_lens: Sequence[int]):
     costs ``mean(kv_lens[r0:r0+m]) / max(kv_lens)`` of its nominal time
     (the nominal roofline cost reads the full cache for every slot).
     Non-attention tasks are unchanged, so the skew isolates exactly the
-    raggedness the paper's dynamic scheduler absorbs."""
+    raggedness of the decode batch."""
     kv = list(kv_lens)
     ref = max(kv) if kv else 1
 
@@ -181,8 +166,8 @@ def skewed_time_fn(base_fn, kv_lens: Sequence[int]):
 
 
 def _mpk_cost_model(compiled: CompiledTGraph, cfg: SimConfig):
-    """The ``(partition, time_fn, wait_fn)`` triple the mpk/mpk_dyn/
-    mpk_tp replays run under (paper §5).  The partition IS the schedule
+    """The ``(partition, time_fn, wait_fn)`` triple the mpk/mpk_tp
+    replays run under (paper §5).  The partition IS the schedule
     the megakernel executes: static per-worker queues cut out of the
     linearized order, synchronized by in-heap event counters on the
     cross-worker edges.  When the compile-time width differs from the
@@ -219,8 +204,7 @@ def _mpk_cost_model(compiled: CompiledTGraph, cfg: SimConfig):
 
     if part is None or part.requested_workers != cfg.n_workers:
         # the partitioner always balances for the NOMINAL (uniform)
-        # costs — compile time cannot predict runtime raggedness,
-        # which is exactly what mpk vs mpk_dyn measures under skew
+        # costs — compile time cannot predict runtime raggedness
         part = partition_workers(tg, compiled.lin, cfg.n_workers,
                                  cfg.pipeline_depth,
                                  time_fn=base_time_fn,
@@ -237,35 +221,15 @@ def predicted_timeline(compiled: CompiledTGraph,
     """The *predicted* per-task timeline of the mpk replays, in one
     schema: ``{"mode", "makespan", "start", "end", "worker"}`` with
     ``start``/``end``/``worker`` keyed by task id.  ``mode="mpk"`` (or
-    ``"mpk_tp"``) replays the static partition with
-    :func:`~repro.core.schedule.replay_partition`; ``mode="mpk_dyn"``
-    runs :func:`~repro.runtime.dyn_sched.simulate_dynamic` and converts
-    its descriptor-row keys back to task ids.  The ``obs`` package
+    ``"mpk_tp"``) replays the partition with
+    :func:`~repro.core.schedule.replay_partition`.  The ``obs`` package
     reconciles this against the kernel's trace ring."""
     cfg = cfg or SimConfig()
-    if cfg.mode not in ("mpk", "mpk_dyn", "mpk_tp"):
+    if cfg.mode not in ("mpk", "mpk_tp"):
         raise ValueError(f"predicted_timeline needs an mpk mode, got "
                          f"{cfg.mode!r}")
     tg = compiled.tg
     part, time_fn, wait_fn = _mpk_cost_model(compiled, cfg)
-
-    if cfg.mode == "mpk_dyn":
-        from ..runtime.dyn_sched import build_dyn_sched, simulate_dynamic
-        dyn = build_dyn_sched(compiled, part)
-        tasks = [tg.tasks[tid] for tid in compiled.order]
-        dres = simulate_dynamic(
-            dyn, tasks, time_fn, wait_fn,
-            queue_overhead=cfg.queue_overhead,
-            pipeline_depth=(cfg.pipeline_depth if cfg.pipelined else 1),
-            overlap_comm=cfg.overlap_comm, n_dma=cfg.n_dma)
-        order = compiled.order
-        return {
-            "mode": cfg.mode,
-            "makespan": dres.makespan,
-            "start": {order[r]: t for r, t in dres.start.items()},
-            "end": {order[r]: t for r, t in dres.done.items()},
-            "worker": {order[r]: w for r, w in dres.worker.items()},
-        }
 
     res = replay_partition(
         tg, part.queues, part.step_of, time_fn=time_fn, wait_fn=wait_fn,
@@ -315,30 +279,9 @@ def simulate(compiled: CompiledTGraph,
                          sum(1 for x in tg.tasks.values() if x.is_comm),
                          len(per_op))
 
-    if cfg.mode in ("mpk", "mpk_dyn", "mpk_tp"):
+    if cfg.mode in ("mpk", "mpk_tp"):
         part, time_fn, wait_fn = _mpk_cost_model(compiled, cfg)
         width = max(1, part.num_workers)
-
-        if cfg.mode == "mpk_dyn":
-            # ---- decentralized dynamic scheduler (ready queues) ----
-            from ..runtime.dyn_sched import build_dyn_sched, simulate_dynamic
-            dyn = build_dyn_sched(compiled, part)
-            tasks = [tg.tasks[tid] for tid in compiled.order]
-            dres = simulate_dynamic(
-                dyn, tasks, time_fn, wait_fn,
-                queue_overhead=cfg.queue_overhead,
-                pipeline_depth=(cfg.pipeline_depth if cfg.pipelined
-                                else 1),
-                overlap_comm=cfg.overlap_comm, n_dma=cfg.n_dma)
-            makespan = dres.makespan
-            return SimResult(
-                makespan,
-                sum(dres.busy) / (makespan * width + 1e-30),
-                sum(1 for x in tg.tasks.values() if not x.is_dummy),
-                sum(1 for x in tg.tasks.values() if x.is_comm),
-                1,
-                worker_busy=[b / max(makespan, 1e-30)
-                             for b in dres.busy])
 
         res = replay_partition(
             tg, part.queues, part.step_of, time_fn=time_fn,

@@ -57,9 +57,7 @@ covered by an event counter resident in the heap at ``event_offset``
      stats block (a compiler bug, asserted zero by the tests).
   34 sig_ev    event-table index this task increments after its stores
      land, -1 if no consumer waits on it.
-  35 affinity  (dynamic scheduler only) the worker pool newly-ready
-     tasks are enqueued onto — the static partition's ``worker_of``
-     placement hint; reserved (0) under the static scheduler.
+  35 reserved (0)
 
 Every prefetch row copy is TN elements wide: row-slot padding
 (``ld >= cols + TN``) guarantees a TN-wide read from any legal element
@@ -78,33 +76,6 @@ at ``event_offset``) followed by a per-worker ``STATS_WORDS``-sized DMA/
 event counter block (written by the kernel itself, read back via
 ``MegakernelExecutor.pipeline_counters()`` / ``worker_counters()``) at
 ``stats_offset``.
-
-Dynamic scheduler (``scheduler="dynamic"``, see
-``runtime/dyn_sched.py`` — the protocol's source of truth): the
-descriptor table becomes **schedule-order-free** — one flat row per
-linearized task (row id == linearized position, no step padding), each
-carrying its event wait/signal words (32-34, emitted for EVERY event,
-not just the cross-worker cut) and its affinity worker (35).  The grid
-is ``(ceil(T / W), W)`` pop slots; which task a slot runs is decided at
-execution time by the in-heap ready queues.  Between the event table
-and the stats blocks the heap gains:
-
-  ``queue_offset``   W per-worker ready pools (``QUEUE_CAP`` = 128 f32
-                     words each: a descriptor-row id, or ``QUEUE_EMPTY``)
-                     followed by the shared overflow queue
-                     (``dyn.overflow_cap`` words),
-  ``qc_offset``      per-pool [pushed, popped] cursor counters
-                     (2 × (W + 1) words, pushed pre-charged with the
-                     initial ready image),
-  ``trace_offset``   the pop trace: one word per grid slot recording
-                     the popped row id (``QUEUE_EMPTY`` for idle slots)
-                     — asserted equal to ``dyn_sched.replay_sequential``
-                     by the tests.
-
-The executor re-writes the initial queue image, cursor counters and
-event zeros through the per-step scatter before every launch; the
-consumer lists live in a second scalar-prefetch operand
-(``DynSchedPlan.sched_table()``).
 """
 from __future__ import annotations
 
@@ -133,18 +104,15 @@ DESC_WORDS = 36
 #: them, [2] prefetch tiles issued, [3] primary tiles demand-loaded
 #: (pipeline misses), [4] 2^20-unit spill of [1], [5] event waits
 #: checked, [6] event-wait violations (must stay 0), [7] event signals,
-#: and — dynamic scheduler only, zero under static — [8] pops from the
-#: worker's own pool, [9] pops from the shared overflow queue,
-#: [10] steals from other workers' pools, [11] idle grid slots;
-#: [12] the row copies of [1] that read or write state (SSM and conv
-#: state tiles, K/V cache rows), [13] 2^20-unit spill of [12]
+#: [8-11] reserved (zero), [12] the row copies of [1] that read or write
+#: state (SSM and conv state tiles, K/V cache rows), [13] 2^20-unit
+#: spill of [12]
 STATS_WORDS = 14
 
 #: f32 words PER GRID SLOT in the optional trace ring
-#: (``CompileOptions.trace``): [0] worker lane, [1] descriptor row
-#: (-1 = dynamic idle slot), [2] kind code, [3] logical start tick,
-#: [4] logical end tick, [5] pop source (-1 static / 0 own / 1 overflow
-#: / 2 steal), [6] event-wait trigger count, [7] reserved
+#: (``CompileOptions.trace``): [0] worker lane, [1] descriptor row,
+#: [2] kind code, [3] logical start tick, [4] logical end tick,
+#: [5] reserved (-1), [6] event-wait trigger count, [7] reserved
 TRACE_WORDS = 8
 
 #: words at the head of the trace ring, before the records: word 0 is
@@ -257,21 +225,8 @@ class MegakernelPlan:
     #: heap offset of the event-counter table (one f32 word per event)
     event_offset: int = 0
     #: number of in-heap event counters (0 when W == 1: program order
-    #: covers every dependency, no cross-worker cut exists).  Under the
-    #: dynamic scheduler EVERY event with producers and consumers gets a
-    #: counter — the counters ARE the dispatch mechanism.
+    #: covers every dependency, no cross-worker cut exists)
     num_events: int = 0
-    #: "static" (per-worker descriptor streams, PR 4) or "dynamic"
-    #: (heap-resident ready queues, ``runtime/dyn_sched.py``)
-    scheduler: str = "static"
-    #: the dynamic-scheduler plan (None under the static scheduler)
-    dyn: Any = None
-    #: heap offset of the ready pools (+ overflow queue right after)
-    queue_offset: int = 0
-    #: heap offset of the per-pool [pushed, popped] cursor counters
-    qc_offset: int = 0
-    #: heap offset of the pop trace (one word per grid slot)
-    trace_offset: int = 0
     #: chips of the stamped multichip plan (1 = single-chip).  At C > 1
     #: the heap is C per-chip tensor regions (each ``chip_stride`` words,
     #: the fused transport of ``distributed/comm_tasks``) followed by the
@@ -291,7 +246,7 @@ class MegakernelPlan:
     def pipeline_stats(self) -> Dict[str, Any]:
         """The static half of the schedule→kernel pipeline contract:
         scheduler stalls plus the prefetch plan's coverage over the
-        descriptor table (the dynamic half — actual bulk-DMA counts — is
+        descriptor table (the measured half — actual bulk-DMA counts — is
         ``MegakernelExecutor.pipeline_counters()``), and ``tile_fill``: the
         words of the matmul tasks' own tiles (k × n) over the words their
         TN-wide weight-row copies move (k × TN)."""
@@ -584,11 +539,7 @@ def _build_layout(compiled: CompiledTGraph, tn: int
 
 def lower_tgraph(compiled: CompiledTGraph, cfg,
                  tn: Optional[int] = None,
-                 scheduler: str = "static",
                  trace: bool = False) -> MegakernelPlan:
-    if scheduler not in ("static", "dynamic"):
-        raise ValueError(f"unknown scheduler {scheduler!r}; "
-                         "expected 'static' or 'dynamic'")
     g = compiled.graph
     tg = compiled.tg
 
@@ -852,10 +803,6 @@ def lower_tgraph(compiled: CompiledTGraph, cfg,
         part = partition_workers(tg, compiled.lin, 1)
         compiled.partition = part
 
-    if scheduler == "dynamic":
-        return _lower_dynamic(compiled, cfg, descs, layout, heap_size,
-                              statics, part, trace)
-
     # ---- scatter the task table onto the (step, worker) grid ----
     W = part.num_workers
     num_steps = part.num_steps
@@ -1020,8 +967,6 @@ def stamp_multichip(plan: MegakernelPlan, n_chips: int) -> MegakernelPlan:
     """
     from ...distributed.comm_tasks import (expand_ring_allreduce,
                                            n_comm_events, n_ring_steps)
-    assert plan.scheduler == "static", \
-        "multichip stamping requires the static scheduler"
     C = n_chips
     if C <= 1:
         return plan
@@ -1146,67 +1091,3 @@ def stamp_multichip(plan: MegakernelPlan, n_chips: int) -> MegakernelPlan:
                           C * nev0 + n_comm_ev, n_chips=C,
                           chip_stride=chip_stride, trace=plan.trace,
                           ring_offset=ring_off)
-
-
-def _lower_dynamic(compiled: CompiledTGraph, cfg, descs: np.ndarray,
-                   layout: Dict[str, TensorSlot], heap_size: int,
-                   statics: Dict[str, Any], part,
-                   trace: bool = False) -> MegakernelPlan:
-    """Finish the lowering for ``scheduler="dynamic"``: keep the flat
-    per-task table in linearized order (row id == lin position — the pop
-    priority), stamp every row's event wait/signal words + affinity, and
-    append the ready-queue regions to the heap.  No prefetch plan: which
-    task a slot runs is a runtime decision, so every task demand-loads
-    its primary tile through its own record (words 28-30) — the cost the
-    ``mpk_dyn`` simulator charges as the per-pop queue overhead."""
-    from ...runtime.dyn_sched import QUEUE_CAP, build_dyn_sched
-
-    dyn = build_dyn_sched(compiled, part)
-    W = dyn.num_workers
-    T = dyn.num_tasks
-    assert descs.shape[0] == T
-
-    for row in range(T):
-        rec = _primary_record(descs[row])
-        if rec is not None:
-            descs[row, 28:31] = rec
-        descs[row, 35] = dyn.affinity[row]
-        e = int(dyn.wait_ev[row])
-        if e >= 0:
-            descs[row, 32] = e
-            descs[row, 33] = dyn.trigger[e]
-        descs[row, 34] = dyn.sig_ev[row]
-
-    num_steps = -(-T // W)             # pop slots per worker lane
-    event_offset = heap_size
-    heap_size += dyn.num_events
-    queue_offset = heap_size
-    heap_size += W * QUEUE_CAP + dyn.overflow_cap
-    qc_offset = heap_size
-    heap_size += 2 * (W + 1)
-    trace_offset = heap_size
-    heap_size += num_steps * W
-    stats_offset = heap_size
-    heap_size += STATS_WORDS * W
-    ring_offset = 0
-    if trace:
-        ring_offset = heap_size
-        heap_size += TRACE_HEADER + num_steps * W * TRACE_WORDS
-
-    statics.update({
-        "W": W, "NUM_STEPS": num_steps, "EVENT_OFF": event_offset,
-        "N_EVENTS": dyn.num_events, "STATS_OFF": stats_offset,
-        "DYN": 1, "QOFF": queue_offset, "QCAP": QUEUE_CAP,
-        "OV_ROWS": dyn.overflow_cap // QUEUE_CAP, "QC_OFF": qc_offset,
-        "TRACE_OFF": trace_offset, "T_TASKS": T,
-        "MAX_OUT": dyn.max_out,
-    })
-    if trace:
-        statics["TRACE"] = 1
-        statics["TR_OFF"] = ring_offset
-    return MegakernelPlan(compiled, descs, layout, heap_size, statics,
-                          stats_offset, W, num_steps, event_offset,
-                          dyn.num_events, scheduler="dynamic", dyn=dyn,
-                          queue_offset=queue_offset, qc_offset=qc_offset,
-                          trace_offset=trace_offset, trace=trace,
-                          ring_offset=ring_offset)

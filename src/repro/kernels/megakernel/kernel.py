@@ -1,7 +1,6 @@
 """The persistent megakernel: one ``pl.pallas_call`` executes an entire
-compiled tGraph as W decentralized per-worker task streams — statically
-partitioned (``scheduler="static"``) or dispatched at runtime from
-heap-resident ready queues (``scheduler="dynamic"``).
+compiled tGraph as W decentralized per-worker task streams, statically
+partitioned by the compiler.
 
 TPU adaptation of MPK's in-kernel runtime (paper §5): the grid is 2-D
 ``(step, worker)`` — each worker walks its own static descriptor stream
@@ -53,32 +52,11 @@ A per-worker counter block (``STATS_WORDS`` f32 words per worker at
 itself: [0] bulk tile DMAs issued, [1] row copies inside them (what the
 pre-pipelining kernel issued as individual DMAs), [2] prefetch tiles
 issued, [3] primary tiles demand-loaded, [5] event waits checked,
-[6] event-wait violations, [7] event signals, [8-11] dynamic-scheduler
-pops (own pool / overflow / steals / idle slots), [12] the row copies of
-[1] that read or write state: SSM and conv state tiles, K/V cache rows.
+[6] event-wait violations, [7] event signals, [12] the row copies of
+[1] that read or write state: SSM and conv state tiles, K/V cache rows
+(words 8-11 are reserved and stay zero).
 ``MegakernelExecutor.pipeline_counters()`` / ``worker_counters()`` read
 it back.
-
-Dynamic scheduler (``statics["DYN"] == 1``; protocol in
-``runtime/dyn_sched.py``): each grid slot runs **pop → wait-check →
-compute → signal-and-enqueue** instead of walking a static stream.  The
-pop scans this worker's 128-word ready pool (one bulk row DMA + a VPU
-argmin — the minimum descriptor-row id wins, making the pop priority
-"earliest linearized position" with the task-id tie-break inherent),
-falling back to the shared overflow queue and then to *stealing* from
-the other workers' pools.  The popped row indexes the schedule-order-
-free descriptor table dynamically (scalar-prefetched SMEM).  After the
-task's stores land, the signal RMW increments its event counter; the
-producer that brings it to the trigger count walks the event's consumer
-list (the second scalar-prefetch operand) and pushes each newly-ready
-row into its affinity worker's pool (first empty slot, overflow when
-full).  Every slot also records what it popped into the in-heap pop
-trace, which the tests assert equal to ``dyn_sched.replay_sequential``
-— the sequential interpret-mode execution *is* the protocol replay, so
-dynamic outputs stay bitwise-identical to the static scheduler and the
-interpreter.  No cross-slot prefetch is planned (the next task is a
-runtime decision): every task demand-loads its primary tile through its
-own record, words 28-30.
 
 Compiled for a TPU (``interpret=False``, Mosaic): the heap is one
 ``(1, N)`` f32 row, tiled ``(1, 128)`` in HBM.  Every tile moves as
@@ -90,9 +68,8 @@ Scalar words (lengths, token ids, positions, counters, event and trace
 words) never pass through vector registers: they move through a 256-word
 SMEM block staged from the word's tile.  The grid runs in order on the
 chip's one TensorCore, exactly as in interpret mode.  The compiled kernel
-covers the static scheduler and the dense decoder's and the SSM's kinds
-(``COMPILED_KINDS``); MoE and COMM kinds and the dynamic scheduler run
-in interpret mode only.
+covers the dense decoder's and the SSM's kinds (``COMPILED_KINDS``); MoE
+and COMM kinds run in interpret mode only.
 
 Validated in interpret mode against the numpy tGraph interpreter and the
 JAX model oracle (tests/test_megakernel.py, tests/test_program_api.py,
@@ -112,15 +89,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...models.layers import softplus
-from ...runtime.dyn_sched import QUEUE_EMPTY
 from .desc import (LANE, STATS_WORDS, TRACE_HEADER, TRACE_WORDS,
                    heap_words)
 
 __all__ = ["make_megakernel", "make_count", "COMM_BLOCK", "COMPILED_KINDS"]
-
-#: occupied/empty discriminator for ready-pool slots (row ids sit far
-#: below, the QUEUE_EMPTY sentinel far above)
-_QTH = QUEUE_EMPTY / 2
 
 #: word width of one COMM span-copy block: the chunked collectives
 #: (kinds 14/15) move flat heap spans in masked 256-word blocks through
@@ -179,8 +151,8 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
     f32 heap.
 
     ``interpret=True`` runs it through the Pallas interpreter (CPU);
-    ``False`` compiles it with Mosaic for a TPU, which takes a static
-    scheduler plan whose task kinds are all in ``COMPILED_KINDS``.
+    ``False`` compiles it with Mosaic for a TPU, which takes a plan whose
+    task kinds are all in ``COMPILED_KINDS``.
     ``kinds`` lists the task-kind codes the descriptor table holds;
     dispatch branches for other kinds are left out of the kernel (empty
     means every kind).
@@ -188,14 +160,10 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
     global _MAKE_COUNT
     _MAKE_COUNT += 1
     kinds = frozenset(int(k) for k in kinds) or frozenset(range(16))
-    if not interpret:
-        if statics.get("DYN", 0):
-            raise NotImplementedError(
-                "the compiled TPU megakernel runs the static scheduler only")
-        if not kinds <= COMPILED_KINDS:
-            raise NotImplementedError(
-                "task kinds %s have no compiled TPU implementation yet"
-                % sorted(kinds - COMPILED_KINDS))
+    if not interpret and not kinds <= COMPILED_KINDS:
+        raise NotImplementedError(
+            "task kinds %s have no compiled TPU implementation yet"
+            % sorted(kinds - COMPILED_KINDS))
     W = max(1, statics.get("W", 1))
     EVENT_OFF = statics.get("EVENT_OFF", 0)
     TN = statics["TN"]
@@ -220,14 +188,6 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
     SCH_W = max(1, statics.get("STORE_CH", 128))   # masked-store chunk
     SB_ROWS = max(TKC, TS, HDS, WC, 8)
     TNK = max(TN, TKC)
-    DYN = bool(statics.get("DYN", 0))
-    QOFF = statics.get("QOFF", 0)
-    QCAP = statics.get("QCAP", 128)
-    OV_ROWS = max(1, statics.get("OV_ROWS", 1))
-    OVOFF = QOFF + W * QCAP
-    QC_OFF = statics.get("QC_OFF", 0)
-    TRACE_OFF = statics.get("TRACE_OFF", 0)
-    MAX_OUT = statics.get("MAX_OUT", 0)
     #: real multi-chip transport: when set, REMOTE_COPY descriptors
     #: issue ``pltpu.make_async_remote_copy`` against the PEER chip's
     #: heap (one program instance per chip under shard_map) instead of
@@ -242,12 +202,8 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
     def kernel(desc, *rest):
         rest = list(rest)
         sT = rest.pop() if TRACE else None   # trace-record staging
-        if DYN:
-            (sched, heap_in, heap, sA, sB, sC, sD, acc, acc2, sO, sY, sP,
-             sBlk, cnt, sR, sem, psem, rsend, rrecv, sQ, sS) = rest
-        else:
-            (heap_in, heap, sA, sB, sC, sD, acc, acc2, sO, sY, sP,
-             sBlk, cnt, sR, sem, psem, rsend, rrecv) = rest
+        (heap_in, heap, sA, sB, sC, sD, acc, acc2, sO, sY, sP,
+         sBlk, cnt, sR, sem, psem, rsend, rrecv) = rest
         s = pl.program_id(0)                # grid step (shared time axis)
         w_id = pl.program_id(1)             # worker lane
         slot = jax.lax.rem(s, 2)            # A side: this step's operands
@@ -329,18 +285,12 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         def peek(addr):
             return sBlk[0, blk_load(addr)]
 
-        def poke(addr, value):
-            sBlk[0, blk_load(addr)] = value
-            blk_store(addr)
-
         def poke_add(addr, delta):
-            """heap[addr] += delta; returns the new value.  The grid runs
-            in order on one core, so the read-modify-write is exact."""
+            """heap[addr] += delta.  The grid runs in order on one core,
+            so the read-modify-write is exact."""
             o = blk_load(addr)
-            new = sBlk[0, o] + delta
-            sBlk[0, o] = new
+            sBlk[0, o] = sBlk[0, o] + delta
             blk_store(addr)
-            return new
 
         # ---------------- DMA helpers (all through the aliased out ref) ---
         def load_tile(dst, base, ld, nrows, max_rows, width, state=False):
@@ -472,97 +422,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 sP.at[w_id, slot, r, :, pl.ds(c0, cw)], hrow(base + c0, cw)),
                 TN, valid)
 
-        # --------------- queue-word helpers (dynamic scheduler only) ----
-        # Raw single-row / single-word heap traffic for the ready pools,
-        # cursor counters and pop trace.  Deliberately NOT routed through
-        # ``_count``: the DMA counters keep measuring operand traffic;
-        # scheduler traffic is visible through words 8-11 instead.
-        def _qrow(r, base, width=QCAP):
-            """sQ[r, :width] = heap[base : base + width] (one pool row)."""
-            _copy(heap.at[:, pl.ds(base, width)], vrow(sQ, r, width))
-
-        # --------------- task selection: static grid row or dynamic pop
-        if DYN:
-            # Pop per the protocol order (runtime/dyn_sched.py): own
-            # pool, overflow queue, then steal.  Each pool scan is one
-            # bulk row DMA + a vector argmin; the minimum row id (=
-            # earliest linearized position) wins.
-            sS[0] = jnp.int32(-1)       # source: -1 none, 0 own, 1 ovf,
-            sS[1] = jnp.int32(0)        #   2 steal; [1] consumed slot
-            sS[4] = jnp.int32(0)        #   offset; [4] popped row id
-            _qrow(0, QOFF + w_id * QCAP)
-            vals = sQ[0, 0, :]
-            mn = jnp.min(vals)
-
-            @pl.when(mn < _QTH)
-            def _():
-                sS[0] = jnp.int32(0)
-                sS[1] = (w_id * QCAP
-                         + jnp.argmin(vals)).astype(jnp.int32)
-                sS[4] = mn.astype(jnp.int32)
-
-            @pl.when(sS[0] < 0)
-            def _():                    # overflow queue scan
-                for r in range(OV_ROWS):
-                    _qrow(r, OVOFF + r * QCAP)
-                tile = sQ[:OV_ROWS, 0, :]
-                mo = jnp.min(tile)
-
-                @pl.when(mo < _QTH)
-                def _():
-                    sS[0] = jnp.int32(1)
-                    sS[1] = (W * QCAP + jnp.argmin(
-                        tile.reshape(-1))).astype(jnp.int32)
-                    sS[4] = mo.astype(jnp.int32)
-
-            for k in range(1, W):       # steal scan, victims (w+k) % W
-                @pl.when(sS[0] < 0)
-                def _(k=k):
-                    vw = jax.lax.rem(w_id + k, W)
-                    _qrow(0, QOFF + vw * QCAP)
-                    sv = sQ[0, 0, :]
-                    ms = jnp.min(sv)
-
-                    @pl.when(ms < _QTH)
-                    def _():
-                        sS[0] = jnp.int32(2)
-                        sS[1] = (vw * QCAP
-                                 + jnp.argmin(sv)).astype(jnp.int32)
-                        sS[4] = ms.astype(jnp.int32)
-
-            popped = sS[0] >= 0
-            t = sS[4]                   # the popped descriptor row
-
-            @pl.when(popped)
-            def _():                    # consume: mark slot empty
-                poke(QOFF + sS[1], jnp.float32(QUEUE_EMPTY))
-                pool = jnp.minimum(sS[1] // QCAP, W)
-                poke_add(QC_OFF + 2 * pool + 1, 1.0)   # popped cursor
-
-                @pl.when(sS[0] == 0)
-                def _():
-                    cadd(8, 1.0)
-
-                @pl.when(sS[0] == 1)
-                def _():
-                    cadd(9, 1.0)
-
-                @pl.when(sS[0] == 2)
-                def _():
-                    cadd(10, 1.0)
-
-            @pl.when(jnp.logical_not(popped))
-            def _():                    # only the trailing pad slots
-                cadd(11, 1.0)
-
-            # pop trace: what this grid slot executed (QUEUE_EMPTY when
-            # idle) — asserted equal to dyn_sched.replay_sequential
-            poke(TRACE_OFF + s * W + w_id,
-                 jnp.where(popped, sS[4].astype(jnp.float32),
-                           jnp.float32(QUEUE_EMPTY)))
-        else:
-            popped = None
-            t = s * W + w_id            # row in the static grid
+        t = s * W + w_id                # row in the static grid
         d = lambda i: desc[t, i]
 
         # ------------------- trace ring (CompileOptions.trace) ---------
@@ -579,9 +439,6 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             _tick(3)                    # record word 3: start tick
 
         # ------------------------------------------------ prefetch phase
-        # (static scheduler only: the dynamic scheduler cannot know a
-        # slot's next task at compile time, so every dynamic task
-        # demand-loads through its own record, words 28-30.)
         # Issue the NEXT task in this worker's stream into the B side of
         # this worker's double buffer.  The compiler emitted (off, ld,
         # rows) at words 24-26 only when the tile does not overlap
@@ -593,35 +450,27 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
                 sP.at[w_id, side, i, :, pl.ds(0, TN)],
                 psem.at[w_id, side])
 
-        if not DYN:
-            pf_rows = d(26)
+        pf_rows = d(26)
 
-            @pl.when(pf_rows > 0)
+        @pl.when(pf_rows > 0)
+        def _():
+            _count(pf_rows)
+            cadd(2, 1.0)
+
+        def pf_body(i, _):
+            @pl.when(i < pf_rows)
             def _():
-                _count(pf_rows)
-                cadd(2, 1.0)
-
-            def pf_body(i, _):
-                @pl.when(i < pf_rows)
-                def _():
-                    _primary_copy(d(24), d(25), i, nslot).start()
-                return 0
-            jax.lax.fori_loop(0, TM, pf_body, 0)
-
-        def _gate(pred):
-            """Conjoin ``pred`` with "this slot popped a task" under the
-            dynamic scheduler (idle pad slots must not execute)."""
-            return pred if not DYN else jnp.logical_and(popped, pred)
+                _primary_copy(d(24), d(25), i, nslot).start()
+            return 0
+        jax.lax.fori_loop(0, TM, pf_body, 0)
 
         # ------------------------------------------- event wait (word 32)
         # Cross-worker producers synchronize through the in-heap event
         # table.  The in-order grid already satisfies every dependency
-        # (static: proved by the compiler; dynamic: a task is only ever
-        # popped after its event fully triggered), so the hardware
-        # spin-wait degrades to a checked assertion: the counter must
-        # ALREADY equal the trigger count; anything else is a
-        # compiler/scheduler bug, counted as a violation.
-        @pl.when(_gate(d(32) >= 0))
+        # (proved by the compiler), so the hardware spin-wait degrades to
+        # a checked assertion: the counter must ALREADY equal the trigger
+        # count; anything else is a compiler bug, counted as a violation.
+        @pl.when(d(32) >= 0)
         def _():
             seen = peek(EVENT_OFF + d(32))
             cadd(5, 1.0)
@@ -1090,70 +939,23 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
 
         present = sorted(kinds)
 
-        def _dispatch():
-            # switch over the kinds this plan holds only: Mosaic lowers a
-            # switch as nested ifs, and its compiler crashes on a task
-            # branch nested 12 or more levels deep
-            idx = jnp.int32(0)
-            for i, code in enumerate(present):
-                idx = jnp.where(d(0) == code, jnp.int32(i), idx)
-            jax.lax.switch(idx, [branches[code] for code in present])
+        # switch over the kinds this plan holds only: Mosaic lowers a
+        # switch as nested ifs, and its compiler crashes on a task branch
+        # nested 12 or more levels deep
+        idx = jnp.int32(0)
+        for i, code in enumerate(present):
+            idx = jnp.where(d(0) == code, jnp.int32(i), idx)
+        jax.lax.switch(idx, [branches[code] for code in present])
 
-        if DYN:
-            @pl.when(popped)
-            def _():
-                _dispatch()
-        else:
-            _dispatch()
-
-        # --------------------------------- enqueue one newly-ready task
-        def _push(crow):
-            """Push descriptor row ``crow`` into its affinity worker's
-            pool (word 35; first empty slot), spilling to the shared
-            overflow queue when the pool is full."""
-            aw = desc[crow, 35]
-            _qrow(0, QOFF + aw * QCAP)
-            free = sQ[0, 0, :] >= _QTH
-            row_id = crow.astype(jnp.float32)
-
-            @pl.when(jnp.any(free))
-            def _():
-                fidx = jnp.argmax(free).astype(jnp.int32)
-                poke(QOFF + aw * QCAP + fidx, row_id)
-                poke_add(QC_OFF + 2 * aw, 1.0)       # pushed cursor
-
-            @pl.when(jnp.logical_not(jnp.any(free)))
-            def _():                     # affinity pool full: overflow
-                for r in range(OV_ROWS):
-                    _qrow(r, OVOFF + r * QCAP)
-                ofree = (sQ[:OV_ROWS, 0, :] >= _QTH).reshape(-1)
-                oidx = jnp.argmax(ofree).astype(jnp.int32)
-                poke(OVOFF + oidx, row_id)
-                poke_add(QC_OFF + 2 * W, 1.0)
-
-        # ------------------------- event signal-and-enqueue (word 34)
+        # ---------------------------------- event signal (word 34)
         # After this task's stores have landed, increment its triggering
         # event's in-heap counter (a read-modify-write through SMEM; on
         # real parallel hardware this is the atomic the event table
-        # provides — the in-order grid makes it exact).  Under the
-        # dynamic scheduler, the producer whose increment brings the
-        # counter to the trigger count walks the event's consumer list
-        # and enqueues every newly-ready task.
-        @pl.when(_gate(d(34) >= 0))
+        # provides — the in-order grid makes it exact).
+        @pl.when(d(34) >= 0)
         def _():
-            count = poke_add(EVENT_OFF + d(34), 1.0)
+            poke_add(EVENT_OFF + d(34), 1.0)
             cadd(7, 1.0)
-            if DYN and MAX_OUT > 0:
-                trig = sched[d(34), 0].astype(jnp.float32)
-
-                @pl.when(count == trig)
-                def _():                 # event fully triggered
-                    def push_body(j, _):
-                        @pl.when(j < sched[d(34), 1])
-                        def _():
-                            _push(sched[d(34), 2 + j])
-                        return 0
-                    jax.lax.fori_loop(0, MAX_OUT, push_body, 0)
 
         def blk_write(addr, words):
             """heap[addr + j] = words[j] through the SMEM block."""
@@ -1163,21 +965,16 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
             blk_store(addr)
 
         # ------------------- trace ring: assemble + store the record ---
-        # Every grid slot writes its full TRACE_WORDS record (static
-        # noops record kind 0; dynamic idle slots record row/kind -1) so
-        # no stale data from a previous launch survives in the ring.
+        # Every grid slot writes its full TRACE_WORDS record (noops
+        # record kind 0) so no stale data from a previous launch survives
+        # in the ring.
         if TRACE:
             _tick(4)                    # record word 4: end tick
             sT[0] = w_id.astype(jnp.float32)
-            if DYN:
-                sT[1] = jnp.where(popped, t.astype(jnp.float32), -1.0)
-                sT[2] = jnp.where(popped, d(0).astype(jnp.float32), -1.0)
-                sT[5] = sS[0].astype(jnp.float32)
-            else:
-                sT[1] = t.astype(jnp.float32)
-                sT[2] = d(0).astype(jnp.float32)
-                sT[5] = jnp.float32(-1.0)
-            sT[6] = jnp.where(_gate(d(32) >= 0),
+            sT[1] = t.astype(jnp.float32)
+            sT[2] = d(0).astype(jnp.float32)
+            sT[5] = jnp.float32(-1.0)       # reserved
+            sT[6] = jnp.where(d(32) >= 0,
                               d(33).astype(jnp.float32), 0.0)
             sT[7] = jnp.float32(0.0)
             blk_write(TR_OFF + TRACE_HEADER
@@ -1213,17 +1010,12 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         pltpu.SemaphoreType.DMA,                      # rsend (remote DMA)
         pltpu.SemaphoreType.DMA,                      # rrecv (remote DMA)
     ]
-    if DYN:
-        scratch_shapes += [
-            pltpu.VMEM((OV_ROWS, 1, QCAP), jnp.float32),  # sQ (pool scans)
-            pltpu.SMEM((8,), jnp.int32),                  # sS (pop state)
-        ]
     if TRACE:
         scratch_shapes += [
             pltpu.SMEM((TRACE_WORDS,), jnp.float32),      # sT (trace rec)
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 if DYN else 1,
+        num_scalar_prefetch=1,
         grid=(num_steps, W),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -1233,7 +1025,7 @@ def make_megakernel(statics: Dict[str, Any], num_steps: int,
         pl.pallas_call,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, HEAP), jnp.float32),
-        input_output_aliases={2 if DYN else 1: 0},
+        input_output_aliases={1: 0},
         interpret=interpret,
         name="mpk_megakernel",
     )(kernel)

@@ -51,7 +51,8 @@ __all__ = ["compile_decode_megakernel", "MegakernelExecutor",
 #: named field map of the per-worker STATS block: counter name → word
 #: index.  Words 4 and 13 (``SPILL_WORDS``) are the 2^20-unit spills of
 #: ``row_copies`` and ``state_row_copies`` — folded back into those
-#: fields by ``decode_stats_row`` instead of surfacing as counters.
+#: fields by ``decode_stats_row`` instead of surfacing as counters;
+#: words 8-11 are reserved.
 STATS_FIELDS = {
     "bulk_copies": 0,
     "row_copies": 1,
@@ -60,10 +61,6 @@ STATS_FIELDS = {
     "event_waits": 5,
     "event_wait_violations": 6,
     "event_signals": 7,
-    "pops_own": 8,
-    "pops_overflow": 9,
-    "steals": 10,
-    "idle_slots": 11,
     "state_row_copies": 12,
 }
 
@@ -158,7 +155,6 @@ def compile_decode_megakernel(cfg, batch: int, max_seq: int,
                               event_fusion: bool = True,
                               pipeline_depth: int = 2,
                               num_workers: int = 1,
-                              scheduler: str = "static",
                               tp: int = 1,
                               trace: bool = False
                               ) -> MegakernelPlan:
@@ -168,27 +164,18 @@ def compile_decode_megakernel(cfg, batch: int, max_seq: int,
     small, so row tiles stay register-friendly.  The kernel's tile width
     TN is the widest tile of the default partition; every matmul is then
     cut into TM × ≤TN tiles (``DecomposeConfig.matmul_cols``), the same
-    for any ``num_workers``, scheduler or ``tp``.  ``pipeline_depth`` is
+    for any ``num_workers`` or ``tp``.  ``pipeline_depth`` is
     the separation the scheduler enforces between producer→consumer pairs
     (2 = the kernel's double buffer).  ``num_workers`` partitions the
     schedule into W decentralized per-worker descriptor streams
     synchronized through in-heap event counters (paper §5).
-    ``scheduler="dynamic"`` replaces the static streams with the
-    heap-resident ready queues of ``runtime/dyn_sched.py`` — pop →
-    wait → compute → signal-and-enqueue per grid slot.
     ``tp > 1`` inserts AllReduce ops into the graph (paper §6.5) and
     stamps the lowered plan into per-chip task tables whose collectives
-    run as in-kernel COMM tasks (static scheduler only for now — the
-    dynamic scheduler's ready queues are per-chip-heap state that the
-    stamper does not replicate yet).
+    run as in-kernel COMM tasks.
     ``trace=True`` appends the per-task trace ring to the heap and makes
     the kernel timestamp every executed slot (``obs`` decodes it); off,
     the layout and outputs are bitwise identical to the untraced build.
     """
-    if tp > 1 and scheduler != "static":
-        raise NotImplementedError(
-            "tp > 1 megakernels require scheduler='static' (the dynamic "
-            "ready queues are not chip-stamped yet)")
     g = build_decode_graph(cfg, batch, max_seq, tp=tp)
     dec = DecomposeConfig(max_rows=max_rows)
     tn = _align(widest_tile(g, dec))
@@ -198,12 +185,10 @@ def compile_decode_megakernel(cfg, batch: int, max_seq: int,
         event_fusion=event_fusion,
         pipeline_depth=pipeline_depth,
         num_workers=num_workers,
-        scheduler=scheduler,
         trace=trace,
     )
     compiled = megakernelize(g, opts)
-    plan = lower_tgraph(compiled, cfg, tn=tn, scheduler=scheduler,
-                        trace=trace)
+    plan = lower_tgraph(compiled, cfg, tn=tn, trace=trace)
     if tp > 1:
         plan = stamp_multichip(plan, tp)
     return plan
@@ -258,15 +243,6 @@ class MegakernelExecutor:
         self._n_events = plan.num_events
         if self._n_events:
             spans.append((plan.event_offset, self._n_events))
-        # dynamic scheduler: the ready pools, overflow queue and cursor
-        # counters are consumed during the launch — the same update
-        # re-writes the initial queue image before every step
-        self._dynamic = plan.scheduler == "dynamic"
-        if self._dynamic:
-            pools, counters = plan.dyn.queue_image()
-            self._queue_reset = np.concatenate([pools, counters])
-            spans.append((plan.queue_offset, self._queue_reset.size))
-            self._sched = jnp.asarray(plan.dyn.sched_table())
         # trace ring: only the logical tick counter at the ring head
         # needs re-zeroing — the kernel rewrites every record slot each
         # launch (idle/noop slots included)
@@ -349,10 +325,7 @@ class MegakernelExecutor:
                 heap = jax.lax.dynamic_update_slice(
                     heap, vals[None, pos : pos + n], (0, off))
                 pos += n
-            if self._dynamic:
-                heap = kern(self._descs, self._sched, heap)
-            else:
-                heap = kern(self._descs, heap)
+            heap = kern(self._descs, heap)
             logits = heap[:, lg.offset : lg.offset + lg.rows * lg.ld]
             logits = logits.reshape(lg.rows, lg.ld)[:, :lg_cols]
             return heap, logits, heap[0, stats]
@@ -404,8 +377,6 @@ class MegakernelExecutor:
         flat = flat * self._n_chips
         if self._n_events:
             flat.append(np.zeros((self._n_events,), np.float32))
-        if self._dynamic:
-            flat.append(self._queue_reset)
         if self.plan.trace:
             flat.append(np.zeros((1,), np.float32))   # tick counter
         return jnp.asarray(np.concatenate(flat))
@@ -487,41 +458,6 @@ class MegakernelExecutor:
         event-counter traffic of the W-worker runtime."""
         per_worker = self.worker_counters()
         return {k: sum(d[k] for d in per_worker) for k in STATS_FIELDS}
-
-    def scheduler_counters(self) -> Dict[str, Any]:
-        """Dynamic-scheduler queue accounting for the LAST step, read
-        from the in-heap cursor counters and pop counters: per-pool
-        [pushed, popped] cursors (W workers + the shared overflow queue)
-        and the pop-source split.  Every pool must drain
-        (pushed == popped) once the launch completes."""
-        assert self._dynamic, "static scheduler has no queue counters"
-        assert self._heap is not None, "upload() before counters"
-        W = self.plan.num_workers
-        off = self.plan.qc_offset
-        qc = np.asarray(self._heap[:, off : off + 2 * (W + 1)])[0]
-        per = self.pipeline_counters()
-        return {
-            "queue_pushed": [int(qc[2 * i]) for i in range(W + 1)],
-            "queue_popped": [int(qc[2 * i + 1]) for i in range(W + 1)],
-            "pops_own": per["pops_own"],
-            "pops_overflow": per["pops_overflow"],
-            "steals": per["steals"],
-            "idle_slots": per["idle_slots"],
-        }
-
-    def pop_trace(self) -> np.ndarray:
-        """The dynamic scheduler's in-heap pop trace for the LAST step:
-        the descriptor row each grid slot executed, -1 for idle pad
-        slots.  Asserted equal to ``dyn_sched.replay_sequential`` by the
-        tests (the sequential interpret-mode execution IS the protocol
-        replay)."""
-        from ...runtime.dyn_sched import QUEUE_EMPTY
-        assert self._dynamic, "static scheduler has no pop trace"
-        assert self._heap is not None, "upload() before pop_trace()"
-        off = self.plan.trace_offset
-        n = self.plan.num_steps * self.plan.num_workers
-        tr = np.asarray(self._heap[:, off : off + n])[0]
-        return np.where(tr >= QUEUE_EMPTY / 2, -1, tr).astype(np.int64)
 
     def task_ring(self) -> np.ndarray:
         """Raw trace-ring records for the LAST step: an

@@ -28,15 +28,12 @@ import numpy as np
 
 
 def program_stats(arch: str, shape, num_workers: int = 4,
-                  scheduler: str = "static",
                   trace_prefix: Path | None = None) -> dict:
     """Compiler-side Program stats for a cell (``repro.api`` interpreter
     backend — no execution): task/event counts, the liveness-packed
     workspace footprint, and the W-worker runtime contract
     (``Program.worker_stats``: partition, event-counter cut, replayed
-    makespan; with ``scheduler="dynamic"`` also the ready-queue depth /
-    pop-source profile of the protocol replay) at a
-    serving-representative (batch, seq)."""
+    makespan) at a serving-representative (batch, seq)."""
     from repro.api import compile as mpk_compile
     from repro.configs import get_config
 
@@ -44,7 +41,7 @@ def program_stats(arch: str, shape, num_workers: int = 4,
     batch = min(8, shape.global_batch)
     max_seq = min(1024, shape.seq_len)
     prog = mpk_compile(cfg, batch, max_seq, backend="interpreter",
-                       num_workers=num_workers, scheduler=scheduler)
+                       num_workers=num_workers)
     rec = prog.describe()
     s = prog.stats
     rec["workspace_reuse_x"] = round(s["workspace_reuse_x"], 2)
@@ -54,7 +51,6 @@ def program_stats(arch: str, shape, num_workers: int = 4,
     rec["pipeline_stalls_naive"] = ps["stalls_naive"]
     ws = prog.worker_stats
     rec["workers"] = {
-        "scheduler": ws["scheduler"],
         "num_workers": ws["num_workers"],
         "queue_lens": ws["queue_lens"],
         "cross_worker_deps": ws["cross_worker_deps"],
@@ -63,14 +59,6 @@ def program_stats(arch: str, shape, num_workers: int = 4,
         "worker_utilization": [round(u, 4)
                                for u in ws["worker_utilization"]],
     }
-    if scheduler == "dynamic":
-        rec["workers"].update({
-            "dyn_sim_makespan_us": round(ws["dyn_sim_makespan_us"], 2),
-            "queue_max_depth": ws["queue_max_depth"],
-            "pops_own": ws["replay_pops_own"],
-            "pops_overflow": ws["replay_pops_overflow"],
-            "steals": ws["replay_steals"],
-        })
     if trace_prefix is not None:
         # compile-only cell: the dumpable timeline is the PREDICTED one
         from repro.obs import write_chrome_trace
@@ -113,7 +101,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         rec["program"] = program_stats(
             arch, shape,
             num_workers=(overrides or {}).get("program_workers", 4),
-            scheduler=(overrides or {}).get("program_scheduler", "static"),
             trace_prefix=(out_dir / f"{arch}_{shape_name}_{mesh_tag}"
                           if with_program_trace else None))
 
@@ -259,14 +246,10 @@ def main() -> int:
     ap.add_argument("--program-stats", action="store_true",
                     help="record repro.api Program compiler stats (tasks/"
                          "events/workspace + worker partition / event "
-                         "counters / ready-queue depths) in each cell json")
+                         "counters) in each cell json")
     ap.add_argument("--program-workers", type=int, default=4,
                     help="worker width the --program-stats compile "
                          "partitions onto")
-    ap.add_argument("--program-scheduler", choices=["static", "dynamic"],
-                    default="static",
-                    help="runtime scheduler for --program-stats (dynamic "
-                         "adds ready-queue depth / pop-source stats)")
     ap.add_argument("--program-trace", action="store_true",
                     help="with --program-stats: also write the predicted "
                          "task timeline (<cell>.trace.json, Chrome-trace "
@@ -288,8 +271,6 @@ def main() -> int:
         overrides["moe_2d"] = True
     if args.program_workers != 4:
         overrides["program_workers"] = args.program_workers
-    if args.program_scheduler != "static":
-        overrides["program_scheduler"] = args.program_scheduler
 
     from repro.configs import SHAPES, list_archs
 

@@ -99,10 +99,6 @@ def main() -> None:
     ap.add_argument("--backend", choices=["jax", "interpreter",
                                           "megakernel"], default="jax",
                     help="Program execution backend for decode steps")
-    ap.add_argument("--scheduler", choices=["static", "dynamic"],
-                    default="static",
-                    help="in-kernel task dispatch: static per-worker "
-                         "streams or heap-resident ready queues")
     ap.add_argument("--workers", type=int, default=1,
                     help="decentralized worker lanes the compiled "
                          "schedule is partitioned onto")
@@ -146,7 +142,6 @@ def main() -> None:
     program = mpk_compile(cfg, args.slots, args.max_seq,
                           backend=args.backend,
                           num_workers=args.workers,
-                          scheduler=args.scheduler,
                           step_cache=step_cache,
                           trace=args.trace_json is not None).bind(params)
     if args.warmup:
@@ -190,19 +185,9 @@ def main() -> None:
         if prog.step_count > 0:
             ws = prog.worker_stats
             print(f"[serve] workers: W={ws['num_workers']} "
-                  f"scheduler={ws['scheduler']} "
                   f"event_waits={ws.get('event_waits', 0)} "
                   f"violations={ws.get('event_wait_violations', 0)} "
                   f"signals={ws.get('event_signals', 0)}")
-            if args.scheduler == "dynamic":
-                print(f"[serve] ready queues: "
-                      f"pops_own={ws['kernel_pops_own']} "
-                      f"overflow={ws['kernel_pops_overflow']} "
-                      f"steals={ws['kernel_steals']} "
-                      f"idle={ws['kernel_idle_slots']} "
-                      f"max_depth={ws['queue_max_depth']} "
-                      f"pushed={ws['kernel_queue_pushed']} "
-                      f"popped={ws['kernel_queue_popped']}")
     summary = engine.metrics_summary()
     for key in ("ttft", "queue", "tpot"):
         if f"{key}_mean_s" in summary:

@@ -7,7 +7,7 @@ and prints the predicted-vs-observed reconciliation report — the
 measurement loop the compiler's cost oracle is validated with::
 
     mpk-trace --workers 4 --out trace.json --snapshot snapshot.json
-    mpk-trace --scheduler dynamic --backend interpreter
+    mpk-trace --backend interpreter
 """
 from __future__ import annotations
 
@@ -27,8 +27,6 @@ def main() -> None:
                     help="megakernel = the kernel-written trace ring; "
                          "interpreter = the sequential-execution timeline")
     ap.add_argument("--workers", type=int, default=2)
-    ap.add_argument("--scheduler", choices=["static", "dynamic"],
-                    default="static")
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None,
@@ -58,8 +56,7 @@ def main() -> None:
 
     prog = mpk_compile(cfg, args.batch, args.max_seq,
                        backend=args.backend, num_workers=args.workers,
-                       scheduler=args.scheduler, tp=args.tp,
-                       trace=True).bind(params).init_state()
+                       tp=args.tp, trace=True).bind(params).init_state()
     lens = np.zeros((args.batch,), np.int32)
     for _ in range(max(1, args.steps)):
         toks = np.asarray(rng.integers(1, cfg.vocab, size=args.batch),
@@ -69,7 +66,7 @@ def main() -> None:
 
     observed = prog.trace()
     predicted = prog.predicted_trace()
-    print(f"[trace] {args.backend} backend, scheduler={args.scheduler} "
+    print(f"[trace] {args.backend} backend "
           f"W={args.workers}: {len(observed.events)} events over "
           f"{observed.makespan:.0f} ticks"
           if observed.meta.get("time_unit") == "tick" else

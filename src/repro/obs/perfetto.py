@@ -43,8 +43,6 @@ def chrome_trace(trace: TaskTrace) -> Dict[str, Any]:
     for e in trace.events:
         args: Dict[str, Any] = {"task": e.task, "row": e.row,
                                 "kind": e.kind}
-        if e.source >= 0:
-            args["pop_source"] = ("own", "overflow", "steal")[e.source]
         if e.wait_ev >= 0:
             args["wait_ev"] = e.wait_ev
             args["wait_cnt"] = e.wait_cnt
@@ -85,7 +83,6 @@ def chrome_trace(trace: TaskTrace) -> Dict[str, Any]:
         "displayTimeUnit": "ms",
         "otherData": {
             "origin": trace.origin,
-            "scheduler": trace.scheduler,
             "num_workers": trace.num_workers,
             "n_chips": trace.n_chips,
             **{k: v for k, v in trace.meta.items()},

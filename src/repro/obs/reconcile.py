@@ -9,11 +9,10 @@ ticks — absolute times are incomparable, so the report measures *shape*:
   of each side, difference normalized by (n − 1): 0 means the kernel
   executed tasks in exactly the predicted order,
 * **worker agreement** — fraction of matched tasks whose observed lane
-  equals the predicted placement (only meaningful for the static
-  scheduler, where placement is a compile-time decision).
+  equals the predicted placement (a compile-time decision).
 
-Bounded skew here is what makes ``replay_partition`` /
-``simulate_dynamic`` a trustworthy cost oracle for the autotuner.
+Bounded skew here is what makes ``replay_partition`` a trustworthy cost
+oracle for the autotuner.
 """
 from __future__ import annotations
 

@@ -47,7 +47,6 @@ class TaskEvent:
     name: str          # human name ("matmul", "attention_decode", ...)
     start: float       # ticks (observed) or seconds (predicted)
     end: float
-    source: int = -1   # pop source: -1 static, 0 own, 1 overflow, 2 steal
     wait_cnt: int = 0  # event-wait trigger count (0 = no wait word)
     chip: int = 0      # chip of a stamped multichip plan
     wait_ev: int = -1  # descriptor wait-event id (word 32)
@@ -59,7 +58,6 @@ class TaskTrace:
     """A full timeline: events plus enough context to export/reconcile."""
 
     origin: str                    # "kernel" | "interpreter" | "predicted"
-    scheduler: str                 # "static" | "dynamic"
     num_workers: int
     events: List[TaskEvent]
     n_chips: int = 1
@@ -77,35 +75,28 @@ def _live_slots(ring: np.ndarray, descs) -> np.ndarray:
     """Rows of a raw task ring worth decoding: every slot that computed
     (kind > 0) or synchronized (a wait or signal word on its descriptor
     — dummy tasks carry the compiler's start/final events, and dropping
-    them would make the event-order check miss their signals).  Dynamic
-    idle pad slots (row -1) and silent static noop pads are skipped."""
+    them would make the event-order check miss their signals).  Silent
+    noop pads are skipped."""
     rows = ring[:, 1].astype(np.int64)
-    live = rows >= 0
-    idx = np.clip(rows, 0, len(descs) - 1)
-    synced = (descs[idx, 32] >= 0) | (descs[idx, 34] >= 0)
-    return live & ((ring[:, 2] > 0) | synced)
+    synced = (descs[rows, 32] >= 0) | (descs[rows, 34] >= 0)
+    return (ring[:, 2] > 0) | synced
 
 
 def decode_ring(plan, ring: np.ndarray) -> TaskTrace:
     """Decode a raw ``task_ring()`` array against its plan into the
     observed :class:`TaskTrace` (times are logical ticks).
 
-    The ring's row word is the *descriptor row*: under the dynamic
-    scheduler that is the linearized position (task id =
-    ``compiled.order[row]``); under the static scheduler the grid slot
-    maps back through the worker partition.  Stamped multichip grids
-    (COMM tasks, per-chip mirrors) carry no single task id — those
-    events keep ``task=-1`` and are matched by row instead."""
+    The ring's row word is the *descriptor row*, the grid slot, which
+    maps back to its task through the worker partition.  Stamped
+    multichip grids (COMM tasks, per-chip mirrors) carry no single task
+    id — those events keep ``task=-1`` and are matched by row instead."""
     assert ring.ndim == 2 and ring.shape[1] == TRACE_WORDS
     W = plan.num_workers
     n_chips = max(1, plan.n_chips)
     w_per_chip = max(1, W // n_chips)
 
     row_tid: Dict[int, int] = {}
-    if plan.scheduler == "dynamic":
-        order = plan.compiled.order
-        row_tid = {r: order[r] for r in range(len(order))}
-    elif n_chips == 1:
+    if n_chips == 1:
         part = plan.compiled.partition
         if part is not None:
             row_tid = {part.step_of[t] * W + part.worker_of[t]: t
@@ -126,75 +117,53 @@ def decode_ring(plan, ring: np.ndarray) -> TaskTrace:
             name=KIND_NAMES.get(kind, f"kind{kind}"),
             start=float(rec[3]),
             end=float(rec[4]),
-            source=int(rec[5]),
             wait_cnt=int(rec[6]),
             chip=worker // w_per_chip,
             wait_ev=int(d[32]),
             sig_ev=int(d[34]),
         ))
     return TaskTrace(
-        origin="kernel", scheduler=plan.scheduler, num_workers=W,
+        origin="kernel", num_workers=W,
         events=events, n_chips=n_chips,
         meta={"num_steps": plan.num_steps,
               "ring_slots": int(ring.shape[0]),
               "time_unit": "tick"})
 
 
-def sequential_trace(compiled, scheduler: str = "static",
-                     seq=None) -> TaskTrace:
+def sequential_trace(compiled) -> TaskTrace:
     """The interpreter backend's timeline, on the SAME two-ticks-per-
     task clock the kernel ring uses (task *i* of the sequential
-    execution spans [2i, 2i+1)).
-
-    Static: the interpreter walks ``compiled.order`` but each task still
-    *belongs* to its partition lane, so worker comes from the partition.
-    Dynamic: ``seq`` is the :class:`~repro.runtime.dyn_sched.SeqTrace`
-    of the protocol replay (pop order, lanes, sources)."""
+    execution spans [2i, 2i+1)).  The interpreter walks
+    ``compiled.order`` but each task still *belongs* to its partition
+    lane, so worker comes from the partition."""
     tg = compiled.tg
     part = compiled.partition
-
-    def _ev(i, tid, worker, source=-1):
+    worker_of = part.worker_of if part is not None else {}
+    events: List[TaskEvent] = []
+    for i, tid in enumerate(compiled.order):
         task = tg.tasks[tid]
         kind = KIND_CODES.get("noop" if task.is_dummy else task.kind, 0)
-        return TaskEvent(
-            task=tid, row=i, worker=worker, kind=kind,
+        events.append(TaskEvent(
+            task=tid, row=i, worker=int(worker_of.get(tid, 0)), kind=kind,
             name=KIND_NAMES.get(kind, f"kind{kind}"),
-            start=float(2 * i), end=float(2 * i + 1), source=source)
-
-    events: List[TaskEvent] = []
-    if scheduler == "dynamic" and seq is not None:
-        src_code = {"own": 0, "overflow": 1, "steal": 2}
-        order = compiled.order
-        for i, (row, w, src) in enumerate(zip(seq.order, seq.worker,
-                                              seq.source)):
-            events.append(_ev(i, order[row], w, src_code.get(src, -1)))
-        W = max(seq.worker, default=0) + 1 if seq.worker else 1
-    else:
-        worker_of = part.worker_of if part is not None else {}
-        for i, tid in enumerate(compiled.order):
-            events.append(_ev(i, tid, int(worker_of.get(tid, 0))))
-        W = part.num_workers if part is not None else 1
-    return TaskTrace(origin="interpreter", scheduler=scheduler,
-                     num_workers=W, events=events,
+            start=float(2 * i), end=float(2 * i + 1)))
+    W = part.num_workers if part is not None else 1
+    return TaskTrace(origin="interpreter", num_workers=W, events=events,
                      meta={"time_unit": "tick"})
 
 
-def predicted_task_trace(compiled, scheduler: str = "static",
-                         *, num_workers: Optional[int] = None,
+def predicted_task_trace(compiled, *, num_workers: Optional[int] = None,
                          pipeline_depth: int = 2,
                          tp: int = 1, **sim_kw) -> TaskTrace:
     """The compiler's *predicted* timeline as a :class:`TaskTrace`
-    (times in roofline seconds): ``replay_partition`` for the static
-    scheduler, ``simulate_dynamic`` for the dynamic one, through
-    :func:`~repro.core.runtime_sim.predicted_timeline` so the costs are
+    (times in roofline seconds): ``replay_partition`` through
+    :func:`~repro.core.runtime_sim.predicted_timeline`, so the costs are
     exactly the ones ``runtime_sim.simulate`` charges."""
     from ..core.runtime_sim import SimConfig, predicted_timeline
 
     part = compiled.partition
     W = num_workers or (part.requested_workers if part is not None else 1)
-    mode = "mpk_dyn" if scheduler == "dynamic" else (
-        "mpk_tp" if tp > 1 else "mpk")
-    cfg = SimConfig(mode=mode, n_workers=W,
+    cfg = SimConfig(mode="mpk_tp" if tp > 1 else "mpk", n_workers=W,
                     pipeline_depth=pipeline_depth, tp=tp, **sim_kw)
     tl = predicted_timeline(compiled, cfg)
 
@@ -210,8 +179,7 @@ def predicted_task_trace(compiled, scheduler: str = "static",
             name=KIND_NAMES.get(kind, f"kind{kind}"),
             start=float(t0), end=float(tl["end"][tid])))
     events.sort(key=lambda e: (e.start, e.worker, e.task))
-    return TaskTrace(origin="predicted", scheduler=scheduler,
-                     num_workers=W, events=events,
+    return TaskTrace(origin="predicted", num_workers=W, events=events,
                      meta={"mode": tl["mode"],
                            "makespan": float(tl["makespan"]),
                            "time_unit": "s"})

@@ -54,8 +54,7 @@ def comm_time(nbytes: float, *, ici_bw: float = TPU_V5E.ici_link_bw,
     """Duration of one inter-chip transfer: ``bytes / ici_bw + latency``.
 
     The single comm cost model shared by the worker partitioner
-    (``core/schedule.default_task_time``), the runtime simulator
-    (``core/runtime_sim``) and the dynamic-scheduler replay — previously
-    each carried its own copy of this formula.
+    (``core/schedule.default_task_time``) and the runtime simulator
+    (``core/runtime_sim``).
     """
     return nbytes / ici_bw + latency

@@ -1,8 +1,4 @@
 from .kv_cache import PagedKVCache
 from .engine import ServingEngine, Request, RequestMetrics
-from .dyn_sched import (DynSchedPlan, build_dyn_sched, replay_sequential,
-                        simulate_dynamic)
 
-__all__ = ["PagedKVCache", "ServingEngine", "Request", "RequestMetrics",
-           "DynSchedPlan", "build_dyn_sched", "replay_sequential",
-           "simulate_dynamic"]
+__all__ = ["PagedKVCache", "ServingEngine", "Request", "RequestMetrics"]

@@ -391,7 +391,7 @@ class ServingEngine:
     def metrics_snapshot(self) -> Dict[str, float]:
         """The unified end-of-run snapshot: the program's
         ``metrics_snapshot`` (compiler stats, pipeline contract, kernel
-        worker/scheduler counters) joined with this engine's serving
+        worker counters) joined with this engine's serving
         latency summary — one JSON-ready dict for scripting
         (``mpk-serve --metrics-json``)."""
         return self.program.metrics_snapshot(

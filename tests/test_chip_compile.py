@@ -5,7 +5,7 @@ Each test lowers and compiles the jitted decode step of
 ``MegakernelExecutor`` with ``interpret=False`` against one chip of a
 described ``v5e:2x2`` topology, at published widths cut to one layer,
 8 slots × 1024 positions: deepseek-7b as ``chip_smoke.py`` serves it
-(also with the trace ring on) and mamba2-2.7b; and the prefill's state
+and mamba2-2.7b, each also with the trace ring on; and the prefill's state
 gather and scatter at the cells' sizes.  Shapes only — the 7 GB heap is
 a ``ShapeDtypeStruct``.
 What Mosaic or XLA:TPU refuses (an unaligned DMA, too much VMEM, a heap
@@ -75,9 +75,10 @@ def _compile_step(one_chip, arch, *, trace=False):
 
 @pytest.mark.parametrize("arch,trace", [("deepseek-7b", False),
                                         ("deepseek-7b", True),
-                                        ("mamba2-2.7b", False)],
+                                        ("mamba2-2.7b", False),
+                                        ("mamba2-2.7b", True)],
                          ids=["deepseek-static", "deepseek-trace",
-                              "mamba2-static"])
+                              "mamba2-static", "mamba2-trace"])
 def test_step_compiles_for_v5e(one_chip, no_persistent_cache, arch, trace):
     plan, compiled = _compile_step(one_chip, arch, trace=trace)
     assert plan.heap_size > 0.8e9            # published widths
@@ -122,19 +123,14 @@ def test_state_gather_scatter_compile_in_bounded_memory(
 
 def test_compiled_kernel_refuses_unported_plans():
     """Plans the compiled kernel cannot run fail at build time, not on
-    the chip: the dynamic scheduler and task kinds without a compiled
-    implementation, or a layout with unaligned tile DMAs."""
+    the chip: task kinds without a compiled implementation, or a layout
+    with unaligned tile DMAs."""
     from repro.configs import get_config
     from repro.kernels.megakernel.kernel import make_megakernel
     from repro.kernels.megakernel.ops import (MegakernelExecutor,
                                               compile_decode_megakernel)
 
     cfg = get_config("deepseek-7b-reduced")
-    dyn = compile_decode_megakernel(cfg, 2, 16, num_workers=2,
-                                    scheduler="dynamic")
-    with pytest.raises(NotImplementedError, match="static scheduler"):
-        make_megakernel(dyn.statics, dyn.num_steps, dyn.heap_size,
-                        interpret=False)
     moe = compile_decode_megakernel(get_config("granite-moe-1b-a400m-reduced"),
                                     2, 16)
     with pytest.raises(NotImplementedError, match="task kinds"):
@@ -144,3 +140,52 @@ def test_compiled_kernel_refuses_unported_plans():
     assert plan.compiled_layout_errors()
     with pytest.raises(ValueError, match="compiled TPU kernel"):
         MegakernelExecutor(plan, cfg).step_fn(interpret=False)
+
+
+#: the task kinds of each assigned architecture's reduced plan that the
+#: compiled kernel does not implement yet (the MoE kinds: top-k router,
+#: gather GEMM, combine); the other seven run compiled
+_UNCOMPILED = {
+    "deepseek-7b": [], "gemma-7b": [], "mamba2-2.7b": [],
+    "mistral-nemo-12b": [], "musicgen-large": [], "qwen1.5-110b": [],
+    "qwen2-vl-2b": [],
+    "granite-moe-1b-a400m": [9, 10, 11],
+    "jamba-1.5-large-398b": [9, 10, 11],
+    "llama4-maverick-400b-a17b": [9, 10, 11],
+}
+
+
+def test_uncompiled_table_covers_archs():
+    from repro.configs import ARCHS
+    assert set(_UNCOMPILED) == set(ARCHS)
+
+
+@pytest.mark.parametrize("arch", sorted(_UNCOMPILED))
+def test_compiled_gate_per_arch(arch):
+    """Every assigned architecture's reduced plan meets the compiled
+    kernel's build-time gate as the gate says: ``make_megakernel`` takes
+    the plan's kinds or names exactly those outside ``COMPILED_KINDS``,
+    and ``step_fn(interpret=False)`` refuses a plan with layout errors.
+    Plan only, at 2 slots × 16: no compile, no chip."""
+    from repro.configs import get_config
+    from repro.kernels.megakernel.kernel import COMPILED_KINDS, make_megakernel
+    from repro.kernels.megakernel.ops import (MegakernelExecutor,
+                                              compile_decode_megakernel)
+
+    cfg = get_config(arch).reduced()
+    plan = compile_decode_megakernel(cfg, 2, 16)
+    kinds = {int(k) for k in plan.descs[:, 0]}
+    missing = sorted(kinds - COMPILED_KINDS)
+    assert missing == _UNCOMPILED[arch], missing
+    build = lambda: make_megakernel(plan.statics, plan.num_steps,  # noqa: E731
+                                    plan.heap_size, interpret=False,
+                                    kinds=kinds)
+    if missing:
+        with pytest.raises(NotImplementedError) as err:
+            build()
+        assert str(missing) in str(err.value)
+    else:
+        build()
+    if plan.compiled_layout_errors():
+        with pytest.raises(ValueError, match="compiled TPU kernel"):
+            MegakernelExecutor(plan, cfg).step_fn(interpret=False)

@@ -44,25 +44,22 @@ def quickstart():
     return cfg, params
 
 
-def _run(cfg, params, *, workers=2, scheduler="static", trace=True,
-         backend="megakernel"):
+def _run(cfg, params, *, workers=2, trace=True, backend="megakernel"):
     prog = mpk_compile(cfg, 2, 16, backend=backend, num_workers=workers,
-                       scheduler=scheduler,
                        trace=trace).bind(params).init_state()
-    logits = prog.step(TOKS, LENS)
+    if cfg.embed_input:
+        toks = np.asarray(jax.random.normal(KEY, (2, cfg.d_model)),
+                          np.float32)
+    else:
+        toks = TOKS
+    logits = prog.step(toks, LENS)
     return prog, np.asarray(logits)
 
 
 @pytest.fixture(scope="module")
 def mk_static(quickstart):
     cfg, params = quickstart
-    return _run(cfg, params, workers=2, scheduler="static")
-
-
-@pytest.fixture(scope="module")
-def mk_dynamic(quickstart):
-    cfg, params = quickstart
-    return _run(cfg, params, workers=2, scheduler="dynamic")
+    return _run(cfg, params, workers=2)
 
 
 # ---------------------------------------------------------------------------
@@ -76,8 +73,7 @@ def test_trace_off_bitwise_unchanged(quickstart, mk_static):
     ring existed (same compile, trace=True, gives identical ones too)."""
     cfg, params = quickstart
     prog_on, logits_on = mk_static
-    prog_off, logits_off = _run(cfg, params, workers=2,
-                                scheduler="static", trace=False)
+    prog_off, logits_off = _run(cfg, params, workers=2, trace=False)
     assert not prog_off.plan.trace and prog_on.plan.trace
     assert np.array_equal(prog_off.plan.descs, prog_on.plan.descs), \
         "trace=True must not perturb the descriptor table"
@@ -104,12 +100,14 @@ def test_tick_stream_is_a_permutation(mk_static):
     assert (ring[:, 3] < ring[:, 4]).all()
 
 
-@pytest.mark.parametrize("which", ["static", "dynamic"])
-def test_schema_order_and_reconcile(which, mk_static, mk_dynamic):
-    prog, _ = mk_static if which == "static" else mk_dynamic
+@pytest.mark.parametrize("workers", [1, 2])
+def test_schema_order_and_reconcile(workers, quickstart, mk_static):
+    cfg, params = quickstart
+    prog, _ = mk_static if workers == 2 else _run(cfg, params,
+                                                  workers=workers)
     observed = prog.trace()
     assert observed.origin == "kernel"
-    assert observed.num_workers == 2
+    assert observed.num_workers == workers
 
     obj = chrome_trace(observed)
     assert validate_chrome_trace(obj) == []
@@ -125,35 +123,58 @@ def test_schema_order_and_reconcile(which, mk_static, mk_dynamic):
     assert rep.matched == rep.n_predicted == rep.n_observed
     assert rep.unmatched_predicted == [] and rep.unmatched_observed == []
     assert rep.mean_abs_rank_skew < 0.25
-    if which == "static":
-        # static placement is a compile-time decision the kernel obeys
-        assert rep.worker_agreement == 1.0
+    # placement is a compile-time decision the kernel obeys
+    assert rep.worker_agreement == 1.0
 
 
-def test_dynamic_pop_sources_match_counters(mk_dynamic):
-    """Every executed slot records where its task came from; the split
-    must agree with the kernel's own pop-source counters."""
-    prog, _ = mk_dynamic
+def test_reserved_words_stay_unset(mk_static):
+    """The counter block's reserved words 8-11 stay zero and every ring
+    record's reserved word 5 reads -1 after a launch."""
+    from repro.kernels.megakernel.desc import STATS_WORDS
+
+    prog, _ = mk_static
+    plan = prog.plan
+    heap = prog.executor.read_heap()
+    stats = heap[plan.stats_offset:
+                 plan.stats_offset + plan.num_workers * STATS_WORDS]
+    assert (stats.reshape(plan.num_workers, STATS_WORDS)[:, 8:12]
+            == 0).all()
+    assert (prog.executor.task_ring()[:, 5] == -1).all()
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "gemma-7b", "qwen2-vl-2b",
+                                  "granite-moe-1b-a400m"])
+def test_trace_ring_per_family(arch):
+    """The ring on other families' kinds (SSM and conv, GeGLU, M-RoPE
+    with embedding input, MoE): the ticks are a permutation of
+    0..2N-1, event order holds, and every record names its own grid
+    slot and that slot's kind from the descriptor table."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), n_layers=1)
+    params = init_params(cfg, KEY, dtype=jnp.float32)
+    prog, _ = _run(cfg, params, workers=2)
     ring = prog.executor.task_ring()
-    live = ring[:, 1] >= 0
-    src = ring[live, 5].astype(np.int64)
-    assert set(np.unique(src)) <= {0, 1, 2}
-    ws = prog.worker_stats
-    assert int((src == 0).sum()) == ws["kernel_pops_own"]
-    assert int((src == 1).sum()) == ws["kernel_pops_overflow"]
-    assert int((src == 2).sum()) == ws["kernel_steals"]
-    # idle slots are the pad the ring records as row -1
-    assert int((~live).sum()) == ws["kernel_idle_slots"]
+    n = ring.shape[0]
+    ticks = np.concatenate([ring[:, 3], ring[:, 4]]).astype(np.int64)
+    assert sorted(ticks.tolist()) == list(range(2 * n))
+    assert check_event_order(prog.trace()) == []
+    assert (ring[:, 1] == np.arange(n)).all()
+    assert (ring[:, 2] == prog.plan.descs[:, 0]).all()
+
+
+def test_compile_takes_no_scheduler(quickstart):
+    cfg, _ = quickstart
+    with pytest.raises(TypeError):
+        mpk_compile(cfg, 2, 16, backend="megakernel",
+                    **{"scheduler": "static"})
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("scheduler", ["static", "dynamic"])
-@pytest.mark.parametrize("workers", [1, 4])
-def test_sweep_widths(quickstart, scheduler, workers):
-    """The acceptance sweep at W ∈ {1, 4} (W=2 is the fast lane):
-    schema-valid export, event order intact, every task reconciled."""
+@pytest.mark.parametrize("workers", [1, 2, 4])
+def test_sweep_widths(quickstart, workers):
+    """The acceptance sweep at W ∈ {1, 2, 4}: schema-valid export, event
+    order intact, every task reconciled."""
     cfg, params = quickstart
-    prog, _ = _run(cfg, params, workers=workers, scheduler=scheduler)
+    prog, _ = _run(cfg, params, workers=workers)
     observed = prog.trace()
     assert validate_chrome_trace(chrome_trace(observed)) == []
     assert check_event_order(observed) == []
@@ -169,8 +190,7 @@ def test_sweep_widths(quickstart, scheduler, workers):
 
 def test_interpreter_trace_reconciles(quickstart):
     cfg, params = quickstart
-    prog, _ = _run(cfg, params, workers=2, scheduler="dynamic",
-                   backend="interpreter")
+    prog, _ = _run(cfg, params, workers=2, backend="interpreter")
     tl = prog.trace()
     assert tl.origin == "interpreter"
     rep = reconcile(prog.predicted_trace(), tl)
@@ -192,7 +212,7 @@ def test_predicted_trace_makespan_matches_sim(quickstart):
 
     cfg, params = quickstart
     prog = mpk_compile(cfg, 2, 16, backend="interpreter", num_workers=2)
-    tl = predicted_task_trace(prog.compiled, "static", num_workers=2)
+    tl = predicted_task_trace(prog.compiled, num_workers=2)
     sim = simulate(prog.compiled, SimConfig(mode="mpk", n_workers=2))
     assert tl.meta["makespan"] == pytest.approx(sim.makespan)
     assert tl.makespan == pytest.approx(sim.makespan)
@@ -202,7 +222,7 @@ def test_predicted_trace_makespan_matches_sim(quickstart):
 def test_sequential_trace_static(quickstart):
     cfg, params = quickstart
     prog = mpk_compile(cfg, 2, 16, backend="interpreter", num_workers=2)
-    tl = sequential_trace(prog.compiled, "static")
+    tl = sequential_trace(prog.compiled)
     assert len(tl.events) == len(prog.compiled.order)
     assert [e.start for e in tl.events] == \
         [2.0 * i for i in range(len(tl.events))]

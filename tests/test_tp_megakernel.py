@@ -129,11 +129,6 @@ def test_stamped_table_matches_ring_closed_forms():
                 p.event_offset + p.num_events
 
 
-def test_dynamic_scheduler_tp_unsupported():
-    with pytest.raises(NotImplementedError):
-        _plan(_quick_cfg(), tp=2, scheduler="dynamic")
-
-
 def test_api_tp_validation():
     from repro import api
     with pytest.raises(ValueError):
